@@ -281,15 +281,16 @@ def check():
 @click.option('--timeout', default=90.0, show_default=True,
               help='Backend-init probe timeout (seconds).')
 @click.option('--no-probe', is_flag=True,
-              help='Skip the init probe: process table + relay only.')
+              help='Skip the backend probe: process table only.')
 @click.option('--reap', is_flag=True,
               help='Kill session-owned (fingerprinted) stray daemons.')
 @click.option('--reap-all', is_flag=True,
               help='Kill ALL framework daemons, fingerprinted or not.')
 @_clean_errors
 def doctor(timeout, no_probe, reap, reap_all):
-    """Diagnose TPU backend health: phased init probe, stray framework
-    daemons, device-relay socket state (see utils/tpu_doctor.py)."""
+    """Diagnose TPU backend health: backend probe (an ordinary child,
+    killed at its timeout) and stray framework daemons — one that
+    touched jax holds the chip (see utils/tpu_doctor.py)."""
     import json as _json
 
     from skypilot_tpu.utils import tpu_doctor

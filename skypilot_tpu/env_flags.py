@@ -44,9 +44,6 @@ FLAGS: Tuple[Flag, ...] = (
     Flag('SKYTPU_WORKSPACE', 'str', None,
          'Active workspace name; set by the request runner for every '
          'server-executed request.'),
-    Flag('SKYTPU_PKG_ROOT', 'path', None,
-         'Override for the installed package root (tpu_doctor uses it '
-         'to attribute framework processes to this checkout).'),
     Flag('SKYTPU_DB_URL', 'url', None,
          'External database URL for server state; unset = per-user '
          'sqlite under SKYTPU_STATE_DIR.'),
@@ -202,7 +199,8 @@ FLAGS: Tuple[Flag, ...] = (
          'Graceful drain window before a replica exits.'),
     Flag('SKYTPU_DECODE_KERNEL', 'str', None,
          "Set to 'pallas' to enable the fused decode attention "
-         'kernel.'),
+         "kernel ('interpret' runs it in the Pallas interpreter: "
+         'tests and the CPU rehearsal).'),
     # -- serving: QoS gate --------------------------------------------
     Flag('SKYTPU_QOS', 'bool', '0',
          'Enable the QoS admission gate on serving replicas.'),
@@ -329,8 +327,6 @@ FLAGS: Tuple[Flag, ...] = (
     Flag('SKYTPU_SERVE_CLAIM_GRACE_S', 'float', '300',
          'Grace before a dead controller\'s service claim may be '
          'adopted.'),
-    Flag('SKYTPU_GUARD_SPARE_MAX_S', 'float', '900',
-         'Max seconds the spot-guard keeps an idle spare alive.'),
     Flag('SKYTPU_SSH_USER', 'str', '$USER',
          'SSH user for the ssh_pool provisioner.'),
     Flag('SKYTPU_LOCAL_BUCKET_ROOT', 'path', None,
@@ -384,7 +380,9 @@ FLAGS: Tuple[Flag, ...] = (
          'Persistent XLA compilation-cache directory (per model '
          'version, provisioned by instance_setup). A replacement '
          'replica reuses its predecessor\'s lowered programs instead '
-         'of recompiling every PROGRAMS entry.'),
+         'of recompiling every PROGRAMS entry. '
+         'JAX_COMPILATION_CACHE_DIR wins when set; unset, the cache is '
+         '<checkout>/.jax_cache (utils/jax_env.py).'),
     Flag('SKYTPU_COMPILE_CACHE_MIN_S', 'float', '0',
          'Minimum compile seconds before a program is persisted to '
          'the compile cache (0 caches everything — required for the '
@@ -452,19 +450,6 @@ FLAGS: Tuple[Flag, ...] = (
     # -- bench / probe / test harness ---------------------------------
     Flag('SKYTPU_BENCH_SWEEP_BUDGET_S', 'float', '600',
          'Wall-clock budget for one bench sweep phase.'),
-    Flag('SKYTPU_BENCH_REAP_ALL', 'bool', None,
-         'Bench teardown reaps every framework process, not just its '
-         'own session.'),
-    Flag('SKYTPU_BENCH_PROBE_TIMEOUTS', 'csv', None,
-         'Per-probe timeout overrides for bench runs.'),
-    Flag('SKYTPU_PROBE_PHASE_DEADLINE_S', 'float', '300',
-         'perf_probe per-phase deadline.'),
-    Flag('SKYTPU_PROBE_HARD_DEADLINE_S', 'float', '600',
-         'perf_probe whole-run hard deadline.'),
-    Flag('SKYTPU_PROBE_HOLD_FILE', 'path', None,
-         'Probe synchronization hold-file (kill/resume scenarios).'),
-    Flag('SKYTPU_PROBE_HOLD_MAX_S', 'float', '60',
-         'Max seconds a probe parks on the hold-file.'),
     Flag('SKYTPU_LIVE_KIND', 'bool', None,
          'Opt into the live kind-cluster integration test.'),
 )

@@ -109,66 +109,6 @@ from skypilot_tpu.observability.profiler import profiled_jit
 from skypilot_tpu.observability import trace as trace_lib
 from skypilot_tpu.utils import prefix_affinity as affinity_lib
 
-# -- persistent XLA compilation cache (cold-start collapse) ------------------
-
-_COMPILE_CACHE_STATE: Optional[dict] = None
-
-
-def maybe_enable_compile_cache() -> dict:
-    """Point jax at the per-model-version persistent compilation cache
-    (``SKYTPU_COMPILE_CACHE``, provisioned by
-    ``provision/instance_setup.py`` alongside the ckpt mirror) so a
-    replacement replica REUSES its predecessor's lowered programs
-    instead of recompiling every ``PROGRAMS`` entry from source.
-
-    Idempotent and crash-proof: ``llm_server`` calls it before backend
-    init (the cache must be configured before the first lowering);
-    the engine constructor calls it again defensively for embedded
-    users. Returns the status block ``/health`` surfaces::
-
-        {'enabled': bool, 'dir': str, 'entries_at_start': int,
-         'warm': bool}
-
-    ``warm`` — the cache already held entries when THIS process
-    enabled it — is how boots classify warm vs cold for the
-    autoscaler's spin-up lead-time model (serve/autoscalers.py)."""
-    global _COMPILE_CACHE_STATE
-    if _COMPILE_CACHE_STATE is not None:
-        return _COMPILE_CACHE_STATE
-    cache_dir = (os.environ.get('SKYTPU_COMPILE_CACHE') or '').strip()
-    if not cache_dir:
-        _COMPILE_CACHE_STATE = {'enabled': False}
-        return _COMPILE_CACHE_STATE
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    try:
-        min_s = float(os.environ.get('SKYTPU_COMPILE_CACHE_MIN_S',
-                                     '0') or '0')
-    except ValueError:
-        min_s = 0.0
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        entries = sum(1 for n in os.listdir(cache_dir)
-                      if not n.endswith('-atime'))
-        jax.config.update('jax_compilation_cache_dir', cache_dir)
-        # Default min-compile-time (1 s) would skip every program the
-        # tiny CPU-backend probe replica compiles; 0 caches everything.
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          min_s)
-        try:
-            jax.config.update('jax_persistent_cache_min_entry_size_bytes',
-                              -1)
-        except Exception:  # noqa: BLE001 — older jax: default caches all
-            pass
-        _COMPILE_CACHE_STATE = {'enabled': True, 'dir': cache_dir,
-                                'entries_at_start': entries,
-                                'warm': entries > 0}
-    except Exception as e:  # noqa: BLE001 — cache trouble must never
-        # fail a boot: serving without the cache is just slower.
-        _COMPILE_CACHE_STATE = {'enabled': False,
-                                'error': str(e)[:200]}
-    return _COMPILE_CACHE_STATE
-
-
 @dataclasses.dataclass
 class _Request:
     """Host-side bookkeeping for one prompt row occupying (at most) one
@@ -544,7 +484,8 @@ class ContinuousEngine:
         '_admitting': '_lock', '_prefilling': '_lock',
         '_unfetched': '_lock', '_slot_req': '_lock',
         '_tier_waiting': '_lock',
-        'prefills': '_lock', 'prefill_groups': '_lock',
+        'prefills': '_lock', 'failures': '_lock',
+        'prefill_groups': '_lock',
         'prefill_chunks': '_lock', 'prefix_hits': '_lock',
         'prefix_hit_tokens': '_lock', 'prefix_stores': '_lock',
         'share_hits': '_lock', 'share_hit_tokens': '_lock',
@@ -580,10 +521,6 @@ class ContinuousEngine:
                  prefix_share: Optional[bool] = None,
                  kv_tiers: Optional[bool] = None,
                  role: Optional[str] = None):
-        # Defensive for embedded users; the serving entrypoint already
-        # enabled it before the backend initialized (first lowering
-        # must see the cache config).
-        maybe_enable_compile_cache()
         self.params = params
         self.cfg = cfg
         # Disaggregated serving role (serve/disagg.py): 'prefill'
@@ -761,7 +698,7 @@ class ContinuousEngine:
                 mesh, self.rules, ('layers', 'batch', 'kv_heads', None))
             self._vec_sharding = sharding_lib.logical_sharding(
                 mesh, self.rules, ('batch',))
-            if gen_lib._DECODE_KERNEL_ENABLED:
+            if gen_lib._DECODE_KERNEL:
                 # The pallas decode kernel runs per head shard under TP
                 # via shard_map (generate.kernel_shard_ctx) — no gate.
                 self._shard_ctx = gen_lib.kernel_shard_ctx(mesh,
@@ -815,6 +752,7 @@ class ContinuousEngine:
         self._no_flight_since: Optional[float] = None
         # Stats (read by /health).
         self.prefills = 0
+        self.failures = 0  # _fail_everything trips
         self.prefill_groups = 0
         self.prefill_chunks = 0
         self.prefix_hits = 0
@@ -1180,6 +1118,7 @@ class ContinuousEngine:
                     'cow_forks': self.cow_forks}),
                 'kv_tiers': tier_stats,
                 'queued': queued, 'prefills': self.prefills,
+                'failures': self.failures,
                 'prefill_groups': self.prefill_groups,
                 'prefill_batch': self.prefill_batch,
                 'prefill_chunk': self.prefill_chunk,
@@ -1336,6 +1275,7 @@ class ContinuousEngine:
             self._inflight = None
             self._last_dispatch_t = None
             self._no_flight_since = None
+            self.failures += 1
         for req in doomed:  # dupes are safe: first set_exception wins
             if not req.future.done():
                 req.future.set_exception(exc)

@@ -13,6 +13,7 @@ training stack (``models/llama.py``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Optional, Tuple
 
@@ -34,13 +35,14 @@ _NEG_INF = -1e30
 # static args only, so a flag that changed mid-process would be
 # silently ignored for already-compiled shapes — latching makes the
 # semantics honest (set the env before the serving process starts).
-# Tests monkeypatch the module attribute directly.
-_DECODE_KERNEL_ENABLED = (
-    os.environ.get('SKYTPU_DECODE_KERNEL') == 'pallas')
-
-
-def _use_decode_kernel() -> bool:
-    return _DECODE_KERNEL_ENABLED
+# 'pallas' compiles the Mosaic kernel (TPU only); 'interpret' runs it in
+# the Pallas interpreter — something tests and the CPU rehearsal ask
+# for by name, never inferred from the backend. Tests monkeypatch the
+# module attribute directly.
+_DECODE_KERNEL = os.environ.get('SKYTPU_DECODE_KERNEL') or None
+if _DECODE_KERNEL not in (None, 'pallas', 'interpret'):
+    raise ValueError(f'SKYTPU_DECODE_KERNEL={_DECODE_KERNEL!r}: '
+                     "'pallas' or 'interpret'")
 
 
 def kernel_shard_ctx(mesh, rules):
@@ -129,48 +131,41 @@ def _cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     position: keys scale the post-QK logits, values scale the probs
     before PV — the full-precision cache never materializes."""
     b, s, hq, d = q.shape
-    if s == 1 and _use_decode_kernel():
+    if s == 1 and _DECODE_KERNEL is not None:
         # Opt-in pallas flash-decode (ops/decode_attention.py): streams
         # the cache once with an online softmax instead of
         # materializing the [B, Hkv, G, 1, M] fp32 logits between two
         # einsums. Tolerance-level (not bit-exact) vs this path, hence
         # opt-in: SKYTPU_DECODE_KERNEL=pallas.
+        from skypilot_tpu.ops import attention as attention_ops
         from skypilot_tpu.ops import decode_attention
-        from skypilot_tpu.ops.attention import _use_pallas
         if decode_attention.fits(k_cache.shape[2], d):
             lengths = (jnp.broadcast_to(valid_len, (b,)).astype(jnp.int32)
                        if valid_len.ndim == 0
                        else valid_len.astype(jnp.int32))
-            interp = not _use_pallas()
+            kernel = functools.partial(
+                decode_attention.flash_decode,
+                interpret=_DECODE_KERNEL == 'interpret')
+            args = (q[:, 0], k_cache, v_cache, lengths)
+            if k_s is not None:
+                args += (k_s, v_s)
             if shard_ctx is None:
-                out = decode_attention.flash_decode(
-                    q[:, 0], k_cache, v_cache, lengths, k_s, v_s,
-                    interpret=interp)
+                out = kernel(*args)
             else:
                 # TP serving: run the kernel per head shard (see
-                # kernel_shard_ctx). check_rep off: the scalar-prefetch
+                # kernel_shard_ctx). check_vma off: the scalar-prefetch
                 # grid confuses the replication checker.
-                from jax.experimental.shard_map import shard_map
                 mesh, p_q, p_kv, p_len, p_s = shard_ctx
-                if k_s is None:
-                    out = shard_map(
-                        lambda q_, k_, v_, l_: decode_attention.
-                        flash_decode(q_, k_, v_, l_, interpret=interp),
-                        mesh=mesh, in_specs=(p_q, p_kv, p_kv, p_len),
-                        out_specs=p_q, check_rep=False)(
-                            q[:, 0], k_cache, v_cache, lengths)
-                else:
-                    out = shard_map(
-                        lambda q_, k_, v_, l_, ks_, vs_: decode_attention.
-                        flash_decode(q_, k_, v_, l_, ks_, vs_,
-                                     interpret=interp),
-                        mesh=mesh,
-                        in_specs=(p_q, p_kv, p_kv, p_len, p_s, p_s),
-                        out_specs=p_q, check_rep=False)(
-                            q[:, 0], k_cache, v_cache, lengths, k_s, v_s)
+                in_specs = (p_q, p_kv, p_kv, p_len) + (
+                    (p_s, p_s) if k_s is not None else ())
+                out = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                                    out_specs=p_q, check_vma=False)(*args)
             return out[:, None].astype(q.dtype)
-        # else: geometry the kernel can't take (VMEM cap / non-128
-        # cache) — fall through to the einsum path.
+        # Geometry the kernel can't take (VMEM cap / non-128 cache):
+        # the einsum path below, said once per shape.
+        attention_ops.log_fallback_once(
+            'flash_decode', k_cache.shape,
+            f'cache M={k_cache.shape[2]}, D={d} outside fits()')
     hkv = k_cache.shape[1]
     group = hq // hkv
     max_len = k_cache.shape[2]

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import ad_checkpoint
 
 from skypilot_tpu.models import moe
-from skypilot_tpu.ops import flash_attention
+from skypilot_tpu.ops import attention as attention_ops
 
 Params = Dict[str, Any]
 
@@ -217,8 +217,10 @@ def _use_seq_parallel(mesh) -> bool:
 def _decoder_layer(cfg: LlamaConfig, x: jax.Array, layer: Params,
                    positions: jax.Array,
                    moe_constrain=None,
-                   mesh=None) -> Tuple[jax.Array, jax.Array]:
-    """One decoder block; returns (x, moe_aux_loss)."""
+                   mesh=None, attn_shard=None
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """One decoder block; returns (x, moe_aux_loss). ``attn_shard``
+    (``ops.attention.shard_ctx``) runs the flash kernel per mesh shard."""
     # Attention block
     h = rms_norm(x, layer['attn_norm'], cfg.norm_eps)
     # Checkpoint names let remat policies (REMAT_POLICIES) pick precisely
@@ -239,7 +241,8 @@ def _decoder_layer(cfg: LlamaConfig, x: jax.Array, layer: Params,
         from skypilot_tpu.parallel import ring_attention as ring_lib
         att = ring_lib.ring_attention(qt, kt, vt, mesh, causal=True)
     else:
-        att = flash_attention(qt, kt, vt, causal=True)
+        att = attention_ops.flash_attention(qt, kt, vt, causal=True,
+                                            shard=attn_shard)
     att = att.transpose(0, 2, 1, 3)
     # Named so a remat policy can keep attention outputs (the most
     # expensive recompute) while rematerializing cheap elementwise/matmul
@@ -286,14 +289,15 @@ REMAT_POLICIES = {
 def _layer_stack(cfg: LlamaConfig, x: jax.Array, layers: Params,
                  positions: jax.Array, remat: bool,
                  moe_constrain=None,
-                 mesh=None, remat_policy: str = 'full'
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 mesh=None, remat_policy: str = 'full',
+                 attn_shard=None) -> Tuple[jax.Array, jax.Array]:
     """Scan over (a slice of) the layer stack; returns (x, aux_sum)."""
 
     def body(carry, layer):
         x, aux = carry
         y, a = _decoder_layer(cfg, x, layer, positions,
-                              moe_constrain=moe_constrain, mesh=mesh)
+                              moe_constrain=moe_constrain, mesh=mesh,
+                              attn_shard=attn_shard)
         return (y, aux + a), None
 
     if remat:
@@ -351,6 +355,9 @@ def forward_with_aux(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         mb_positions = positions[:b // n_micro]
 
         def stage_fn(layers, x_mb):
+            # vmapped over stages below; the flash kernel stays a bare
+            # call here (no attn_shard) — pipelining has not met a TPU
+            # mesh yet.
             return _layer_stack(cfg, x_mb, layers, mb_positions, remat,
                                 moe_constrain=moe_constrain, mesh=mesh,
                                 remat_policy=remat_policy)
@@ -370,7 +377,9 @@ def forward_with_aux(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     else:
         x, aux = _layer_stack(cfg, x, params['layers'], positions, remat,
                               moe_constrain=moe_constrain, mesh=mesh,
-                              remat_policy=remat_policy)
+                              remat_policy=remat_policy,
+                              attn_shard=attention_ops.shard_ctx(mesh,
+                                                                 rules))
 
     x = rms_norm(x, params['final_norm'], cfg.norm_eps)
     logits = jnp.einsum('bsd,dv->bsv', x, params['lm_head'],

@@ -241,7 +241,7 @@ def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
     finishing row stays active and its blocks are only released after
     the chunk returns, so active writes never race a reallocation."""
     b, s = x.shape[0], x.shape[1]
-    p = k_pool.shape[3]
+    p = k_pool.shape[2]  # this layer's plane: [NB, Hkv, P, D]
     mb = tables.shape[1]
     positions = (lengths[:, None]
                  + jnp.arange(s, dtype=jnp.int32)[None])  # [B, S]

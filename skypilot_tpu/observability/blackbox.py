@@ -4,18 +4,18 @@ dumped as an incident bundle at the moment things go wrong.
 The tree can see healthy traffic end-to-end (traces, goodput, gauges),
 but failures used to be forensically blind: the engine's
 ``_fail_everything`` killed every in-flight stream with one log line,
-preemptions and watchdog reaps left no state snapshot, and a hung TPU
-probe pinned nothing but a stuck-phase name. This module is the crash
-counterpart of ``trace.py``: a **bounded in-process event ring** every
-layer appends cheap typed events to, plus a **dump** path that freezes
-the ring — with trace spans, the last ``/health`` snapshot, declared
-``SKYTPU_*`` flag values, and ``faulthandler`` thread stacks — into one
-atomically written JSON file (an *incident bundle*) in a spool.
+and preemptions and watchdog reaps left no state snapshot. This module
+is the crash counterpart of ``trace.py``: a **bounded in-process event
+ring** every layer appends cheap typed events to, plus a **dump** path
+that freezes the ring — with trace spans, the last ``/health``
+snapshot, declared ``SKYTPU_*`` flag values, and ``faulthandler``
+thread stacks — into one atomically written JSON file (an *incident
+bundle*) in a spool.
 
 Design constraints (shared with the rest of the observability package):
 
 * **Dependency-free** — rides inside the engine thread, the serve
-  controller, the agent daemon, and the probe child; stdlib only.
+  controller and the agent daemon; stdlib only.
 * **Lock-cheap recording** — ``record()`` is one tuple build plus a
   deque append under a private lock; it performs no I/O, no host sync,
   and allocates nothing beyond the ring slot, so it is legal from the
@@ -34,8 +34,7 @@ Design constraints (shared with the rest of the observability package):
 Triggers (bounded label set for ``skytpu_incident_bundles_total``):
 engine failure (``models/engine.py _fail_everything``), SIGTERM /
 preemption (trainer emergency persist, replica drain), watchdog reap
-(``jobs/watchdog.py``), probe phase-deadline abort
-(``utils/tpu_doctor.py`` child), and on-demand (``/debug/blackbox?dump=1``,
+(``jobs/watchdog.py``), and on-demand (``/debug/blackbox?dump=1``,
 ``stpu debug dump``, ``kill -QUIT``). ``SKYTPU_BLACKBOX=0`` disables
 recording and dumping entirely (byte-parity pinned by
 ``tools/perf_probe.py --blackbox``).
@@ -133,9 +132,6 @@ EVENTS: Tuple[Event, ...] = (
           'The autostop policy acted (stop | down).'),
     Event('sched.watchdog',
           'A watchdog sweep acted: requeued / reaped / gave up ids.'),
-    # -- probes --------------------------------------------------------
-    Event('probe.phase',
-          'The phased TPU init probe crossed (or aborted in) a phase.'),
     # -- runtime profiler (observability/profiler.py) ------------------
     Event('profiler.storm',
           'A profiled jit program compiled past its declared shape '
@@ -151,8 +147,8 @@ assert len(EVENT_NAMES) == len(EVENTS), 'duplicate event declaration'
 #: (observability/slo.py): a page-severity alert transitioning to
 #: firing dumps the implicated processes, so gradual saturation — not
 #: just crashes — arrives with a frozen timeline attached.
-TRIGGERS = ('engine_failure', 'sigterm', 'watchdog', 'probe_deadline',
-            'slo_breach', 'manual')
+TRIGGERS = ('engine_failure', 'sigterm', 'watchdog', 'slo_breach',
+            'manual')
 
 #: Env flags whose values are secrets: bundles record presence, never
 #: the value.

@@ -171,8 +171,8 @@ _BY_NAME: Dict[str, Program] = {p.name: p for p in PROGRAMS}
 #: Cold-start phases in their designed order. Each :func:`mark` records
 #: the phase's first COMPLETION crossing; durations telescope between
 #: consecutive crossings, so the ledger sums to the observed wall-clock
-#: by construction. The two ``backend_init.*`` sub-phases are the init
-#: legs the tpu_doctor probe child pins hangs to.
+#: by construction. The two ``backend_init.*`` sub-phases are PJRT
+#: client construction and device enumeration (utils/jax_env.py).
 COLD_START_PHASES: Tuple[str, ...] = (
     'imports',
     'backend_init.plugin_discovery',
@@ -483,9 +483,7 @@ def mark(phase: str) -> None:
     record, not a recurring timer). Always recorded regardless of
     SKYTPU_PROFILE (a timestamp dict write is free; flipping the flag
     on mid-process must not lose the start), but SURFACED only with
-    profiling on — the tpu_doctor probe child therefore runs with
-    SKYTPU_PROFILE=1 in its scratch env so its probe_deadline bundle
-    carries the crossed sub-phases."""
+    profiling on."""
     if phase not in COLD_START_PHASES:
         raise ValueError(f'unknown cold-start phase {phase!r}; declared: '
                          f'{", ".join(COLD_START_PHASES)}')

@@ -19,6 +19,10 @@ lengths arrive via scalar prefetch and mask tail positions in-kernel.
 int8 caches fold their per-position scales exactly like the jnp path:
 key scales into the post-QK logits, value scales into the probs.
 
+The int8 scales travel as lane-dense ``[1, M]`` rows: a ``[M, 1]``
+column pads to 128 lanes per position in VMEM, which at the cap would
+outweigh the cache slices themselves.
+
 OPT-IN (``SKYTPU_DECODE_KERNEL=pallas``): accumulation order differs
 from the XLA path, so outputs match to tolerance, not bit-exactly — and
 the serving engine's exact-parity contract keeps the XLA path as its
@@ -37,9 +41,10 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_K = 512
 _NEG_INF = -1e30
 # Both K and V slices ([M, D] each, plus scales in int8 mode) sit whole
-# in VMEM per program; cap M*D so they fit (~16 MB/core budget shared
-# with everything else). Beyond the cap callers take the XLA path —
-# same policy as the training kernel's _BWD_VMEM_CAP_ELEMS.
+# in VMEM per program; cap M*D so they fit the scoped-VMEM limit.
+# M = 16384 at D = 128, bf16 and int8, is what the chip_smoke `kernels`
+# phase compiles on a v5e. Beyond the cap callers take the XLA path —
+# same policy as the training kernel's _VMEM_CAP_ELEMS.
 VMEM_CAP_ELEMS = 2 * 1024 * 1024
 
 
@@ -64,7 +69,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
                    max_len: int, quant: bool):
     """q_ref [G, D]; k_ref/v_ref [M, D] (one (row, kv-head) slice);
     len_ref: scalar-prefetched [B] valid lengths. ``quant`` (static):
-    k/v are int8 codes and ``rest`` leads with their [M, 1] fp32
+    k/v are int8 codes and ``rest`` leads with their [1, M] fp32
     per-position scales, folded exactly where the jnp path folds them
     (keys into the logits, values into the probs). ONE body serves both
     modes so the masking/accumulation can never diverge."""
@@ -81,13 +86,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
 
     def body(kb, carry):
         acc, m_prev, l_prev = carry
-        start = kb * block_k
+        start = pl.multiple_of(kb * block_k, block_k)
         kblk = k_ref[pl.ds(start, block_k), :]
         s = jax.lax.dot_general(
             q, kblk.astype(q.dtype), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [G, bk]
         if quant:
-            s = s * ks_ref[pl.ds(start, block_k), :][:, 0][None, :]
+            s = s * ks_ref[:, pl.ds(start, block_k)]
         ki = start + jax.lax.broadcasted_iota(jnp.int32, (g, block_k), 1)
         s = jnp.where(ki < valid, s, _NEG_INF)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -97,7 +102,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, block_k: int,
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         vblk = v_ref[pl.ds(start, block_k), :]
         if quant:
-            p = p * vs_ref[pl.ds(start, block_k), :][:, 0][None, :]
+            p = p * vs_ref[:, pl.ds(start, block_k)]
         acc = acc * alpha + jax.lax.dot_general(
             p.astype(q.dtype), vblk.astype(q.dtype),
             (((1,), (0,)), ((), ())),
@@ -150,10 +155,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             interpret=interpret,
         )(lengths, qg, k_cache, v_cache)
     else:
-        # Scales get a trailing singleton dim: Mosaic wants the minor
-        # dim 128-divisible or the full array dim (same trick as the
-        # training kernel's lse/delta).
-        sspec = pl.BlockSpec((None, None, m, 1),
+        sspec = pl.BlockSpec((None, None, 1, m),
                              lambda bi, hi, *_: (bi, hi, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
@@ -163,5 +165,6 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             functools.partial(_decode_kernel, quant=True, **common),
             grid_spec=grid_spec, out_shape=out_shape,
             interpret=interpret,
-        )(lengths, qg, k_cache, v_cache, k_s[..., None], v_s[..., None])
+        )(lengths, qg, k_cache, v_cache, k_s[:, :, None, :],
+          v_s[:, :, None, :])
     return out.reshape(b, hq, d)

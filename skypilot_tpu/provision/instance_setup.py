@@ -28,7 +28,7 @@ REMOTE_WORKDIR = '~/sky_workdir'
 # Replicas get a per-model-version subdir (serve/replica_managers.py
 # injects SKYTPU_COMPILE_CACHE=<base>/<service>-v<version>) so a
 # replacement replica deserializes its predecessors' lowered programs
-# instead of recompiling them (models/engine.maybe_enable_compile_cache).
+# instead of recompiling them (utils/jax_env.enable_compile_cache).
 REMOTE_COMPILE_CACHE_DIR = '~/.skytpu/compile_cache'
 
 

@@ -21,7 +21,7 @@ sizes otherwise (``profiler.jit_cache_sizes``) — a compile grows the
 cache whether or not the ledger recorded it.
 
 Budget discipline: with the persistent compilation cache populated
-(``models/engine.maybe_enable_compile_cache``) the same warm-up mix
+(``utils/jax_env.enable_compile_cache``) the same warm-up mix
 deserializes its programs instead of compiling them, which is why the
 ``perf_probe --coldstart`` gate can demand the second boot be strictly
 faster on the compile-phase ledger.
@@ -92,8 +92,8 @@ def _cache_canary() -> Optional[Dict[str, int]]:
     boot. Returns {'entries_before', 'entries_after'} (None with the
     cache off); after a successful round trip the canary's entry
     exists whether this boot wrote it or a predecessor did."""
-    from skypilot_tpu.models import engine as engine_lib
-    state = engine_lib.maybe_enable_compile_cache()
+    from skypilot_tpu.utils import jax_env
+    state = jax_env.compile_cache_state()
     if not state.get('enabled'):
         return None
     import jax

@@ -280,8 +280,7 @@ _DISAGG_FALLBACK = Gauge(
 _INCIDENT_BUNDLES = Gauge(
     'skytpu_incident_bundles_total',
     'Incident bundles written by this process since start, by trigger '
-    '(engine_failure | sigterm | watchdog | probe_deadline | '
-    'slo_breach | manual).',
+    '(engine_failure | sigterm | watchdog | slo_breach | manual).',
     ['trigger'], registry=SERVING_REGISTRY)
 # Runtime profiler (observability/profiler.py): compile ledger, device
 # memory, cold-start phases. Gauges mirroring the profiler's own

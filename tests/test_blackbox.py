@@ -231,28 +231,6 @@ def test_sigterm_orders_persist_before_bundle(tmp_path):
     assert len(bundles) == 1 and bundles[0]['trigger'] == 'sigterm'
 
 
-def test_probe_child_deadline_abort_writes_bundle(tmp_path, monkeypatch):
-    """The phased TPU probe's child self-aborts on a stuck phase AND
-    leaves an incident bundle (stuck phase + stacks) that probe_backend
-    carries home in its report — the bench un-blinding satellite."""
-    from skypilot_tpu.utils import tpu_doctor
-    monkeypatch.setenv('SKYTPU_PROBE_HOLD_FILE',
-                       str(tmp_path / 'never-created'))
-    monkeypatch.setenv('SKYTPU_PROBE_HOLD_MAX_S', '30')
-    monkeypatch.setenv('SKYTPU_PROBE_PHASE_DEADLINE_S', '2')
-    report = tpu_doctor.probe_backend(timeout_s=25.0)
-    assert not report['ok']
-    assert report['last_phase'] == 'phase-deadline-abort'
-    b = report['bundle']
-    assert b is not None, report
-    assert b['trigger'] == 'probe_deadline'
-    assert 'python-started' in b['reason']
-    phases = [e['attrs']['phase'] for e in b['events']
-              if e['name'] == 'probe.phase']
-    assert phases and phases[0] == 'python-started'
-    assert 'Thread 0x' in b['stacks'] or 'Current thread' in b['stacks']
-
-
 # -- registry ----------------------------------------------------------------
 
 

@@ -304,8 +304,8 @@ def test_pallas_decode_kernel_under_tp(tiny):
     cfg, params = tiny
     mesh = mesh_lib.build_mesh(mesh_lib.MeshSpec(fsdp=1, tensor=2),
                                devices=jax.devices()[:2])
-    old = gen_lib._DECODE_KERNEL_ENABLED
-    gen_lib._DECODE_KERNEL_ENABLED = True
+    old = gen_lib._DECODE_KERNEL
+    gen_lib._DECODE_KERNEL = 'interpret'
     eng = None
     try:
         eng = engine_lib_.ContinuousEngine(params, cfg, slots=2,
@@ -322,7 +322,7 @@ def test_pallas_decode_kernel_under_tp(tiny):
         assert len(got) == 6
         assert all(0 <= t < cfg.vocab_size for t in got)
     finally:
-        gen_lib._DECODE_KERNEL_ENABLED = old
+        gen_lib._DECODE_KERNEL = old
         if eng is not None:
             eng.stop()
 

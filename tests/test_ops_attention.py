@@ -67,15 +67,22 @@ def test_flash_fwd_lse_is_logsumexp(small_blocks):
     assert jnp.allclose(lse, expect, atol=1e-3)
 
 
-def test_bwd_vmem_fallback_matches(monkeypatch):
-    """Beyond the VMEM cap the bwd falls back to the reference vjp."""
-    monkeypatch.setattr(attention, '_BWD_VMEM_CAP_ELEMS', 1)
+def test_vmem_cap_takes_reference_and_says_so_once(monkeypatch, caplog):
+    """Past the resident-K/V cap the whole op (fwd and bwd) takes the
+    reference — on a trace-time condition, logged once per shape."""
+    monkeypatch.setattr(attention, '_VMEM_CAP_ELEMS', 1)
+    monkeypatch.setattr(attention, '_logged_fallbacks', set())
     b, h, s, d = 1, 2, 256, 64
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     q, k, v, g = (_rand((b, h, s, d), kk) for kk in ks)
-    _, vjp = jax.vjp(
-        lambda a, b_, c: attention._flash_attention(a, b_, c, True, True),
-        q, k, v)
+    with caplog.at_level('WARNING', logger=attention.__name__):
+        _, vjp = jax.vjp(
+            lambda a, b_, c: attention.flash_attention(
+                a, b_, c, True, interpret=True), q, k, v)
+        attention.flash_attention(q, k, v, True, interpret=True)
+    tagged = [r for r in caplog.records
+              if attention.FALLBACK_TAG in r.getMessage()]
+    assert len(tagged) == 1 and 'VMEM cap' in tagged[0].getMessage()
     _, vjp_ref = jax.vjp(
         lambda a, b_, c: attention.attention_reference(a, b_, c, True),
         q, k, v)
@@ -181,7 +188,8 @@ def test_flash_decode_geometry_gate():
 
 def test_flash_decode_opt_in_end_to_end(monkeypatch):
     """With the kernel latched on, the decode-step logits through the
-    kernel match the einsum path's closely (interpret mode off TPU).
+    kernel match the einsum path's closely (interpret mode, asked for
+    by name).
     The flag is latched at import (module jits cache compiled paths),
     so tests patch the module attribute."""
     from skypilot_tpu.models import generate as gen_lib
@@ -195,7 +203,7 @@ def test_flash_decode_opt_in_end_to_end(monkeypatch):
     logits, cache = gen_lib.forward_cached(params, prompt, cache, cfg)
     tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
     ref_logits, _ = gen_lib.forward_cached(params, tok, cache, cfg)
-    monkeypatch.setattr(gen_lib, '_DECODE_KERNEL_ENABLED', True)
+    monkeypatch.setattr(gen_lib, '_DECODE_KERNEL', 'interpret')
     ker_logits, _ = gen_lib.forward_cached(params, tok, cache, cfg)
     # bf16 activations: per-path accumulation-order noise is ~0.03 in
     # logit units; the check is that the kernel is wired in and sane.

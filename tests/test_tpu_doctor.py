@@ -1,7 +1,7 @@
-"""tpu_doctor: phased init probe, fingerprint-scoped reaping, relay
-snapshot (r3 verdict Next #1 + advisor medium on reaper ownership)."""
+"""tpu_doctor: fingerprint-scoped reaping (r3 advisor medium on reaper
+ownership) and the backend probe — an ordinary child, killed at its
+timeout."""
 import os
-import signal
 import subprocess
 import sys
 import time
@@ -96,115 +96,67 @@ def test_reap_ownership_semantics():
             pass
 
 
-def test_probe_backend_completes_on_cpu():
-    # conftest pins JAX_PLATFORMS=cpu; the subprocess inherits it, so the
-    # full phase ladder must complete.
+def test_probe_backend_reports_the_device():
+    # conftest pins JAX_PLATFORMS=cpu; the child inherits it, brings the
+    # backend up the way the entry points do and prints its device line.
     probe = tpu_doctor.probe_backend(timeout_s=120.0)
     assert probe['ok'], probe
-    assert probe['last_phase'] == 'first-compile-done'
-    assert probe['diagnosis'] == 'completed'
-    assert any(p.startswith('devices-enumerated') for p in probe['phases'])
+    assert probe['outcome'] == 'completed'
+    assert probe['device']['platform'] == 'cpu'
+    assert probe['device']['device_count'] >= 1
+    assert probe['stderr_tail'] is None
 
 
-def test_probe_backend_timeout_pins_phase(monkeypatch, tmp_path):
-    """Timeout path, deterministically: the child is HELD at the
-    python-started phase via the injected hold-file gate, so the
-    assertion never races real jax import/compile speed (the old
-    timing flake: with timeout_s=0.05 a fast box could reach
-    first-compile-done inside the parent's post-timeout SIGUSR1
-    window)."""
-    gate = tmp_path / 'release-probe-child'
-    monkeypatch.setenv('SKYTPU_PROBE_HOLD_FILE', str(gate))
-    try:
-        probe = tpu_doctor.probe_backend(timeout_s=0.05)
-    finally:
-        gate.touch()  # release the detached child; it exits on its own
+def test_probe_backend_kills_the_child_at_its_timeout():
+    """No detached child is left to finish on its own: a second process
+    holding the chip is exactly what the next caller cannot survive."""
+    probe = tpu_doctor.probe_backend(timeout_s=0.05)
     assert not probe['ok']
     assert probe['outcome'] == 'timeout'
-    assert probe['elapsed_s'] < 30
-    # Held before the ladder finished; the diagnosis names the stage.
-    assert probe['last_phase'] in (None, 'python-started')
-    assert probe['diagnosis'] != 'completed'
-
-
-def test_probe_phase_deadline_aborts_naming_stuck_phase(monkeypatch,
-                                                        tmp_path):
-    """Per-phase deadline (r06 un-blinding satellite): a child whose
-    CURRENT phase overruns SKYTPU_PROBE_PHASE_DEADLINE_S self-aborts
-    and the probe result names the stuck phase — a real-TPU bench run
-    either completes or fails loudly, never hangs blind. The hold gate
-    (never released) simulates the hang at python-started; the 1s
-    phase deadline turns it into a deterministic abort well inside the
-    parent's 60s budget."""
-    gate = tmp_path / 'never-created'
-    monkeypatch.setenv('SKYTPU_PROBE_HOLD_FILE', str(gate))
-    monkeypatch.setenv('SKYTPU_PROBE_HOLD_MAX_S', '30')
-    monkeypatch.setenv('SKYTPU_PROBE_PHASE_DEADLINE_S', '1')
-    probe = tpu_doctor.probe_backend(timeout_s=60.0)
-    assert not probe['ok']
-    assert probe['outcome'] == 'timeout', probe
-    assert probe['last_phase'] == 'phase-deadline-abort', probe
-    assert 'python-started' in probe['diagnosis'], probe
-    assert 'deadline' in probe['diagnosis'], probe
-
-
-def test_bench_tpu_unreachable_fails_loudly():
-    """bench satellite: a wanted-TPU run whose probe surrendered must
-    not report its CPU measurement as the trajectory — the headline
-    value becomes 0.0 with the stuck phase named, and the CPU number is
-    demoted to detail.cpu_reference."""
-    import pathlib
-    import sys as sys_mod
-    sys_mod.path.insert(0, str(pathlib.Path(__file__).parents[1]))
-    import bench
-    result = {'metric': 'llama_train_model_tflops_per_chip',
-              'value': 0.123456, 'vs_baseline': 0.005,
-              'detail': {'backend': 'cpu', 'cpu_fallback': True,
-                         'tokens_per_sec_per_chip': 321.0}}
-    out = bench.mark_tpu_unreachable(
-        result, {'final_hang_phase': 'jax-imported',
-                 'final_diagnosis': 'hung in backend init'})
-    assert out['value'] == 0.0 and out['vs_baseline'] == 0.0
-    assert out['detail']['tpu_unreachable'] is True
-    assert out['detail']['tpu_stuck_phase'] == 'jax-imported'
-    assert out['detail']['cpu_reference']['tflops_per_chip'] == 0.123456
-    assert out['detail']['cpu_reference']['tokens_per_sec_per_chip'] \
-        == 321.0
+    assert probe['device'] is None
+    time.sleep(0.2)
+    left = [p for p in tpu_doctor.framework_processes()
+            if 'skypilot_tpu.utils.jax_env' in p['cmdline']]
+    assert not left, left
 
 
 def test_probe_backend_crash_reports_error_line(monkeypatch):
     """A clean fast failure (unknown platform, no device attached) is a
-    CRASH, not a hang — the diagnosis must carry the error text."""
+    crash — the report carries the error text."""
     monkeypatch.setenv('JAX_PLATFORMS', 'bogus-backend')
     probe = tpu_doctor.probe_backend(timeout_s=120.0)
     assert not probe['ok']
     assert probe['outcome'] == 'crashed'
-    assert 'CRASHED' in probe['diagnosis']
-    assert 'bogus' in probe['diagnosis'] or 'bogus' in probe['stderr_tail']
+    assert 'bogus' in probe['stderr_tail']
+
+
+def test_probe_backend_refuses_an_unasked_for_cpu(monkeypatch):
+    """JAX_PLATFORMS unset on a machine with no accelerator: JAX falls
+    back to the CPU with a warning; the probe (like every entry point)
+    refuses to call that healthy."""
+    monkeypatch.delenv('JAX_PLATFORMS')
+    probe = tpu_doctor.probe_backend(timeout_s=120.0)
+    assert not probe['ok']
+    assert probe['outcome'] == 'crashed'
+    assert 'no accelerator' in probe['stderr_tail']
 
 
 def test_doctor_report_verdict_without_probe():
     report = tpu_doctor.doctor_report(probe=False)
-    assert 'framework_processes' in report
-    assert 'relay' in report
-    assert 'listener_count_total' in report['relay']
-    assert 'verdict' not in report  # no probe ran: nothing to adjudicate
+    assert set(report) == {'framework_processes'}  # nothing to adjudicate
 
 
-def test_relay_state_sees_a_listener():
-    import socket
-    srv = socket.socket()
-    srv.bind(('127.0.0.1', 0))
-    srv.listen(1)
-    port = srv.getsockname()[1]
+def test_doctor_report_blames_live_framework_processes(monkeypatch):
+    alien = _spawn_marked(None)
+    monkeypatch.setenv('JAX_PLATFORMS', 'bogus-backend')
     try:
-        socks = tpu_doctor.tcp_sockets()
-        mine = [s for s in socks if s['state'] == 'LISTEN' and
-                s['local'].endswith(f':{port}')]
-        assert mine, f'listener on :{port} not found'
-        assert mine[0]['pid'] == os.getpid()
+        time.sleep(0.3)
+        report = tpu_doctor.doctor_report(probe_timeout_s=120.0)
     finally:
-        srv.close()
+        alien.kill()
+        alien.wait()
+    assert not report['probe']['ok']
+    assert 'may hold the chip' in report['verdict']
 
 
 def test_audit_clean_tool_flags_and_clears():
@@ -225,56 +177,3 @@ def test_audit_clean_tool_flags_and_clears():
     r = subprocess.run([sys.executable, 'tools/audit_clean.py'],
                        capture_output=True, text=True, timeout=60)
     assert str(alien.pid) not in r.stderr
-
-
-def test_bench_probe_diagnostics_assembled_on_failure(monkeypatch,
-                                                      tmp_path):
-    """A surrendered bench run must carry the full adjudication picture
-    (r3 verdict Next #1): per-attempt phases, final hang diagnosis,
-    process table, relay sockets. The probe children are HELD via the
-    injected hold-file gate (same determinism rig as
-    test_probe_backend_timeout_pins_phase): without it, a fast
-    scheduling window let a 0.05s-timeout child reach 'completed' and
-    flake the final_diagnosis assertion."""
-    import pathlib
-    sys.path.insert(0, str(pathlib.Path(__file__).parents[1]))
-    import bench
-    monkeypatch.setenv('SKYTPU_BENCH_PROBE_TIMEOUTS', '0.05,0.05')
-    gate = tmp_path / 'release-bench-probe-children'
-    monkeypatch.setenv('SKYTPU_PROBE_HOLD_FILE', str(gate))
-    bench._PROBE_DIAGNOSTICS.clear()
-    try:
-        assert bench._tpu_reachable() is False
-    finally:
-        gate.touch()  # release the detached children; they exit alone
-    d = bench._PROBE_DIAGNOSTICS
-    assert len(d['failed_attempts']) == 2
-    assert d['final_diagnosis'] and d['final_diagnosis'] != 'completed'
-    assert isinstance(d['process_table_clean'], bool)
-    assert 'listener_count_total' in d['relay']
-    assert 'framework_processes' in d
-
-
-def test_sigusr1_stack_dump_machinery():
-    """The probe child registers a faulthandler on SIGUSR1; verify the
-    same wiring dumps a stack from a hung child (what the artifact's
-    hang_stack carries)."""
-    child = subprocess.Popen(
-        [sys.executable, '-c',
-         'import faulthandler, signal, sys, time\n'
-         'faulthandler.register(signal.SIGUSR1, file=sys.stderr)\n'
-         'print("ready", flush=True)\n'
-         'time.sleep(60)'],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE)
-    try:
-        assert child.stdout.readline().strip() == b'ready'
-        child.send_signal(signal.SIGUSR1)
-        time.sleep(1.0)
-        child.kill()
-        _, err = child.communicate(timeout=10)
-        assert b'Thread' in err or b'Current thread' in err
-    finally:
-        try:
-            child.kill()
-        except OSError:
-            pass

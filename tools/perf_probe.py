@@ -2299,7 +2299,7 @@ def profile_probe() -> dict:
 
 def coldstart_probe() -> dict:
     """Cold-start collapse gate (persistent XLA compile cache + AOT
-    warm-up, serve/warmup.py + models/engine.maybe_enable_compile_cache),
+    warm-up, serve/warmup.py + utils/jax_env.enable_compile_cache),
     five legs over real OS-process replicas sharing one cache dir:
 
     (a) **cold boot, READY gated on coverage** — the FIRST 200 /health
