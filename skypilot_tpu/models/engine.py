@@ -103,11 +103,77 @@ from skypilot_tpu.observability import blackbox
 # machine-gated by perf_probe --profile). With SKYTPU_PROFILE off the
 # wrappers are passthroughs; on, the steady-state cost is two
 # thread-local writes per dispatch — skylint host-sync stays clean.
+from skypilot_tpu.observability import profiler
 from skypilot_tpu.observability.profiler import profiled_jit
 # Tier promote/demote spans for the trace waterfall; add_span is a
 # retroactive ring append — no I/O on the engine thread.
 from skypilot_tpu.observability import trace as trace_lib
 from skypilot_tpu.utils import prefix_affinity as affinity_lib
+
+
+class RequestTimeline:
+    """Where one request's time went inside the engine: stamps on
+    ``time.perf_counter()``, each ``None`` until the request reaches
+    it, taken where the request changes hands.
+
+    ``submit`` (``_build_request``) -> ``admit`` (it leaves the queue
+    for good, holding its slot and blocks, or starts its chunked
+    prefill) -> ``prefill`` (the dispatch of the program that computes
+    its last prompt token has returned) -> ``first`` (its first token
+    is handed over: on the host, just before callbacks fire) ->
+    ``last`` (retirement).
+    The phases between them are ``engine.queue``, ``engine.prep``,
+    ``engine.first_wait`` and ``engine.decode``; the first three
+    telescope to ``first - submit``. ``path`` is ``group`` / ``shared``
+    / ``long`` / ``import``, ``group`` the requests in its prefill
+    group, ``saved_tokens`` the prompt tokens a prefix cache served.
+
+    The engine thread alone writes it (``submit`` excepted, stamped
+    before the request is queued). Others read it once the future has
+    resolved; a streaming callback may read ``first`` when called. A
+    request that fails keeps the stamps it had reached."""
+    __slots__ = ('submit', 'admit', 'prefill', 'first', 'last', 'path',
+                 'group', 'saved_tokens')
+    PHASES = (('engine.queue', 'submit', 'admit'),
+              ('engine.prep', 'admit', 'prefill'),
+              ('engine.first_wait', 'prefill', 'first'),
+              ('engine.decode', 'first', 'last'))
+
+    def __init__(self, submit: float):
+        self.submit: float = submit
+        self.admit: Optional[float] = None
+        self.prefill: Optional[float] = None
+        self.first: Optional[float] = None
+        self.last: Optional[float] = None
+        self.path: Optional[str] = None
+        self.group = 0
+        self.saved_tokens = 0
+
+    def phases(self) -> List[tuple]:
+        """``(span name, start, end)`` of every phase the request has
+        closed, in order."""
+        out = []
+        for name, a, b in self.PHASES:
+            t0, t1 = getattr(self, a), getattr(self, b)
+            if t0 is None or t1 is None:
+                break
+            out.append((name, t0, t1))
+        return out
+
+    def admitted_at(self, now: float, path: str, group: int = 1) -> None:
+        """The ``admit`` stamp, with the way the request went."""
+        self.admit, self.path, self.group = now, path, group
+
+
+class EngineFuture(concurrent.futures.Future):
+    """What ``submit()`` / ``submit_prefill()`` / ``submit_import()``
+    return: a ``Future`` with one attribute more, ``timeline``, the
+    request's :class:`RequestTimeline`."""
+
+    def __init__(self, timeline: RequestTimeline):
+        super().__init__()
+        self.timeline = timeline
+
 
 @dataclasses.dataclass
 class _Request:
@@ -119,7 +185,7 @@ class _Request:
     row: List[int]
     max_new: int
     temperature: float
-    future: concurrent.futures.Future
+    future: EngineFuture
     tokens: List[int] = dataclasses.field(default_factory=list)
     on_tokens: Optional[object] = None
     top_k: int = 0        # 0 = off
@@ -138,6 +204,10 @@ class _Request:
     # request has parked on a background spill fetch — bounded so a
     # pathological spill state degrades to recompute, never a loop.
     tier_parks: int = 0
+
+    @property
+    def timeline(self) -> RequestTimeline:
+        return self.future.timeline
 
 
 @dataclasses.dataclass
@@ -485,7 +555,6 @@ class ContinuousEngine:
         '_unfetched': '_lock', '_slot_req': '_lock',
         '_tier_waiting': '_lock',
         'prefills': '_lock', 'failures': '_lock',
-        'prefill_groups': '_lock',
         'prefill_chunks': '_lock', 'prefix_hits': '_lock',
         'prefix_hit_tokens': '_lock', 'prefix_stores': '_lock',
         'share_hits': '_lock', 'share_hit_tokens': '_lock',
@@ -497,7 +566,6 @@ class ContinuousEngine:
         'peak_active': '_lock', 'spec_rounds': '_lock',
         'spec_proposals': '_lock', 'spec_accepted': '_lock',
         'exports': '_lock', 'imports': '_lock',
-        'export_ms': '_lock', 'import_ms': '_lock',
         'import_errors': '_lock', 'dispatches': '_lock',
         'host_overlap_ms': '_lock', 'bubble_ms': '_lock',
         '_gap_ms_total': '_lock', '_gap_count': '_lock',
@@ -753,7 +821,6 @@ class ContinuousEngine:
         # Stats (read by /health).
         self.prefills = 0
         self.failures = 0  # _fail_everything trips
-        self.prefill_groups = 0
         self.prefill_chunks = 0
         self.prefix_hits = 0
         self.prefix_hit_tokens = 0
@@ -781,8 +848,6 @@ class ContinuousEngine:
         # KV handoff accounting (disaggregated serving).
         self.exports = 0
         self.imports = 0
-        self.export_ms = 0.0
-        self.import_ms = 0.0
         self.import_errors = 0
         # Overlap observability (see stats()['pipeline']): host work
         # done while a chunk computes vs host time the device provably
@@ -798,7 +863,7 @@ class ContinuousEngine:
     def submit(self, row: List[int], max_new: int,
                temperature: float = 0.0, on_tokens=None,
                top_k: int = 0, top_p: float = 1.0,
-               eos=None) -> concurrent.futures.Future:
+               eos=None) -> EngineFuture:
         req = self._build_request(row, max_new, temperature, on_tokens,
                                   top_k, top_p, eos)
         with self._lock:
@@ -810,7 +875,7 @@ class ContinuousEngine:
     def submit_prefill(self, row: List[int], max_new: int,
                        temperature: float = 0.0, top_k: int = 0,
                        top_p: float = 1.0,
-                       eos=None) -> concurrent.futures.Future:
+                       eos=None) -> EngineFuture:
         """Prefill-role admission: compute the prompt's KV, sample the
         first token, and RETIRE — the future resolves with a
         ``PrefillHandoff`` a decode-role engine can import
@@ -842,7 +907,7 @@ class ContinuousEngine:
                       top_p: float = 1.0, eos=None, on_tokens=None,
                       layout: str = 'paged', block_start: int = 0,
                       k=None, v=None, k_s=None,
-                      v_s=None) -> concurrent.futures.Future:
+                      v_s=None) -> EngineFuture:
         """Decode-role admission of an imported prompt: install the
         transferred KV (paged: block scatter + table install; dense:
         row insert), emit ``first`` as the request's first token, and
@@ -993,7 +1058,7 @@ class ContinuousEngine:
             # (the HTTP layer already normalizes; don't re-build)
             eos = frozenset([eos] if isinstance(eos, int) else
                             (int(t) for t in eos))
-        fut: concurrent.futures.Future = concurrent.futures.Future()
+        fut = EngineFuture(RequestTimeline(time.perf_counter()))
         # Engine futures are UNCANCELLABLE (state RUNNING from birth): a
         # client disconnect cancelling a PENDING future would flip it
         # done, making the emission loop skip the slot forever (slot +
@@ -1074,16 +1139,12 @@ class ContinuousEngine:
             return {'slots': self.slots, 'active_slots': active,
                 'kv_cache': 'int8' if self.kv_quantize else 'bf16',
                 'kv_layout': self.kv_layout,
-                # Disaggregated-serving role + handoff accounting
-                # (serve/disagg.py): exports are prefill-role
-                # retirements, imports are decode-role admissions of
-                # transferred tables; queued_imports is the decode
-                # pool's admission backpressure signal.
-                'role': self.role,
+                # Handoff accounting (serve/disagg.py): exports are
+                # prefill-role retirements, imports are decode-role
+                # admissions of transferred tables; queued_imports is
+                # the decode pool's admission backpressure signal.
                 'disagg': {'exports': self.exports,
                            'imports': self.imports,
-                           'export_ms': round(self.export_ms, 3),
-                           'import_ms': round(self.import_ms, 3),
                            'import_errors': self.import_errors,
                            'queued_imports': queued_imports},
                 'kv_blocks': (None if self.kv_layout != 'paged' else {
@@ -1114,18 +1175,13 @@ class ContinuousEngine:
                     'host': (tier_stats['host_blocks']
                              if tier_stats else 0),
                     'spilled': (tier_stats['spilled_blocks']
-                                if tier_stats else 0),
-                    'cow_forks': self.cow_forks}),
+                                if tier_stats else 0)}),
                 'kv_tiers': tier_stats,
                 'queued': queued, 'prefills': self.prefills,
                 'failures': self.failures,
-                'prefill_groups': self.prefill_groups,
-                'prefill_batch': self.prefill_batch,
-                'prefill_chunk': self.prefill_chunk,
                 'prefill_chunks': self.prefill_chunks,
                 'prefilling': len(self._prefilling),
                 'chunks_run': self.chunks_run,
-                'chunk_steps': self.chunk_steps,
                 'tokens_emitted': self.tokens_emitted,
                 'peak_active_slots': self.peak_active,
                 # Decode-dispatch pipeline: depth 1 = one chunk kept in
@@ -1143,10 +1199,8 @@ class ContinuousEngine:
                     'host_overlap_ms': round(self.host_overlap_ms, 3),
                     'bubble_ms': round(self.bubble_ms, 3)},
                 'speculative': None if self.draft_cfg is None else {
-                    'k': self.spec_k,
                     'rounds': self.spec_rounds,
                     'proposals': self.spec_proposals,
-                    'accepted': self.spec_accepted,
                     'acceptance_rate': (
                         self.spec_accepted / self.spec_proposals
                         if self.spec_proposals else 0.0)},
@@ -1173,9 +1227,7 @@ class ContinuousEngine:
                         / max(self.share_hits + self.share_misses, 1), 4),
                     'commits': self.share_commits,
                     'evictions': self.share_evictions,
-                    'cow_forks': self.cow_forks,
-                    'shared_blocks': shared_blocks,
-                    'cached_blocks': cached_blocks},
+                    'cow_forks': self.cow_forks},
                 'prefill_tokens': self.prefill_tokens,
                 'prefill_tokens_saved': self.prefill_tokens_saved,
                 'prefill_ms': round(self.prefill_ms, 3),
@@ -1223,8 +1275,7 @@ class ContinuousEngine:
                     # the loop re-checks _pending at the top either
                     # way, so a sleeping replica admits immediately
                     # instead of burning a core on a 50 ms poll.
-                    self._wake.wait(_IDLE_WAIT_S)
-                    self._wake.clear()
+                    self._idle_wait(_IDLE_WAIT_S)
                     continue
                 with self._lock:
                     only_exports = all(r is None or r.export
@@ -1251,6 +1302,12 @@ class ContinuousEngine:
                 self._fail_everything(exc)
                 self._wake.wait(0.1)
                 self._wake.clear()
+
+    def _idle_wait(self, timeout: float) -> None:
+        """Nothing to do until a submit() or ``timeout``."""
+        with profiler.span('engine.idle'):
+            self._wake.wait(timeout)
+        self._wake.clear()
 
     # skylint: engine-thread
     def _fail_everything(self, exc: Exception) -> None:
@@ -1367,7 +1424,6 @@ class ContinuousEngine:
         # residue (allocator in_use minus logical) stays the
         # leak/fragmentation signal. Host-side .nbytes attribute reads
         # over already-allocated buffers — no device sync.
-        from skypilot_tpu.observability import profiler
         profiler.register_logical('kv_cache',
                                   profiler.tree_nbytes(self._cache))
         if self._d_cache is not None:
@@ -1525,16 +1581,37 @@ class ContinuousEngine:
         which would fail every other client's in-flight request and
         rebuild the device cache."""
         for req, new in emitted:
+            if req.on_tokens is None:
+                continue
             try:
                 req.on_tokens(new)
             except Exception:  # noqa: BLE001 — isolate per request
                 req.on_tokens = None  # stop notifying the dead consumer
+
+    # skylint: engine-thread
+    def _emit(self, emitted: List[tuple], done=()) -> None:
+        """Hand new tokens to their streams, then resolve the requests
+        that retire here (``done``; ``last`` is stamped before either,
+        so whoever the future wakes finds the timeline whole).
+        ``engine.callbacks`` is user code running on the engine
+        thread."""
+        if not emitted and not done:
+            return
+        now = time.perf_counter()
+        for req in done:
+            req.timeline.last = now
+        with profiler.span('engine.callbacks'):
+            self._fire_callbacks(emitted)
+            for req in done:
+                if not req.future.done():
+                    req.future.set_result(req.tokens)
 
     def _next_key(self) -> jax.Array:
         self._key, sub = jax.random.split(self._key)
         return sub
 
     # skylint: engine-thread
+    @profiler.spanned('engine.admit')
     def _admit(self) -> None:
         """Prefill pending requests into free slots, in power-of-two
         GROUPS: one padded [N, S] forward + one scatter insert per group.
@@ -1556,8 +1633,9 @@ class ContinuousEngine:
                 while (self.prefill_chunk and self._pending
                        and len(self._prefilling) < 2
                        and len(self._pending[0].row) > self.prefill_chunk):
-                    self._prefilling.append(
-                        _Prefilling(self._pending.popleft()))
+                    long = self._pending.popleft()
+                    long.timeline.admitted_at(time.perf_counter(), 'long')
+                    self._prefilling.append(_Prefilling(long))
                 if (self.prefill_chunk and self._pending
                         and len(self._pending[0].row) > self.prefill_chunk):
                     return  # long head waiting on prefill capacity
@@ -1651,6 +1729,8 @@ class ContinuousEngine:
                         owned = self._alloc_blocks(need)
                         slot = free_s[0]
                         self._pending.popleft()
+                        head.timeline.admitted_at(time.perf_counter(),
+                                                'shared')
                         self._slot_req[slot] = head
                         self._slot_blocks[slot] = list(owned)
                         self._slot_shared[slot] = list(nodes)
@@ -1715,6 +1795,9 @@ class ContinuousEngine:
                     while g * 2 <= n:
                         g *= 2
                     reqs = [self._pending.popleft() for _ in range(g)]
+                    now = time.perf_counter()
+                    for r in reqs:
+                        r.timeline.admitted_at(now, 'group', g)
                     # Mid-prefill requests live in NO other structure —
                     # a device failure here must still fail their
                     # futures.
@@ -1733,6 +1816,7 @@ class ContinuousEngine:
                             prompt_len=max(len(r.row) for r in reqs))
 
     # skylint: engine-thread
+    @profiler.spanned('engine.admit_shared')
     def _admit_shared(self, req: _Request, slot: int, nodes: list,
                       partial, plen: int, owned: List[int],
                       pro: Optional[list] = None) -> None:
@@ -1815,6 +1899,8 @@ class ContinuousEngine:
             self.cfg, self.params, self._cache, padded, table[None],
             jnp.int32(slot), np.asarray([covered], np.int32),
             np.asarray([len(suffix)], np.int32), self._shard_ctx)
+        req.timeline.prefill = time.perf_counter()
+        req.timeline.saved_tokens = covered
         first = _jit_sample(
             logits, np.asarray([req.temperature], np.float32),
             self._next_key(),
@@ -1839,7 +1925,6 @@ class ContinuousEngine:
             # lock while /health snapshots them — fold into the commit
             # critical section.
             self.prefills += 1
-            self.prefill_groups += 1
             self.share_hits += 1
             self.share_hit_tokens += covered
             self.prefill_tokens += len(suffix)
@@ -1967,10 +2052,11 @@ class ContinuousEngine:
             return
         t0 = time.perf_counter()
         had_active = any(r is not None for r in self._slot_req)
-        try:
-            self._advance_prefill_impl()
-        finally:
-            self._note_prefill_time(t0, had_active)
+        with profiler.span('engine.advance_prefill'):
+            try:
+                self._advance_prefill_impl()
+            finally:
+                self._note_prefill_time(t0, had_active)
 
     # skylint: engine-thread
     def _advance_prefill_impl(self) -> None:
@@ -2045,6 +2131,7 @@ class ContinuousEngine:
                 cache1 = gen_lib.init_cache(self.cfg, 1, self.max_len,
                                             quantize=self.kv_quantize)
             entry.cache, entry.consumed = cache1, p_hit
+            req.timeline.saved_tokens = p_hit
             if spec:
                 entry.d_cache = gen_lib.init_cache(
                     self.draft_cfg, 1, self.max_len,
@@ -2054,6 +2141,7 @@ class ContinuousEngine:
         with self._lock:
             self.prefill_chunks += 1
         if entry.consumed >= n:
+            req.timeline.prefill = time.perf_counter()
             if self._prefix_pool is not None:
                 # Store this prompt's bucket prefix on its second
                 # sighting, like the grouped path (cache row 0 holds
@@ -2068,10 +2156,12 @@ class ContinuousEngine:
                 *_filters_or_none(np.asarray([req.top_k], np.int32),
                                   np.asarray([req.top_p], np.float32)))
             entry.first = first
-            # skylint: allow-host-sync(designed fetch point — one scalar
-            # first token at long-prefill retirement, the chunked path's
-            # only sync; EOS/export routing needs the host value now)
-            entry.first_host = int(jax.device_get(first)[0])
+            with profiler.span('engine.wait_firsts'):
+                # skylint: allow-host-sync(designed fetch point — one
+                # scalar first token at long-prefill retirement, the
+                # chunked path's only sync; EOS/export routing needs the
+                # host value now)
+                entry.first_host = int(jax.device_get(first)[0])
             self._finish_long_prefill(entry)
 
     # skylint: engine-thread
@@ -2112,12 +2202,10 @@ class ContinuousEngine:
             self._prefilling.pop(0)
             self.prefills += 1
             req.tokens.append(entry.first_host)
+            req.timeline.first = time.perf_counter()
             self.tokens_emitted += 1
-        if req.on_tokens is not None:
-            self._fire_callbacks([(req, [entry.first_host])])
+        self._emit([(req, [entry.first_host])], [req] if done else [])
         if done:
-            if not req.future.done():
-                req.future.set_result(req.tokens)
             return
         if self.kv_layout == 'paged':
             from skypilot_tpu.models import paged as paged_lib
@@ -2182,6 +2270,7 @@ class ContinuousEngine:
         self._export_and_retire(req, entry.first_host)
 
     # skylint: engine-thread
+    @profiler.spanned('engine.prefill_group')
     def _prefill_group(self, reqs: List[_Request],
                        slots: List[int]) -> None:
         t0 = time.perf_counter()
@@ -2238,6 +2327,10 @@ class ContinuousEngine:
         logits, cache_n = gen_lib._jit_prefill(  # noqa: SLF001 — same pkg
             self.params, padded, cache_n, self.cfg,
             np.asarray(lens))
+        now = time.perf_counter()
+        for r, p in zip(reqs, p_lens):
+            r.timeline.prefill = now
+            r.timeline.saved_tokens = p
         with self._lock:
             self.prefill_tokens += int(lens.sum())
             self.prefill_tokens_saved += sum(p_lens)
@@ -2313,7 +2406,6 @@ class ContinuousEngine:
                 np.asarray(slots, np.int32))
         with self._lock:
             self.prefills += n
-            self.prefill_groups += 1
             self._unfetched.append((reqs, firsts))
             for i, req in enumerate(reqs):
                 if req.export and self.kv_layout != 'paged':
@@ -2327,6 +2419,7 @@ class ContinuousEngine:
         self._note_prefill_time(t0, had_active)
 
     # skylint: engine-thread
+    @profiler.spanned('engine.drain_firsts')
     def _drain_firsts(self) -> None:
         """Materialize deferred first tokens. MUST run before a chunk's
         emission so every admitted request's token list starts with its
@@ -2338,10 +2431,12 @@ class ContinuousEngine:
         emitted: List[tuple] = []
         exports: List[tuple] = []
         for reqs, firsts in batches:
-            # skylint: allow-host-sync(designed deferred fetch point —
-            # first tokens batched per prefill group and fetched while
-            # the next chunk runs on-device, per the pipeline contract)
-            firsts_host = np.asarray(jax.device_get(firsts))
+            with profiler.span('engine.wait_firsts'):
+                # skylint: allow-host-sync(designed deferred fetch point
+                # — first tokens batched per prefill group and fetched
+                # while the next chunk runs on-device, per the pipeline
+                # contract)
+                firsts_host = np.asarray(jax.device_get(firsts))
             with self._lock:
                 for i, req in enumerate(reqs):
                     first = int(firsts_host[i])
@@ -2368,10 +2463,15 @@ class ContinuousEngine:
                                     self._slot_req[si] = None
                                     self._release_blocks(si)
                                     break
-        self._fire_callbacks(emitted)
-        for req in done:
-            if not req.future.done():
-                req.future.set_result(req.tokens)
+        # One stamp for all of them, here and not at each fetch: a
+        # token is handed to its stream only once the LAST batch has
+        # been fetched, and the wait for the later batches' prefills
+        # is part of the earlier requests' wait for their first token.
+        now = time.perf_counter()
+        for reqs, _ in batches:
+            for req in reqs:
+                req.timeline.first = now
+        self._emit(emitted, done)
         for req, first in exports:
             self._export_and_retire(req, first)
 
@@ -2395,14 +2495,15 @@ class ContinuousEngine:
                     self._release_blocks(si)
                     break
         req.export_src = None  # drop the dense prefill-cache reference
-        with self._lock:
-            self.export_ms += (time.perf_counter() - t0) * 1e3
-            if handoff is not None:
-                self.exports += 1
+        if req.timeline.first is None:  # the chunked long-prefill path
+            req.timeline.first = t0
         if handoff is None:
             if not req.future.done():
                 req.future.set_exception(err)
             return
+        with self._lock:
+            self.exports += 1
+        req.timeline.last = time.perf_counter()
         if not req.future.done():
             req.future.set_result(handoff)
 
@@ -2455,6 +2556,7 @@ class ContinuousEngine:
                               k=k, v=v, k_s=k_s, v_s=v_s, **base)
 
     # skylint: engine-thread
+    @profiler.spanned('engine.admit_imports')
     def _admit_imports(self) -> None:
         """Install queued imported prompts (decode-role admission),
         FIFO. Each head needs a free slot plus its FULL block
@@ -2466,7 +2568,6 @@ class ContinuousEngine:
         genuinely new blocks scatter."""
         from skypilot_tpu.models import paged as paged_lib
         while True:
-            t0 = time.perf_counter()
             doomed = None
             with self._lock:
                 if not self._pending_imports:
@@ -2528,21 +2629,25 @@ class ContinuousEngine:
                         self._pending_imports.popleft()
                 else:
                     self._pending_imports.popleft()
+                if doomed is None:
+                    # The prefill was another engine's: nothing to prep.
+                    req.timeline.admitted_at(time.perf_counter(), 'import')
+                    req.timeline.prefill = req.timeline.admit
+                    req.timeline.saved_tokens = len(nodes) * self.kv_block
             if doomed is not None:
                 if not doomed.future.done():
                     doomed.future.set_exception(KVImportError(
                         'handoff blocks negotiated as shared references '
                         'were evicted before import'))
                 continue
+            emitted = [(req, [entry.first])]
             if trivial:
                 req.tokens.append(entry.first)
+                req.timeline.first = time.perf_counter()
                 with self._lock:
                     self.tokens_emitted += 1
                     self.imports += 1
-                if req.on_tokens is not None:
-                    self._fire_callbacks([(req, [entry.first])])
-                if not req.future.done():
-                    req.future.set_result(req.tokens)
+                self._emit(emitted, [req])
                 continue
             # Device install (outside the lock: submit() must not wait
             # on a scatter dispatch).
@@ -2560,12 +2665,11 @@ class ContinuousEngine:
                     else:
                         self.share_misses += 1
             req.tokens.append(entry.first)
+            req.timeline.first = time.perf_counter()
             with self._lock:
                 self.tokens_emitted += 1
                 self.imports += 1
-                self.import_ms += (time.perf_counter() - t0) * 1e3
-            if req.on_tokens is not None:
-                self._fire_callbacks([(req, [entry.first])])
+            self._emit(emitted)
 
     # skylint: engine-thread
     def _install_import_paged(self, entry: _ImportEntry, slot: int,
@@ -2679,12 +2783,14 @@ class ContinuousEngine:
         # ONE fused fetch: three sequential device_gets would pay three
         # host↔device relay round trips per round; the tuple transfer
         # pays one.
-        # skylint: allow-host-sync(designed fetch point — the spec
-        # round's single fused result transfer; acceptance bookkeeping
-        # needs host values before the next round can be shaped)
-        props_h, tgt_h, samp_h = (
-            np.asarray(a)
-            for a in jax.device_get((props, tgt, samp)))
+        with profiler.span('engine.wait_chunk'):
+            # skylint: allow-host-sync(designed fetch point — the spec
+            # round's single fused result transfer; acceptance
+            # bookkeeping needs host values before the next round can
+            # be shaped)
+            props_h, tgt_h, samp_h = [
+                np.asarray(a)
+                for a in jax.device_get((props, tgt, samp))]
         # props_h/tgt_h: [B, k+1]; samp_h: [B]
         with self._lock:
             self.spec_rounds += 1
@@ -2739,10 +2845,7 @@ class ContinuousEngine:
         self._cache = _jit_rewind(t_cache, adj_dev)
         self._d_cache = _jit_rewind(d_cache, adj_dev)
         self._last = last_dev
-        self._fire_callbacks(emitted)
-        for req in done:
-            if not req.future.done():
-                req.future.set_result(req.tokens)
+        self._emit(emitted, done)
 
     # skylint: engine-thread
     def _run_chunk(self) -> None:
@@ -2766,6 +2869,7 @@ class ContinuousEngine:
             self._flush_pipeline()
 
     # skylint: engine-thread
+    @profiler.spanned('engine.dispatch_chunk')
     def _dispatch_chunk(self) -> _Inflight:
         """Issue (async) one K-step decode chunk over ALL slots against
         the current slot snapshot. Dispatch and retirement strictly
@@ -2856,6 +2960,7 @@ class ContinuousEngine:
             self._no_flight_since = time.perf_counter()
 
     # skylint: engine-thread
+    @profiler.spanned('engine.retire_chunk')
     def _retire_chunk(self, flight: _Inflight,
                       quiet: bool = False) -> None:
         """Fetch a dispatched chunk's tokens and run all host-side
@@ -2867,10 +2972,11 @@ class ContinuousEngine:
         # token (and a first-token-eos resolved here frees its slot
         # before this chunk's junk for it could be appended).
         self._drain_firsts()
-        # skylint: allow-host-sync(designed fetch point — THE chunk
-        # result transfer; under pipelining it lands while the next
-        # chunk computes, which is the whole overlap design)
-        toks_host = np.asarray(jax.device_get(flight.toks))  # [K, B]
+        with profiler.span('engine.wait_chunk'):
+            # skylint: allow-host-sync(designed fetch point — THE chunk
+            # result transfer; under pipelining it lands while the next
+            # chunk computes, which is the whole overlap design)
+            toks_host = np.asarray(jax.device_get(flight.toks))  # [K, B]
         t0 = time.perf_counter()
         with self._lock:
             self.chunks_run += 1
@@ -2903,10 +3009,8 @@ class ContinuousEngine:
                     self._slot_req[i] = None
                     self._release_blocks(i)
                     done.append(req)
-        self._fire_callbacks(emitted)
+        self._emit(emitted, done)
         for req in done:
-            if not req.future.done():
-                req.future.set_result(req.tokens)
             # Counts only — token ids/prompt text never enter the ring
             # (the bundle redaction contract).
             blackbox.record('engine.retire', emitted=len(req.tokens),

@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -424,6 +425,31 @@ def profiled_jit(name: str, fn, **jit_kwargs):
     return wrapper
 
 
+def span(name: str):
+    """A span on the device trace's clock: the ONE place the program
+    opens ``jax.profiler.TraceAnnotation``. Always on, no switch: with
+    no profile being taken it costs the tracer's flag test, and while
+    one is (``jax.profiler.start_trace``, or the profiler server's
+    capture) the span lands in the host plane beside the device's
+    operations, so an idle gap on the chip can be laid to what the host
+    was doing. Not the request waterfall of ``observability/trace.py``,
+    which is on the wall clock and sampled per request."""
+    import jax
+    return jax.profiler.TraceAnnotation(name)
+
+
+def spanned(name: str):
+    """Decorator: the whole call under :func:`span` (looked up at call
+    time, so a test can swap ``span`` for a recorder)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
 # program name -> last wrapper built for it (bounded by the registry).
 # The warm-up driver's coverage fallback: with SKYTPU_PROFILE off the
 # compile ledger stays empty, but a compile still grows the jitted
@@ -673,6 +699,41 @@ def try_snapshot() -> Optional[Dict[str, Any]]:
         return snapshot()
     except Exception:  # noqa: BLE001 — bundles must never fail to dump
         return None
+
+
+_DEVICE_TRACE_MAX_S = 30.0
+
+
+def device_trace(seconds: Any) -> Dict[str, Any]:
+    """Take a ``jax.profiler`` trace of THIS process for ``seconds``
+    (0.1 to 30) into a new directory, and say where it is: the device's
+    operations with, in the host plane, every :func:`span` the program
+    opened meanwhile. Only the process that holds the chip can trace
+    it, and one trace at a time: a second call meanwhile is refused,
+    as is a ``seconds`` that is no number. The Python tracer stays off
+    (it slows the host it is measuring)."""
+    import tempfile
+
+    import jax
+    try:
+        seconds = min(max(float(seconds), 0.1), _DEVICE_TRACE_MAX_S)
+    except ValueError:
+        return {'error': 'device_trace takes a number of seconds'}
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    out = tempfile.mkdtemp(prefix='skytpu-device-trace-')
+    try:
+        # skylint: allow-leak(jax's profiler, not observability/trace's
+        # start_trace: the stop_trace in the finally below ends it)
+        jax.profiler.start_trace(out, profiler_options=opts)
+    except RuntimeError as e:  # a trace is already being taken
+        os.rmdir(out)
+        return {'error': str(e)}
+    try:
+        time.sleep(seconds)
+    finally:
+        jax.profiler.stop_trace()
+    return {'dir': out, 'seconds': seconds}
 
 
 def debug_payload(query: Any) -> Dict[str, Any]:

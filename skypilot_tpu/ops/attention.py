@@ -223,6 +223,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
             jax.ShapeDtypeStruct((b, hq, s, 1), jnp.float32),
         ],
         interpret=interpret,
+        name='flash_fwd',
     )(q, k, v)
 
 
@@ -379,6 +380,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool = False):
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name='flash_dq',
     )(q, k, v, do, lse, delta)
 
     # Reshape per-q-head tensors to [B, Hkv, group, ...] so the kv-grid
@@ -423,6 +425,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, interpret: bool = False):
             jax.ShapeDtypeStruct(v.shape, jnp.float32),
         ],
         interpret=interpret,
+        name='flash_dkv',
     )(qg, k, v, dog, lseg, deltag)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 

@@ -168,11 +168,18 @@ class _ChunkRecorder:
     is one ``time.time()`` plus a tuple append — spans and histogram
     observations are built AFTER the request completes, so the decode
     loop never blocks on observability."""
-    __slots__ = ('t0', 'events')
+    __slots__ = ('t0', 'events', 'timelines')
 
     def __init__(self):
         self.t0 = time.time()
         self.events: List = []  # (t, row_index, n_tokens)
+        self.timelines: Dict[int, Any] = {}  # row_index -> RequestTimeline
+
+    def submitted(self, ri: int, fut):
+        """Keep the engine's own timeline of row ``ri`` (it rides on
+        the future; a stub engine's future has none). Returns ``fut``."""
+        self.timelines[ri] = getattr(fut, 'timeline', None)
+        return fut
 
     def cb(self, ri: int):
         events = self.events
@@ -824,7 +831,8 @@ class LlmServer:
                            ttft_ms=round(ttft * 1000.0, 3), tokens=toks)
         # "prefill" here is submit -> first emission: engine queue time
         # plus the actual prefill plus the first decode chunk — the TTFT
-        # phase a serving operator tunes.
+        # phase a serving operator tunes. Its children (below) say which
+        # of the three it was.
         pipe1 = self._pipeline_stats()
         pattrs: Dict[str, Any] = {'tokens': events[0][2]}
         if pipe0 and pipe1 and 'share_hits' in pipe1:
@@ -836,8 +844,23 @@ class LlmServer:
                 d = (pipe1.get(k) or 0) - (pipe0.get(k) or 0)
                 if d:
                     pattrs[k] = d
-        trace_lib.add_span('serve.prefill', rec.t0, first_t,
-                           parent=anchor, **pattrs)
+        prefill_span = trace_lib.add_span('serve.prefill', rec.t0, first_t,
+                                          parent=anchor, **pattrs)
+        line = rec.timelines.get(events[0][1])
+        if line is not None:
+            # The engine stamps on perf_counter, the waterfall is on the
+            # wall clock: one offset for the request, and each child cut
+            # to its parent so the nesting invariant survives the two
+            # clocks' jitter.
+            to_wall = time.time() - time.perf_counter()
+            for name, t0, t1 in line.phases():
+                if name == 'engine.decode':
+                    break  # serve.decode, on the stream's own stamps
+                trace_lib.add_span(
+                    name, min(max(t0 + to_wall, rec.t0), first_t),
+                    min(max(t1 + to_wall, rec.t0), first_t),
+                    parent=prefill_span, path=line.path, group=line.group,
+                    saved_tokens=line.saved_tokens)
         dattrs: Dict[str, Any] = {'tokens': toks}
         if pipe0 and pipe1:
             # The engine's overlap counters are cumulative across ALL
@@ -898,10 +921,10 @@ class LlmServer:
         # request is sampled (the spans are the only consumer of pipe0).
         pipe0 = (self._pipeline_stats()
                  if trace_lib.current() is not None else None)
-        futs = [asyncio.wrap_future(
-            self.engine.submit(r, max_new, temperature, top_k=top_k,
-                               top_p=top_p, eos=eos,
-                               on_tokens=rec.cb(i)))
+        futs = [asyncio.wrap_future(rec.submitted(
+            i, self.engine.submit(r, max_new, temperature, top_k=top_k,
+                                  top_p=top_p, eos=eos,
+                                  on_tokens=rec.cb(i))))
                 for i, r in enumerate(rows)]
         out = await asyncio.gather(*futs)
         self._observe_serving(rec, qos_class, pipe0)
@@ -1186,10 +1209,10 @@ class LlmServer:
                 # not loop-drain time), then hand off to the writer.
                 rec.events.append((time.time(), ri, len(toks)))
                 loop.call_soon_threadsafe(q.put_nowait, (ri, toks))
-            futs.append(asyncio.wrap_future(
-                self.engine.submit(row, max_new, temperature,
-                                   on_tokens=cb, top_k=top_k,
-                                   top_p=top_p, eos=eos)))
+            futs.append(asyncio.wrap_future(rec.submitted(
+                ri, self.engine.submit(row, max_new, temperature,
+                                       on_tokens=cb, top_k=top_k,
+                                       top_p=top_p, eos=eos))))
         resp = web.StreamResponse()
         resp.content_type = 'application/x-ndjson'
         await resp.prepare(request)
@@ -1545,8 +1568,8 @@ class LlmServer:
             if stream:
                 return await self._kv_import_stream(request, kwargs,
                                                     data, rec, t0)
-            fut = self.engine.submit_import(on_tokens=rec.cb(0),
-                                            **kwargs)
+            fut = rec.submitted(0, self.engine.submit_import(
+                on_tokens=rec.cb(0), **kwargs))
             tokens = await asyncio.wrap_future(fut)
         except ValueError as e:
             self.disagg_stats['import_rejects'] += 1
@@ -1581,8 +1604,8 @@ class LlmServer:
             rec.events.append((time.time(), 0, len(toks)))
             loop.call_soon_threadsafe(q.put_nowait, toks)
 
-        fut = asyncio.wrap_future(
-            self.engine.submit_import(on_tokens=cb, **kwargs))
+        fut = asyncio.wrap_future(rec.submitted(
+            0, self.engine.submit_import(on_tokens=cb, **kwargs)))
         # The first failure mode (evicted negotiated blocks) surfaces at
         # admission — wait for either the first emission or the future,
         # so a doomed import still gets its 409 instead of a broken
@@ -1708,14 +1731,25 @@ class LlmServer:
         """Runtime-profiler state (observability/profiler.py): compile
         ledger, device-memory accounting, cold-start phases.
         ``?programs=1`` appends the PROGRAMS catalog, ``?mem=1`` forces
-        a fresh memory sample. Same scrape-token gate as /metrics;
-        off-loop — a forced memory sample queries every device
-        allocator."""
+        a fresh memory sample, ``?device_trace=<seconds>`` first takes
+        a ``jax.profiler`` trace of this replica (the engine's spans
+        beside the device's operations; docs/operations.md) and says
+        where it is. Same scrape-token gate as /metrics; off-loop — a
+        forced memory sample queries every device allocator, a trace
+        lasts its seconds."""
         if not self._scrape_authorized(request):
             return web.json_response({'error': 'unauthorized'},
                                      status=401)
-        payload = await asyncio.get_event_loop().run_in_executor(
+        loop = asyncio.get_event_loop()
+        traced = None
+        if request.query.get('device_trace'):
+            traced = await loop.run_in_executor(
+                None, profiler.device_trace,
+                request.query['device_trace'])
+        payload = await loop.run_in_executor(
             None, profiler.debug_payload, dict(request.query))
+        if traced is not None:
+            payload['device_trace'] = traced
         return web.json_response(payload)
 
     async def debug_exemplars(self, request: web.Request) -> web.Response:

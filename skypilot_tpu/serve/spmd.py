@@ -189,8 +189,7 @@ class SpmdEngine(ContinuousEngine):
                             and not self._pending:
                         # Idle pacing lives on the head; followers pace
                         # on the broadcast itself.
-                        self._wake.wait(0.02)
-                        self._wake.clear()
+                        self._idle_wait(0.02)
             except Exception as exc:  # noqa: BLE001 — fail local waiters
                 # Same recovery as the parent loop. NOTE: only an error
                 # raised deterministically on EVERY rank (shape bug,

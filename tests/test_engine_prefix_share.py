@@ -311,12 +311,11 @@ def test_stats_surface_share_counters(tiny):
         st = eng.stats()
         kb = st['kv_blocks']
         for key in ('free', 'usable', 'used', 'owned', 'shared',
-                    'cached', 'host', 'spilled', 'cow_forks'):
+                    'cached', 'host', 'spilled'):
             assert key in kb, kb
         ps = st['prefix_share']
         for key in ('enabled', 'hits', 'misses', 'hit_rate',
-                    'hit_tokens', 'commits', 'evictions', 'cow_forks',
-                    'shared_blocks', 'cached_blocks'):
+                    'hit_tokens', 'commits', 'evictions', 'cow_forks'):
             assert key in ps, ps
         assert 'prefill_tokens' in st and 'prefill_tokens_saved' in st
         assert 'prefill_bubble_ms' in st

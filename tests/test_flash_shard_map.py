@@ -6,6 +6,7 @@ and the train loss must come out the same through it."""
 import dataclasses
 import functools
 import os
+import re
 import subprocess
 import sys
 
@@ -115,3 +116,20 @@ def test_decode_kernel_value_is_validated():
         env={**os.environ, 'SKYTPU_DECODE_KERNEL': 'on'},
         capture_output=True, text=True, timeout=120)
     assert r.returncode != 0 and "'pallas' or 'interpret'" in r.stderr
+
+
+def test_the_three_kernels_carry_their_names_into_the_program():
+    """``name=`` on each ``pallas_call`` opens a scope of that name, and
+    on the chip the compiler names the custom call after it
+    (``%flash_fwd.1 = ... custom-call(``): what the device trace and the
+    benchmark's breakdown then show."""
+    q = jnp.zeros((1, 2, 128, 64), jnp.bfloat16)
+    kv = jnp.zeros((1, 1, 128, 64), jnp.bfloat16)
+
+    def loss(q_, k_, v_):
+        return attention.flash_attention(
+            q_, k_, v_, True, interpret=True).astype(jnp.float32).sum()
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
+    for name in ('flash_fwd', 'flash_dq', 'flash_dkv'):
+        assert re.search(rf'\bname={name}\b', jaxpr), name

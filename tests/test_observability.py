@@ -294,45 +294,62 @@ def test_debug_payload_filters(traced):
     assert p['count'] == 1
 
 
-def test_llm_server_traces_serving_phases(traced, monkeypatch):
-    """HTTP-level: a QoS-on replica (stub engine that emits chunk
-    callbacks) produces a serve.generate trace whose phases cover
-    queue-wait -> prefill -> decode, and whose histograms fill — no
-    real jax decode needed."""
+class _ChunkyEngine:
+    """Stub engine emitting two chunks through on_tokens. With
+    ``timelines``, its futures are the engine's own kind and carry the
+    stamps a real engine would have taken."""
+    slots = 4
+
+    def __init__(self, timelines=False):
+        self.timelines = timelines
+
+    def submit(self, row, max_new, temperature=0.0, top_k=0,
+               top_p=1.0, eos=None, on_tokens=None):
+        import concurrent.futures as cf
+        import threading
+        fut = cf.Future()
+        if self.timelines:
+            from skypilot_tpu.models import engine as engine_lib
+            line = engine_lib.RequestTimeline(time.perf_counter())
+            fut = engine_lib.EngineFuture(line)
+
+        def run():
+            half = max(max_new // 2, 1)
+            if self.timelines:
+                time.sleep(0.02)
+                line.admitted_at(time.perf_counter(), 'shared')
+                line.saved_tokens = 2
+                time.sleep(0.005)
+                line.prefill = time.perf_counter()
+                time.sleep(0.02)
+                line.first = time.perf_counter()
+            if on_tokens is not None:
+                on_tokens([1] * half)
+                time.sleep(0.01)
+                on_tokens([1] * (max_new - half))
+            if self.timelines:
+                line.last = time.perf_counter()
+            fut.set_result([1] * max_new)
+
+        threading.Thread(target=run, daemon=True).start()
+        return fut
+
+    def stats(self):
+        return {'slots': self.slots}
+
+    def stop(self):
+        pass
+
+
+def _serve_stub(engine, base_port):
+    """A QoS-on replica over ``engine`` on a port of its own; its URL."""
     import asyncio
-    import concurrent.futures as cf
     import threading
 
-    import requests as requests_lib
     from aiohttp import web
 
     from skypilot_tpu.serve import llm_server as llm_mod
     from skypilot_tpu.utils import common_utils
-
-    class ChunkyEngine:
-        """Stub engine emitting two chunks through on_tokens."""
-        slots = 4
-
-        def submit(self, row, max_new, temperature=0.0, top_k=0,
-                   top_p=1.0, eos=None, on_tokens=None):
-            fut: cf.Future = cf.Future()
-
-            def run():
-                half = max(max_new // 2, 1)
-                if on_tokens is not None:
-                    on_tokens([1] * half)
-                    time.sleep(0.01)
-                    on_tokens([1] * (max_new - half))
-                fut.set_result([1] * max_new)
-
-            threading.Thread(target=run, daemon=True).start()
-            return fut
-
-        def stats(self):
-            return {'slots': self.slots}
-
-        def stop(self):
-            pass
 
     server = llm_mod.LlmServer(
         'tiny', max_len=64, engine='off', qos='on',
@@ -340,8 +357,8 @@ def test_llm_server_traces_serving_phases(traced, monkeypatch):
                       ttl_s={'interactive': 30.0, 'standard': 30.0,
                              'batch': 30.0},
                       tenant_rps=0, tenant_tps=0))
-    server.engine = ChunkyEngine()
-    port = common_utils.find_free_port(23600)
+    server.engine = engine
+    port = common_utils.find_free_port(base_port)
     started = threading.Event()
 
     def run():
@@ -356,7 +373,17 @@ def test_llm_server_traces_serving_phases(traced, monkeypatch):
 
     threading.Thread(target=run, daemon=True).start()
     assert started.wait(15)
-    url = f'http://127.0.0.1:{port}'
+    return f'http://127.0.0.1:{port}'
+
+
+def test_llm_server_traces_serving_phases(traced, monkeypatch):
+    """HTTP-level: a QoS-on replica (stub engine that emits chunk
+    callbacks) produces a serve.generate trace whose phases cover
+    queue-wait -> prefill -> decode, and whose histograms fill — no
+    real jax decode needed."""
+    import requests as requests_lib
+
+    url = _serve_stub(_ChunkyEngine(), 23600)
 
     header = trace.make_header()
     r = requests_lib.post(
@@ -387,6 +414,40 @@ def test_llm_server_traces_serving_phases(traced, monkeypatch):
     assert 'qos_class="interactive"' in text
     assert 'skytpu_serve_queue_wait_seconds_count' in text
     assert 'skytpu_replica_slots 4.0' in text
+
+
+def test_serve_prefill_span_holds_the_engines_three_waits(traced):
+    """Under ``serve.prefill`` (submit -> first token, which is mostly
+    waiting) the engine's own timeline says which wait it was:
+    ``engine.queue``, ``engine.prep``, ``engine.first_wait``, one after
+    the other, inside their parent."""
+    import requests as requests_lib
+
+    url = _serve_stub(_ChunkyEngine(timelines=True), 23650)
+    header = trace.make_header()
+    r = requests_lib.post(
+        f'{url}/generate', json={'tokens': [[1, 2, 3]], 'max_new_tokens': 4},
+        headers={trace.TRACE_HEADER: header}, timeout=30)
+    assert r.status_code == 200
+    tid = trace.parse_header(header)[0]
+    body = requests_lib.get(f'{url}/debug/traces',
+                            params={'trace_id': tid}, timeout=10).json()
+    spans = body['traces'][0]['spans']
+    prefill = next(s for s in spans if s['name'] == 'serve.prefill')
+    kids = [s for s in spans if s['parent_id'] == prefill['span_id']]
+    assert [s['name'] for s in kids] == ['engine.queue', 'engine.prep',
+                                         'engine.first_wait']
+    for s in kids:
+        assert prefill['start'] <= s['start'] <= s['end'] <= prefill['end']
+        assert s['attrs']['path'] == 'shared'
+        assert s['attrs']['saved_tokens'] == 2
+    for a, b in zip(kids, kids[1:]):
+        assert a['end'] == pytest.approx(b['start'], abs=1e-6)
+    lengths = [s['end'] - s['start'] for s in kids]
+    assert lengths[0] >= 0.015 and lengths[2] >= 0.015
+    assert 0.003 <= lengths[1] < 0.015
+    assert sum(lengths) <= prefill['end'] - prefill['start'] + 1e-6
+    assert 'engine.decode' not in [s['name'] for s in spans]
 
 
 @pytest.mark.slow

@@ -207,3 +207,41 @@ def test_debug_payload_catalog(profiling):
     assert out['enabled'] is True
     assert {p['name'] for p in out['programs']} == set(
         profiler.PROGRAM_NAMES)
+
+
+def test_a_device_trace_holds_the_programs_spans():
+    """``/debug/profile?device_trace=<s>`` on a replica: a profiler trace
+    of this process, with the spans the program opened meanwhile in its
+    host plane."""
+    import glob
+    import os
+    import shutil
+    import threading
+
+    stop = threading.Event()
+
+    def work():
+        while not stop.is_set():
+            with profiler.span('engine.test_work'):
+                stop.wait(0.01)
+
+    t = threading.Thread(target=work, daemon=True)
+    t.start()
+    try:
+        got = profiler.device_trace('0.3')
+    finally:
+        stop.set()
+        t.join(timeout=10)
+    try:
+        assert got['seconds'] == pytest.approx(0.3)
+        paths = glob.glob(os.path.join(got['dir'], 'plugins', 'profile',
+                                       '*', '*.xplane.pb'))
+        assert len(paths) == 1
+        data = jax.profiler.ProfileData.from_file(paths[0])
+        names = {ev.name for plane in data.planes
+                 if plane.name.startswith('/host:')
+                 for line in plane.lines for ev in line.events}
+        assert 'engine.test_work' in names
+    finally:
+        shutil.rmtree(got['dir'], ignore_errors=True)
+    assert 'error' in profiler.device_trace('soon')

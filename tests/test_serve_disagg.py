@@ -200,7 +200,7 @@ def test_import_rejected_when_negotiated_blocks_evicted(tiny_params):
             kwargs[name] = kwargs[name][:, 2:]
         with pytest.raises(KVImportError):
             dec.submit_import(**kwargs).result(timeout=300)
-        assert dec.import_errors == 1
+        assert dec.stats()['disagg']['import_errors'] == 1
     finally:
         pre.stop()
         dec.stop()
