@@ -766,8 +766,9 @@ class ContinuousEngine:
                 mesh, self.rules, ('layers', 'batch', 'kv_heads', None))
             self._vec_sharding = sharding_lib.logical_sharding(
                 mesh, self.rules, ('batch',))
-            if gen_lib._DECODE_KERNEL:
-                # The pallas decode kernel runs per head shard under TP
+            if gen_lib._DECODE_KERNEL or self.kv_layout == 'paged':
+                # The pallas decode kernels (the paged layout's own,
+                # and the opt-in dense one) run per head shard under TP
                 # via shard_map (generate.kernel_shard_ctx) — no gate.
                 self._shard_ctx = gen_lib.kernel_shard_ctx(mesh,
                                                            self.rules)
@@ -1139,6 +1140,11 @@ class ContinuousEngine:
             return {'slots': self.slots, 'active_slots': active,
                 'kv_cache': 'int8' if self.kv_quantize else 'bf16',
                 'kv_layout': self.kv_layout,
+                # How the paged decode step reads K/V: 'paged_kernel'
+                # (through the block table, by length) or 'gather'
+                # (every slot's whole max_len into a dense view);
+                # None for the slot layout, which has no table.
+                'decode_attention': self.decode_attention,
                 # Handoff accounting (serve/disagg.py): exports are
                 # prefill-role retirements, imports are decode-role
                 # admissions of transferred tables; queued_imports is
@@ -1360,6 +1366,7 @@ class ContinuousEngine:
         # Share-trie state exists on every layout (None = sharing off)
         # so the admission/release paths never branch on layout first.
         self._trie = None
+        self.decode_attention = None  # the slot layout has no table
         self._slot_shared = [[] for _ in range(self.slots)]
         # The slot's INSTALLED table row (host copy, paged layout):
         # exports reconstruct the exact device table from it — deriving
@@ -1397,6 +1404,15 @@ class ContinuousEngine:
                 [] for _ in range(self.slots)]
             self._trie = (paged_lib.BlockTrie(self.kv_block)
                           if self.prefix_share else None)
+            # Which attention the decode program is built with: the
+            # same call _paged_layer branches on when it is traced.
+            # Speculative mode has no S = 1 step over the pool (its
+            # verify is S = k + 1: the gather).
+            self.decode_attention = (
+                'gather' if self.draft_cfg is not None
+                else paged_lib.decode_path(
+                    self._cache.tables.shape, self._cache.k.shape,
+                    self._cache.k.dtype, self.kv_quantize))
         else:
             self._cache = gen_lib.init_cache(
                 self.cfg, self.slots, self.max_len, kv_sharding=kv,

@@ -16,14 +16,23 @@ TPU-first shape discipline (vs the GPU original's per-block kernels):
 * the pool is one static ``[L, NB, Hkv, P, D]`` buffer; block tables
   are a ``[B, MB]`` int32 array — every shape is fixed at engine
   construction, so decode remains ONE compiled program;
-* decode writes are per-row scatters ``pool.at[table[b, len//P], :,
-  len%P]``; the GATHER assembles each slot's blocks into the standard
-  ``[B, H, MB·P, D]`` attention view and reuses the engine's exact
-  attention math (``generate._cached_attention``) — attention reads the
-  whole cache from HBM either way, so the gather's cost is one extra
-  materialized copy per layer per step. Whether that copy or the
-  stranded padding costs more on TPU is the measured A/B question
-  (``docs/serving.md``);
+* decode writes are per-row scatters at ``table[b, len // P]``, offset
+  ``len % P``. The decode step (S = 1, float pool) then READS THROUGH
+  THE TABLE, BY LENGTH: ``ops/decode_attention.paged_decode`` walks
+  each slot's blocks out of the layer's plane by DMA, only as far as
+  the slot's length, with the einsum path's precision (``_kernel_step``;
+  ``decode_path`` is the one rule for when). Measured on a v5e at 48
+  slots x 2,048 positions, 8 kv heads x 128 (PR 26, PERF.md §6): 66 us
+  a layer with 24 rows live at ~230 positions, 0.6 ms with every row
+  full, against 2.7 ms a layer whatever the slots hold for the dense
+  view below; the step fell from 92 ms to 28.5;
+* everything else — the speculative verify (S = k + 1), the
+  shared-prefix prefill (S = W, one row), int8 pools, backends without
+  the kernel — GATHERS each row's whole table into the standard
+  ``[B, H, MB·P, D]`` attention view and reuses the dense cache's math
+  (``_gather_attention`` -> ``generate._cached_attention``). That is
+  all of ``max_len`` for every slot, live or not, per layer per step:
+  70% of the decode step when S = 1 still took it (ledger, PR 25);
 * unallocated table entries point at block 0, a dedicated JUNK SINK no
   request ever owns: freed slots keep decoding (static shapes forbid
   shrinking the batch) and their overflow writes land harmlessly there.
@@ -46,6 +55,8 @@ from skypilot_tpu.models.generate import (KVCache, _cached_attention,
                                           _mlp_tail, _qkv_proj,
                                           _quantize_block)
 from skypilot_tpu.models.quantization import mm as _mm
+from skypilot_tpu.ops import attention as attention_ops
+from skypilot_tpu.ops import decode_attention
 # Compile ledger (observability/profiler.py): see models/generate.py.
 from skypilot_tpu.observability.profiler import profiled_jit
 from skypilot_tpu.utils import prefix_affinity as affinity_lib
@@ -177,9 +188,10 @@ jit_insert = profiled_jit('paged.insert', _insert_impl,
 
 
 # ---------------------------------------------------------------------------
-# Decode forwards: scatter the step's K/V, gather the slot's blocks
-# into the standard attention view, reuse the dense math. S=1 is the
-# chunked decode step; S=k+1 is the speculative VERIFY window (writes
+# Decode forwards: scatter the step's K/V, then attend — through the
+# block table in a kernel (S=1, the chunked decode step) or over the
+# gathered dense view with the dense math (everything else). S=k+1 is
+# the speculative VERIFY window (writes
 # span up to two blocks per row; rollback afterwards is just a lengths
 # rewind — rolled-back block positions are never attended and get
 # overwritten on the next write, the same invariant as the dense
@@ -213,6 +225,26 @@ def _scatter_multi(pool: jax.Array, tables: jax.Array,
     return pool.at[blk, :, off].set(vals)
 
 
+def _scatter_rows(pool: jax.Array, tables: jax.Array,
+                  lengths: jax.Array, new: jax.Array,
+                  active_rows) -> jax.Array:
+    """``_scatter_multi`` written as a scatter of [D] rows into the
+    plane seen as [NB*H*P, D]: the same writes, but the window is the
+    plane's minor dim, so XLA keeps the pool in its row-major layout —
+    the one a Mosaic call's operands must have. For the [H, D] slabs of
+    ``_scatter_multi`` the TPU compiler re-lays the whole pool as
+    [NB, P, H, D] inside the decode loop and converts every layer's
+    plane back in front of the kernel (PR 26: 9 ms of a 38 ms step)."""
+    b, h, s, d = new.shape
+    nb, _, p, _ = pool.shape
+    blk, off = _block_offsets(tables, lengths, s, p, active_rows)
+    rows = ((blk[:, None] * h + jnp.arange(h, dtype=jnp.int32)[None]) * p
+            + off[:, None]).reshape(-1)
+    vals = new.transpose(0, 2, 1, 3).reshape(b * s * h, d)
+    return pool.reshape(nb * h * p, d).at[rows].set(vals).reshape(
+        pool.shape)
+
+
 def _scatter_multi_s(pool_s: jax.Array, tables: jax.Array,
                      lengths: jax.Array, new_s: jax.Array,
                      active_rows) -> jax.Array:
@@ -224,44 +256,81 @@ def _scatter_multi_s(pool_s: jax.Array, tables: jax.Array,
     return pool_s.at[blk, :, off].set(vals)
 
 
-def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
-                 lengths: jax.Array, tables: jax.Array,
-                 k_pool: jax.Array, v_pool: jax.Array,
-                 active_rows: Optional[jax.Array],
-                 k_s: Optional[jax.Array], v_s: Optional[jax.Array],
-                 shard_ctx=None):
-    """One decoder block at S>=1 over the paged pool. x: [B, S, d]
-    (S=1 decode step; S=k+1 speculative verify). The math is
-    generate.py's (_qkv_proj/_cached_attention/_mlp_tail); only the
-    cache write (pool scatter) and read (block gather) differ from the
-    dense layer. INACTIVE rows scatter to the junk sink (block 0)
-    unconditionally: a freed slot's stale table may point at blocks
-    already reallocated to another request, and an unmasked junk write
-    there would corrupt the new owner's live KV. Within a chunk a
-    finishing row stays active and its blocks are only released after
-    the chunk returns, so active writes never race a reallocation."""
-    b, s = x.shape[0], x.shape[1]
-    p = k_pool.shape[2]  # this layer's plane: [NB, Hkv, P, D]
-    mb = tables.shape[1]
-    positions = (lengths[:, None]
-                 + jnp.arange(s, dtype=jnp.int32)[None])  # [B, S]
-    q, k, v = _qkv_proj(cfg, x, layer, positions)
-    kt = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, D]
-    vt = v.transpose(0, 2, 1, 3)
-    if k_s is not None:
-        k8, ks_new = _quantize_block(kt)
-        v8, vs_new = _quantize_block(vt)
-        k_pool = _scatter_multi(k_pool, tables, lengths, k8, active_rows)
-        v_pool = _scatter_multi(v_pool, tables, lengths, v8, active_rows)
-        k_s = _scatter_multi_s(k_s, tables, lengths, ks_new, active_rows)
-        v_s = _scatter_multi_s(v_s, tables, lengths, vs_new, active_rows)
+def decode_path(tables_shape, plane_shape, dtype, quantized: bool) -> str:
+    """Which attention the S = 1 step takes over a pool with block
+    tables ``tables_shape`` [B, MB] and layer planes ``plane_shape``
+    [..., P, D]: ``'paged_kernel'`` (``ops/decode_attention.paged_decode``)
+    or ``'gather'`` (the dense view + ``_cached_attention``). Decided on
+    what the program can see when it is built: the backend rule of the
+    training kernel (a TPU; the interpreter only where a test asks for
+    it by name) and the pool's geometry. A pool the kernel cannot take
+    on a backend that has the kernel is said once per shape. The ONE
+    definition: ``_paged_layer`` branches on it and the engine reports
+    it (``stats()['decode_attention']``)."""
+    if not (attention_ops._use_pallas()
+            or decode_attention.PAGED_INTERPRET):
+        return 'gather'
+    (b, mb), (p, d) = tables_shape, plane_shape[-2:]
+    if quantized:
+        why = 'int8 pool: the kernel folds no scales'
+    elif not decode_attention.paged_fits(b, mb, p, d, dtype):
+        why = f'B={b}, MB={mb}, P={p}, D={d} outside paged_fits()'
     else:
-        k_pool = _scatter_multi(k_pool, tables, lengths,
-                                kt.astype(k_pool.dtype), active_rows)
-        v_pool = _scatter_multi(v_pool, tables, lengths,
-                                vt.astype(v_pool.dtype), active_rows)
+        return 'paged_kernel'
+    attention_ops.log_fallback_once('paged_decode', (b, mb, p, d), why)
+    return 'gather'
 
-    # Gather: [B, MB, H, P, D] -> [B, H, MB*P, D] attention view.
+
+def _kernel_step(q: jax.Array, kt: jax.Array, vt: jax.Array,
+                 k_pool: jax.Array, v_pool: jax.Array, tables: jax.Array,
+                 lengths: jax.Array, active_rows, shard_ctx):
+    """The S = 1 step's cache write and read where ``paged_decode``
+    runs: q [B, Hq, D] and this step's kt/vt [B, Hkv, 1, D] against the
+    planes [NB, Hkv, P, D]. Scatters kt/vt at ``lengths`` (inactive
+    rows into the junk sink, as ever), then attends positions <=
+    ``lengths`` through the block table. Inactive rows read nothing
+    (valid 0): their output is never used and their stale tables may
+    name blocks that now belong to another request.
+    -> (att [B, Hq, D], k_pool, v_pool)."""
+    if active_rows is None:
+        active_rows = jnp.ones(lengths.shape, bool)
+
+    def step(q, kt, vt, k_pool, v_pool, tables, lengths, active):
+        k_pool = _scatter_rows(k_pool, tables, lengths, kt, active)
+        v_pool = _scatter_rows(v_pool, tables, lengths, vt, active)
+        att = decode_attention.paged_decode(
+            q, k_pool, v_pool, tables, jnp.where(active, lengths + 1, 0),
+            interpret=not attention_ops._use_pallas())
+        return att, k_pool, v_pool
+
+    args = (q, kt, vt, k_pool, v_pool, tables, lengths, active_rows)
+    if shard_ctx is None:
+        return step(*args)
+    # TP serving: write and read per kv-head shard (heads are
+    # independent; GSPMD cannot partition a Mosaic call, nor the row
+    # scatter's reshape over the sharded head dim: either would gather
+    # the pool). Tables, lengths and every batch dim replicated: a
+    # table indexes the whole pool. check_vma off: see
+    # generate._cached_attention.
+    mesh, p_q, p_kv = shard_ctx[:3]
+    heads = jax.sharding.PartitionSpec(None, p_q[1], None)
+    planes = jax.sharding.PartitionSpec(None, p_kv[1], None, None)
+    rep = jax.sharding.PartitionSpec()
+    return jax.shard_map(
+        step, mesh=mesh,
+        in_specs=(heads, planes, planes, planes, planes, rep, rep, rep),
+        out_specs=(heads, planes, planes), check_vma=False)(*args)
+
+
+def _gather_attention(q, k_pool, v_pool, tables, positions, valid, k_s,
+                      v_s, shard_ctx) -> jax.Array:
+    """Attention over a dense view: every row's whole table gathered
+    out of the pool, [B, MB, H, P, D] -> [B, H, MB*P, D], then the
+    dense cache's math. What S > 1 (speculative verify, shared-prefix
+    prefill), int8 pools and backends without the kernel take."""
+    b, mb = tables.shape
+    p = k_pool.shape[2]
+
     def view(pool):
         g = pool[tables]  # [B, MB, H, P, D]
         g = g.transpose(0, 2, 1, 3, 4)
@@ -272,10 +341,60 @@ def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
         g = g.transpose(0, 2, 1, 3)
         return g.reshape(b, g.shape[1], mb * p)
 
-    att = _cached_attention(
-        q, view(k_pool), view(v_pool), positions, lengths + s,
+    return _cached_attention(
+        q, view(k_pool), view(v_pool), positions, valid,
         view_s(k_s) if k_s is not None else None,
         view_s(v_s) if v_s is not None else None, shard_ctx)
+
+
+def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
+                 lengths: jax.Array, tables: jax.Array,
+                 k_pool: jax.Array, v_pool: jax.Array,
+                 active_rows: Optional[jax.Array],
+                 k_s: Optional[jax.Array], v_s: Optional[jax.Array],
+                 shard_ctx=None):
+    """One decoder block at S>=1 over the paged pool. x: [B, S, d]
+    (S=1 decode step; S=k+1 speculative verify). The math is
+    generate.py's (_qkv_proj/_cached_attention/_mlp_tail); only the
+    cache write (pool scatter) and read (through the table in the
+    kernel, or the block gather) differ from the dense layer. INACTIVE
+    rows scatter to the junk sink (block 0)
+    unconditionally: a freed slot's stale table may point at blocks
+    already reallocated to another request, and an unmasked junk write
+    there would corrupt the new owner's live KV. Within a chunk a
+    finishing row stays active and its blocks are only released after
+    the chunk returns, so active writes never race a reallocation."""
+    b, s = x.shape[0], x.shape[1]
+    positions = (lengths[:, None]
+                 + jnp.arange(s, dtype=jnp.int32)[None])  # [B, S]
+    q, k, v = _qkv_proj(cfg, x, layer, positions)
+    kt = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, D]
+    vt = v.transpose(0, 2, 1, 3)
+    if s == 1 and decode_path(tables.shape, k_pool.shape, k_pool.dtype,
+                              k_s is not None) == 'paged_kernel':
+        att, k_pool, v_pool = _kernel_step(
+            q[:, 0], kt.astype(k_pool.dtype), vt.astype(v_pool.dtype),
+            k_pool, v_pool, tables, lengths, active_rows, shard_ctx)
+        att = att[:, None]
+    else:
+        if k_s is not None:
+            k8, ks_new = _quantize_block(kt)
+            v8, vs_new = _quantize_block(vt)
+            k_pool = _scatter_multi(k_pool, tables, lengths, k8,
+                                    active_rows)
+            v_pool = _scatter_multi(v_pool, tables, lengths, v8,
+                                    active_rows)
+            k_s = _scatter_multi_s(k_s, tables, lengths, ks_new,
+                                   active_rows)
+            v_s = _scatter_multi_s(v_s, tables, lengths, vs_new,
+                                   active_rows)
+        else:
+            k_pool = _scatter_multi(k_pool, tables, lengths,
+                                    kt.astype(k_pool.dtype), active_rows)
+            v_pool = _scatter_multi(v_pool, tables, lengths,
+                                    vt.astype(v_pool.dtype), active_rows)
+        att = _gather_attention(q, k_pool, v_pool, tables, positions,
+                                lengths + s, k_s, v_s, shard_ctx)
     x = x + _mm(att, layer['wo'], 'bshk,hkd->bsd')
     token_mask = None
     if cfg.num_experts > 0:

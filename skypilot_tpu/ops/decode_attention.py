@@ -1,32 +1,45 @@
-"""Pallas flash-decode: single-pass cached attention for one new token.
+"""Pallas decode attention: one new token against the KV cache.
 
 Reference analog: the reference's serving engines carry fused decode
 attention kernels (JetStream's pallas kernels, vLLM's paged attention);
-the hot op here is the decode step's attention over the WHOLE KV cache
-— [B, Hq, D] queries against [B, Hkv, M, D] keys/values every token.
+the hot op here is the decode step's attention over the KV cache —
+[B, Hq, D] queries against every live key/value, every token.
 
-The XLA path (``generate._cached_attention``) materializes the
-[B, Hkv, G, 1, M] fp32 logits (plus the softmax intermediates) in HBM
-between its two einsums; at long context that tensor rivals the KV read
-itself. This kernel streams the cache once through VMEM with an online
-softmax (same recipe as the training kernel, ``ops/attention.py``) — no
-logits tensor ever exists in HBM, so decode stays at the KV-stream
-bandwidth floor.
+Two kernels, one online softmax (the training kernel's recipe,
+``ops/attention.py``; float32 logits, softmax and accumulation,
+probabilities cast to the query's dtype before P·V):
 
-Layout: grid (B, Hkv); each program owns one row's one kv head — its
-query GROUP [G, D] and the head's [M, D] cache slice. Per-row valid
-lengths arrive via scalar prefetch and mask tail positions in-kernel.
-int8 caches fold their per-position scales exactly like the jnp path:
-key scales into the post-QK logits, value scales into the probs.
+``paged_decode`` — the paged layout's decode step, ON BY ITSELF
+wherever it fits (``models/paged.decode_path``: a TPU, S = 1, a float
+pool, ``paged_fits``). It reads each slot's blocks out of the pool
+plane [NB, Hkv, P, D] through the block table, only as far as the
+slot's length: tables and lengths arrive by scalar prefetch, the plane
+stays in HBM, a program per slot loops over groups of ``PAGED_GROUP``
+blocks, each block one DMA ([Hkv, P, D]: 32 KB contiguous at 8 x 16 x
+128 bf16) into double-buffered VMEM. No dense view is built and no
+logits tensor exists in HBM. Measured on a v5e (PR 26, PERF.md §6) at
+48 slots, 2,049 blocks of 16, 16/8 heads x 128: 66 us a call with 24
+rows live at ~230 positions (the DMAs alone 54, the arithmetic alone
+27), 303 us with 32 rows at ~1,500 (193 MB of K/V: 78% of the HBM
+peak), 586 us with all 48 at 2,048 (84%); the gather + einsum it
+replaced, 2.7 ms whatever the slots hold. An empty slot costs a grid
+step (~0.35 us) and reads nothing.
 
-The int8 scales travel as lane-dense ``[1, M]`` rows: a ``[M, 1]``
-column pads to 128 lanes per position in VMEM, which at the cap would
-outweigh the cache slices themselves.
-
-OPT-IN (``SKYTPU_DECODE_KERNEL=pallas``): accumulation order differs
-from the XLA path, so outputs match to tolerance, not bit-exactly — and
-the serving engine's exact-parity contract keeps the XLA path as its
-default.
+``flash_decode`` — the dense layout [B, Hkv, M, D] (and a paged S = 1
+step ``paged_decode`` cannot take). The XLA path
+(``generate._cached_attention``) materializes the [B, Hkv, G, 1, M]
+fp32 logits (plus the softmax intermediates) in HBM between its two
+einsums; this kernel streams the cache once through VMEM instead.
+Grid (B, Hkv); each program owns one row's one kv head — its query
+GROUP [G, D] and the head's [M, D] cache slice. Per-row valid lengths
+arrive via scalar prefetch and mask tail positions in-kernel. int8
+caches fold their per-position scales exactly like the jnp path: key
+scales into the post-QK logits, value scales into the probs; the
+scales travel as lane-dense ``[1, M]`` rows (a ``[M, 1]`` column pads
+to 128 lanes per position in VMEM, which at the cap would outweigh the
+cache slices themselves). OPT-IN (``SKYTPU_DECODE_KERNEL=pallas``):
+outputs match the XLA path to tolerance, not bit-exactly, and its speed
+in a cell is still unmeasured (ROADMAP D3).
 """
 from __future__ import annotations
 
@@ -167,4 +180,158 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             interpret=interpret,
         )(lengths, qg, k_cache, v_cache, k_s[:, :, None, :],
           v_s[:, :, None, :])
+    return out.reshape(b, hq, d)
+
+
+# ---------------------------------------------------------------------------
+# Paged decode: the same single-position attention, read straight out of
+# the block pool through the block table.
+
+# Blocks fetched and attended per loop turn (16 x P=16: 256 positions).
+# On a v5e at 48 slots x 8 kv heads x 128 (PR 26), 4 / 8 / 16 / 32 read
+# 76 / 66 / 70 / 81 us a call with 24 rows live at ~230 positions and
+# 450 / 328 / 303 / 314 us with 32 rows at ~1,500 (the DMAs alone: 284).
+PAGED_GROUP = 16
+# Scalar-prefetched tables + lengths live in SMEM for the whole call. A
+# v5e has 1 MiB of it: 512 slots x 128 blocks (256 KiB) compiles, 1,024
+# x 256 (1 MiB) is refused.
+PAGED_SMEM_CAP_BYTES = 256 * 1024
+# Tests and the CPU rehearsal run the kernel in the Pallas interpreter by
+# setting this BY NAME (monkeypatch); nothing infers it from the backend.
+PAGED_INTERPRET = False
+
+
+def _pick_group(max_blocks: int) -> int:
+    """Largest divisor of ``max_blocks`` that is <= PAGED_GROUP, so a
+    group never reads past the end of a table row."""
+    g = min(PAGED_GROUP, max_blocks)
+    while max_blocks % g:
+        g -= 1
+    return g
+
+
+def paged_fits(slots: int, max_blocks: int, block: int, head_dim: int,
+               dtype) -> bool:
+    """True when ``paged_decode`` can take this pool geometry: the
+    tables and lengths fit SMEM, a block's [P, D] is a whole number of
+    the dtype's (sublane, lane) tiles so every DMA lands tile-aligned,
+    and the pool holds what the kernel multiplies (a float dtype; int8
+    pools carry scales the kernel does not fold)."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return False
+    sublane = 8 * (4 // dtype.itemsize)
+    lanes = -(-max_blocks // 128) * 128  # SMEM pads the minor dim
+    return (head_dim % 128 == 0 and block % sublane == 0
+            and (slots * lanes + slots) * 4 <= PAGED_SMEM_CAP_BYTES)
+
+
+def _paged_kernel(tables_ref, valid_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sem, *, block: int, group: int):
+    """One slot per program. q_ref/o_ref [Hkv, G, D]; k_hbm/v_hbm the
+    whole pool plane [NB, Hkv, P, D], left in HBM; tables_ref [B, MB]
+    and valid_ref [B] scalar-prefetched. The slot's blocks arrive
+    ``group`` at a time by DMA into k_buf/v_buf [2, Hkv, group*P, D]
+    (double-buffered: the next group is in flight while this one is
+    multiplied), only as far as ``valid`` reaches; the online softmax is
+    ``_decode_kernel``'s."""
+    b = pl.program_id(0)
+    q = q_ref[...]
+    hkv, g, d = q.shape
+    span = group * block
+    scale = d ** -0.5
+    valid = valid_ref[b]
+    n_blocks = pl.cdiv(valid, block)
+    n_groups = pl.cdiv(valid, span)
+
+    @pl.when(b == 0)
+    def _():
+        # A group's blocks past the row's length are not fetched; their
+        # probabilities are exactly 0, and 0 x (whatever an
+        # uninitialized buffer holds) must not be NaN. Later programs
+        # find finite K/V of earlier rows there.
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+    def group_dma(gi, slot, act):
+        for j in range(group):
+            i = gi * group + j
+
+            @pl.when(i < n_blocks)
+            def _(i=i, j=j):
+                blk = tables_ref[b, i]
+                dst = pl.ds(j * block, block)
+                for plane, buf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    act(pltpu.make_async_copy(
+                        plane.at[blk], buf.at[slot, :, dst, :],
+                        sem.at[s, slot]))
+
+    group_dma(0, 0, lambda c: c.start())
+
+    def body(gi, carry):
+        acc, m_prev, l_prev = carry
+        slot = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _():
+            group_dma(gi + 1, 1 - slot, lambda c: c.start())
+
+        group_dma(gi, slot, lambda c: c.wait())
+        k = k_buf[slot].astype(q.dtype)  # [Hkv, span, D]
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [Hkv, G, span]
+        ki = gi * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(ki < valid, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_buf[slot].astype(q.dtype)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(q.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)  # [Hkv, G, D]
+        return acc, m_new, l_new
+
+    acc0 = jnp.zeros((hkv, g, d), jnp.float32)
+    m0 = jnp.full((hkv, g, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((hkv, g, 1), jnp.float32)
+    acc, _, l = jax.lax.fori_loop(0, n_groups, body, (acc0, m0, l0))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def paged_decode(q: jax.Array, k_plane: jax.Array, v_plane: jax.Array,
+                 tables: jax.Array, valid: jax.Array,
+                 interpret: bool = False) -> jax.Array:
+    """q [B, Hq, D] (the single decode position) against one layer's
+    pool planes [NB, Hkv, P, D] under block tables [B, MB] int32: row b
+    attends positions < valid[b] of the blocks its table names, in
+    order. valid[b] == 0 reads nothing and returns zeros. -> [B, Hq, D].
+    Callers gate on ``paged_fits``."""
+    b, hq, d = q.shape
+    nb, hkv, block, _ = k_plane.shape
+    mb = tables.shape[1]
+    g = hq // hkv
+    group = _pick_group(mb)
+    # What XLA's gather does for the dense view, a DMA does not: keep
+    # every address inside the pool. A row that finished mid-chunk
+    # decodes on past max_len (its writes clip to its last block, its
+    # output is dropped); a table never names a block past the pool.
+    valid = jnp.clip(valid.astype(jnp.int32), 0, mb * block)
+    tables = jnp.clip(tables.astype(jnp.int32), 0, nb - 1)
+    qspec = pl.BlockSpec((None, hkv, g, d), lambda bi, *_: (bi, 0, 0, 0))
+    buf = pltpu.VMEM((2, hkv, group * block, d), k_plane.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[qspec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=qspec,
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))])
+    out = pl.pallas_call(
+        functools.partial(_paged_kernel, block=block, group=group),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=interpret, name='paged_decode',
+    )(tables, valid, q.reshape(b, hkv, g, d), k_plane, v_plane)
     return out.reshape(b, hq, d)

@@ -335,3 +335,113 @@ def test_llm_server_paged_roundtrip(tiny):
     assert eng_stats['kv_layout'] == 'paged'
     assert eng_stats['kv_blocks']['total'] > 0
     server.engine.stop()
+
+
+# -- the decode step through ops/decode_attention.paged_decode --------------
+
+
+@pytest.fixture(scope='module')
+def wide():
+    """head_dim 128: what ``paged_fits`` needs (a tiny preset's 16 is
+    not a lane row). float32, so the kernel and the gather differ by
+    accumulation order alone and greedy tokens agree."""
+    import dataclasses
+    cfg = dataclasses.replace(llama.TINY, head_dim=128, dtype=jnp.float32)
+    return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _run_reuse_pattern(eng):
+    """Two slots, three requests: the short one ends mid-run and the
+    third is admitted into its slot while the long one still decodes —
+    the freed slot's stale table names blocks that now belong to the
+    third request."""
+    rows = [[5, 6, 7], [8, 9, 10, 11, 12, 13, 14, 15, 16, 17, 18, 19,
+                        20, 21, 22, 23, 24, 25], [26, 27]]
+    futs = [eng.submit(r, n) for r, n in zip(rows, (3, 20, 9))]
+    return [f.result(timeout=300) for f in futs]
+
+
+@pytest.fixture()
+def kernel_traces(monkeypatch):
+    """Every trace of ``paged_decode``, as a list of its keywords. The
+    path is chosen when the decode program is traced, and the engine's
+    jits are module-level: drop what an earlier test compiled for these
+    shapes, and what this one leaves."""
+    from skypilot_tpu.ops import decode_attention
+    traced = []
+    real = decode_attention.paged_decode
+
+    def counted(*args, **kw):
+        traced.append(kw)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(decode_attention, 'paged_decode', counted)
+    engine_lib._jit_paged_chunk.clear_cache()
+    yield traced
+    engine_lib._jit_paged_chunk.clear_cache()
+
+
+def test_paged_kernel_engine_matches_gather_engine(wide, kernel_traces,
+                                                   monkeypatch):
+    from skypilot_tpu.models import paged as paged_lib
+    from skypilot_tpu.ops import decode_attention
+    cfg, params = wide
+    eng = _mk(params, cfg, slots=2, chunk_steps=2)
+    try:
+        assert eng.stats()['decode_attention'] == 'gather'  # CPU
+        want = _run_reuse_pattern(eng)
+    finally:
+        eng.stop()
+    assert not kernel_traces
+    engine_lib._jit_paged_chunk.clear_cache()
+    # Asked for by name: the kernel, in the interpreter.
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    eng = _mk(params, cfg, slots=2, chunk_steps=2)
+    try:
+        assert eng.stats()['decode_attention'] == 'paged_kernel'
+        assert _run_reuse_pattern(eng) == want
+        assert eng.stats()['failures'] == 0
+        assert kernel_traces and all(kw['interpret']
+                                     for kw in kernel_traces)
+        # One more step over the pool the run left behind (tables of
+        # freed slots stale, lengths ragged), both ways: logits of order
+        # 1 agree to 1e-4 in float32.
+        cache = jax.tree.map(jnp.copy, eng._cache)
+    finally:
+        eng.stop()
+    toks = jnp.asarray([[3], [4]], jnp.int32)
+    got, new = paged_lib.forward_paged(params, toks, cache, cfg)
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', False)
+    ref, ref_new = paged_lib.forward_paged(params, toks, cache, cfg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(new.k), np.asarray(ref_new.k),
+                               atol=1e-4)
+
+
+def test_paged_kernel_leaves_int8_and_spec_on_the_gather(
+        wide, kernel_traces, monkeypatch):
+    """An int8 pool carries scales the kernel does not fold, and the
+    speculative verify is S = k + 1: both keep the dense view, say so
+    in stats(), and still produce their oracles' tokens."""
+    from skypilot_tpu.ops import decode_attention
+    cfg, params = wide
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    row = [7, 8, 9, 10]
+    eng = _mk(params, cfg, slots=2, kv_quantize=True)
+    try:
+        assert eng.stats()['decode_attention'] == 'gather'
+        assert eng.submit(row, 6).result(timeout=180) == _solo(
+            params, cfg, row, 6, kv_quantize=True)
+    finally:
+        eng.stop()
+    eng = _mk(params, cfg, slots=2, draft_params=params, draft_cfg=cfg,
+              spec_k=2)
+    try:
+        assert eng.stats()['decode_attention'] == 'gather'
+        assert eng.submit(row, 6).result(timeout=180) == _solo(
+            params, cfg, row, 6)
+    finally:
+        eng.stop()
+    assert not kernel_traces
+    assert engine_lib.ContinuousEngine(
+        params, cfg, slots=2, max_len=64).stats()['decode_attention'] is None

@@ -133,3 +133,92 @@ def test_the_three_kernels_carry_their_names_into_the_program():
     jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, kv, kv))
     for name in ('flash_fwd', 'flash_dq', 'flash_dkv'):
         assert re.search(rf'\bname={name}\b', jaxpr), name
+
+
+# -- paged decode per kv-head shard (ops/decode_attention.paged_decode) -----
+
+
+@pytest.mark.parametrize('axes', [dict(tensor=4), dict(data=2, tensor=2)],
+                         ids=['tensor4', 'data2-tensor2'])
+def test_sharded_paged_step_equals_unsharded(axes):
+    """Under a TP mesh the S = 1 write and the kernel run per kv-head
+    shard of the pool plane (heads are independent); tables and lengths
+    go to every shard whole, whatever the mesh does with the batch.
+    Four virtual devices, interpret mode: the sharded step equals the
+    unsharded one, keeps heads and planes sharded, and gathers no
+    plane."""
+    from skypilot_tpu.models import paged
+
+    mesh = _mesh(**axes)
+    ctx = gen_lib.kernel_shard_ctx(mesh, RULES)
+    b, hq, hkv, p, d, nb = 4, 8, 4, 16, 128, 9
+    key = jax.random.PRNGKey(0)
+    q = jax.random.normal(key, (b, hq, d))
+    kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
+                                (nb, hkv, p, d)) for i in (1, 2))
+    kt, vt = (jax.random.normal(jax.random.fold_in(key, i),
+                                (b, hkv, 1, d)) for i in (3, 4))
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [1, 2, 7, 0],
+                          [8, 0, 0, 0]], jnp.int32)
+    lengths = jnp.asarray([63, 16, 39, 2], jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    args = (q, kt, vt, kp, vp, tables, lengths, active)
+
+    def step(shard_ctx):  # off the TPU it runs the interpreter
+        # skylint: allow-jit(test-only numerics check)
+        return jax.jit(lambda *a: paged._kernel_step(*a, shard_ctx))
+
+    got, want = step(ctx)(*args), step(None)(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+    att, k_new, _ = got
+    assert not np.asarray(att[3]).any()  # the inactive row read nothing
+    # The write landed: row 1's 17th position is block 6, offset 0.
+    np.testing.assert_array_equal(k_new[6, :, 0], kt[1, :, 0])
+    spec = jax.sharding.PartitionSpec
+    assert att.sharding.is_equivalent_to(jax.sharding.NamedSharding(
+        mesh, spec(None, ctx[1][1], None)), att.ndim)
+    assert k_new.sharding.is_equivalent_to(jax.sharding.NamedSharding(
+        mesh, spec(None, ctx[2][1], None, None)), k_new.ndim)
+    place = lambda x, s: jax.device_put(  # noqa: E731
+        x, jax.sharding.NamedSharding(mesh, s))
+    sharded = (place(q, spec(None, ctx[1][1], None)),
+               *(place(x, spec(None, ctx[2][1], None, None))
+                 for x in (kt, vt, kp, vp)), tables, lengths, active)
+    hlo = step(ctx).lower(*sharded).compile().as_text()
+    assert 'all-gather' not in hlo and 'all-to-all' not in hlo
+
+
+def test_tp_paged_engine_decodes_through_the_sharded_kernel(monkeypatch):
+    """A paged engine under a four-way tensor mesh builds the shard
+    context without any flag, its decode step runs the kernel per head
+    shard, and the tokens are the unsharded gather engine's (float32:
+    the paths differ by accumulation order alone)."""
+    from skypilot_tpu.models import engine as engine_lib
+    from skypilot_tpu.ops import decode_attention
+
+    cfg = dataclasses.replace(llama.TINY_MH, head_dim=128,
+                              dtype=jnp.float32)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    rows = [[5, 6, 7], [8, 9, 10, 11, 12], [13, 14]]  # > slots: reuse
+
+    def run(**kw):
+        engine_lib._jit_paged_chunk.clear_cache()
+        eng = engine_lib.ContinuousEngine(
+            params, cfg, slots=2, max_len=64, chunk_steps=2,
+            kv_layout='paged', **kw)
+        eng.start()
+        try:
+            futs = [eng.submit(r, 6) for r in rows]
+            return ([f.result(timeout=300) for f in futs],
+                    eng.stats()['decode_attention'], eng._shard_ctx)
+        finally:
+            eng.stop()
+            engine_lib._jit_paged_chunk.clear_cache()
+
+    want, path, ctx = run()
+    assert path == 'gather' and ctx is None
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    got, path, ctx = run(mesh=_mesh(tensor=4))
+    assert path == 'paged_kernel' and ctx is not None
+    assert got == want

@@ -10,6 +10,8 @@ Reference counterpart: the reference has no attention kernels of its own
 (delegated to workloads, SURVEY.md §2.11); the oracle here plays the role
 its workload-level kernels' unit tests play.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -209,3 +211,135 @@ def test_flash_decode_opt_in_end_to_end(monkeypatch):
     # logit units; the check is that the kernel is wired in and sane.
     np.testing.assert_allclose(np.asarray(ker_logits),
                                np.asarray(ref_logits), atol=8e-2)
+
+
+# -- pallas paged decode (ops/decode_attention.paged_decode) ----------------
+
+_P, _MB, _NB = 16, 4, 12  # blocks of 16, max_len 64, 11 usable blocks
+
+# name -> (valid lengths, block tables). 0 in a table is the junk sink:
+# what the engine pads a row's unreserved tail with.
+_PAGED_CASES = {
+    'len0-inactive': ([0, 33], [[0, 0, 0, 0], [3, 1, 2, 0]]),
+    'len1': ([1, 1], [[5, 0, 0, 0], [6, 0, 0, 0]]),
+    'len15': ([15], [[7, 0, 0, 0]]),
+    'len16': ([16], [[7, 0, 0, 0]]),
+    'len17': ([17], [[7, 2, 0, 0]]),
+    'full-max-len': ([64, 64], [[1, 2, 3, 4], [8, 7, 6, 5]]),
+    'ragged': ([5, 64, 0, 31, 48, 17],
+               [[9, 0, 0, 0], [1, 2, 3, 4], [4, 4, 4, 4], [5, 6, 0, 0],
+                [7, 8, 10, 0], [11, 3, 0, 0]]),
+    # A row that finished mid-chunk decodes on past max_len until the
+    # chunk ends: it attends its 64 positions, like the dense view.
+    'past-max-len': ([64 + 3, 20], [[1, 2, 3, 4], [5, 6, 0, 0]]),
+    # The prefix trie's case: two rows' tables name the same blocks.
+    'shared-blocks': ([40, 37, 33], [[2, 3, 4, 0], [2, 3, 5, 0],
+                                     [2, 3, 6, 0]]),
+}
+
+
+def _paged_inputs(group, valid, tables, dtype):
+    hkv, d = 2, 128
+    key = jax.random.PRNGKey(len(valid) + group)
+    q = jax.random.normal(key, (len(valid), hkv * group, d), dtype)
+    kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
+                                (_NB, hkv, _P, d), dtype) for i in (1, 2))
+    return (q, kp, vp, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(valid, jnp.int32))
+
+
+def _paged_reference(q, kp, vp, tables, valid):
+    """What the layer did before the kernel and still does off the
+    TPU: every row's whole table gathered into a dense view, then the
+    einsum path."""
+    from skypilot_tpu.models import paged
+    return paged._gather_attention(  # noqa: SLF001 — oracle
+        q[:, None], kp, vp, tables, (valid - 1)[:, None], valid, None,
+        None, None)[:, 0]
+
+
+@pytest.mark.parametrize('group', [1, 2], ids=['mha', 'gqa2'])
+@pytest.mark.parametrize('case', list(_PAGED_CASES))
+def test_paged_decode_matches_gather_path(monkeypatch, case, group):
+    """float32 end to end, so the tolerance is the accumulation order's
+    alone: 2e-5 on outputs of order 1. Groups of 2 blocks make a full
+    row loop twice and a 17-long row end mid-group."""
+    from skypilot_tpu.ops import decode_attention
+
+    monkeypatch.setattr(decode_attention, 'PAGED_GROUP', 2)
+    valid, tables = _PAGED_CASES[case]
+    args = _paged_inputs(group, valid, tables, jnp.float32)
+    got = np.asarray(decode_attention.paged_decode(*args, interpret=True))
+    want = np.asarray(_paged_reference(*args))
+    live = np.asarray(valid) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    # A row with nothing valid reads nothing and returns zeros (the
+    # gather path attends uniformly over junk there; neither is used).
+    assert not got[~live].any()
+
+
+def test_paged_decode_bf16_tolerance():
+    """bf16 pool and queries, the serving dtype: float32 logits, softmax
+    and accumulation on both sides; the paths differ by when the
+    probabilities are rounded to bf16 (before the normalisation here,
+    after it there): 1e-2 on outputs of order 1."""
+    from skypilot_tpu.ops import decode_attention
+
+    valid, tables = _PAGED_CASES['ragged']
+    args = _paged_inputs(2, valid, tables, jnp.bfloat16)
+    got = decode_attention.paged_decode(*args, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    live = np.asarray(valid) > 0
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live],
+        np.asarray(_paged_reference(*args), np.float32)[live], atol=1e-2)
+
+
+def test_paged_decode_geometry_gate():
+    from skypilot_tpu.ops import decode_attention as da
+
+    assert da.paged_fits(48, 128, 16, 128, jnp.bfloat16)  # the cells'
+    assert da.paged_fits(4, 4, 16, 128, jnp.float32)
+    assert not da.paged_fits(48, 128, 8, 128, jnp.bfloat16)   # half a tile
+    assert da.paged_fits(48, 128, 8, 128, jnp.float32)
+    assert not da.paged_fits(48, 128, 16, 64, jnp.bfloat16)   # D < a lane row
+    assert not da.paged_fits(48, 128, 32, 128, jnp.int8)      # codes + scales
+    assert not da.paged_fits(1024, 256, 16, 128, jnp.bfloat16)  # SMEM
+    assert da._pick_group(128) == da.PAGED_GROUP
+    assert da._pick_group(6) == 6 and da._pick_group(20) == 10
+
+
+def test_paged_layer_takes_the_kernel_only_at_s1_on_float_pools(
+        monkeypatch, caplog):
+    """``paged.decode_path`` is the one rule: off the TPU the gather,
+    silently; with the kernel asked for by name, S = 1 over a float pool
+    that fits takes it, and a pool it cannot take says so once."""
+    from skypilot_tpu.models import paged
+    from skypilot_tpu.ops import decode_attention
+
+    fit = ((4, 4), (_NB, 2, 16, 128), jnp.bfloat16)
+    assert paged.decode_path(*fit, False) == 'gather'  # CPU, not asked
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    monkeypatch.setattr(attention, '_logged_fallbacks', set())
+    assert paged.decode_path(*fit, False) == 'paged_kernel'
+    with caplog.at_level('WARNING', logger=attention.__name__):
+        for _ in range(2):
+            assert paged.decode_path(*fit, True) == 'gather'  # int8
+            assert paged.decode_path((4, 4), (_NB, 2, 16, 16),
+                                     jnp.bfloat16, False) == 'gather'
+    tagged = [r.getMessage() for r in caplog.records
+              if attention.FALLBACK_TAG in r.getMessage()]
+    assert len(tagged) == 2 and all('paged_decode' in t for t in tagged)
+
+    # In the layer: S = 1 traces the kernel under its name, S = 2 (the
+    # speculative verify, the shared-prefix prefill) keeps the gather.
+    from skypilot_tpu.models import llama
+    cfg = dataclasses.replace(llama.TINY, head_dim=128)
+    params = jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: paged.init_pool(cfg, 2, 64, 9, 16))
+    for s, named in ((1, True), (2, False)):
+        jaxpr = str(jax.make_jaxpr(
+            lambda p, t, c: paged.forward_paged(p, t, c, cfg))(
+                params, jax.ShapeDtypeStruct((2, s), jnp.int32), pool))
+        assert ('name=paged_decode' in jaxpr) == named, s
