@@ -23,8 +23,8 @@ line; any phase not ``ok`` makes the exit code non-zero):
                 times: past the first the prefix trie serves its prompt),
                 plus a shared-prefix hit; decode through paged_decode.
   kernels       each Pallas kernel compiled (not interpreted) against its
-                jnp reference (paged_decode at the benchmark cells' pool
-                geometry), the train step's HLO searched for the
+                jnp reference (paged_decode and mla_decode at the benchmark
+                cells' pool geometries), the train step's HLO searched for the
                 Mosaic call, and the greedy request once more under
                 SKYTPU_DECODE_KERNEL=pallas.
   launch-local  execution.launch(Task(run='python -m ...train.run'),
@@ -70,7 +70,10 @@ REAL = dict(model='bench-1b', tp_model='bench-1b', vocab=32768,
             # The benchmark cells' pool: 48 slots, 2,049 blocks of 16,
             # max_len 2048, 16/8 heads x 128, bf16.
             paged=dict(slots=48, blocks=2049, block=16, max_blocks=128,
-                       hq=16, hkv=8, d=128))
+                       hq=16, hkv=8, d=128),
+            # xing-docs-sessions' latent pool: 8,193 blocks of 16,
+            # max_len 4096, 32 heads over one 512 + 64 row, bf16.
+            mla=dict(slots=48, blocks=8193, block=16, max_blocks=256))
 # tiny-mh: 8 kv heads, so --tp 4 divides them. The interpreter cannot
 # afford the kernels' real VMEM caps, so the rehearsal names small ones.
 REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
@@ -79,7 +82,9 @@ REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
                  decode_lens=(128, 256), decode_cap_len=256,
                  hq=4, hkv=2, d=64, decode_kernel='interpret',
                  paged=dict(slots=4, blocks=33, block=16, max_blocks=8,
-                            hq=4, hkv=2, d=128))
+                            hq=4, hkv=2, d=128),
+                 mla=dict(slots=4, blocks=33, block=16, max_blocks=8,
+                          heads=4, rank=96, rope=16))
 
 
 def remaining() -> float:
@@ -718,6 +723,24 @@ def child_kernels(rehearse: bool, meshes) -> int:
              f'{"int8+scales" if quant else "bf16"}',
              np.isfinite(err) and err <= tol, err=round(err, 5), tol=tol)
 
+    def pool_layout(slots, blocks, block, max_blocks):
+        """(valid [slots], tables [slots, max_blocks]) of a pool as the
+        engine leaves it: live rows of every length class, every other
+        slot empty, a first block shared by two rows, tables padded
+        with the junk sink."""
+        max_len = max_blocks * block
+        valid = np.zeros((slots,), np.int32)
+        lens = [1, block - 1, block, block + 1, max_len // 2 + 3, max_len]
+        for i, slot in enumerate(range(0, slots, 2)):
+            valid[slot] = lens[i % len(lens)]
+        tables = np.zeros((slots, max_blocks), np.int32)
+        free = iter(range(1, blocks))
+        for slot in range(slots):
+            n = -(-int(valid[slot]) // block)
+            tables[slot, :n] = [next(free) for _ in range(n)]
+        tables[2, 0] = tables[0, 0]
+        return valid, tables
+
     def paged_case(slots, blocks, block, max_blocks, hq, hkv, d):
         """``paged_decode`` over a pool laid out as the engine leaves
         it (live rows of every length class, empty slots, a prefix
@@ -729,17 +752,7 @@ def child_kernels(rehearse: bool, meshes) -> int:
         kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
                                     (blocks, hkv, block, d), jnp.bfloat16)
                   for i in (1, 2))
-        max_len = max_blocks * block
-        valid = np.zeros((slots,), np.int32)  # every other slot empty
-        lens = [1, block - 1, block, block + 1, max_len // 2 + 3, max_len]
-        for i, slot in enumerate(range(0, slots, 2)):
-            valid[slot] = lens[i % len(lens)]
-        tables = np.zeros((slots, max_blocks), np.int32)
-        free = iter(range(1, blocks))
-        for slot in range(slots):
-            n = -(-int(valid[slot]) // block)
-            tables[slot, :n] = [next(free) for _ in range(n)]
-        tables[2, 0] = tables[0, 0]  # a shared first block
+        valid, tables = pool_layout(slots, blocks, block, max_blocks)
         args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(valid))
         assert interpret or decode_attention.paged_fits(
             slots, max_blocks, block, d, kp.dtype)
@@ -760,8 +773,45 @@ def child_kernels(rehearse: bool, meshes) -> int:
              and not np.asarray(got, np.float32)[~live].any(),
              err=round(err, 5), tol=tol)
 
+    def mla_case(slots, blocks, block, max_blocks, heads=32, rank=512,
+                 rope=64, layers=2):
+        """``mla_decode`` (the absorbed latent step: one shared 576-wide
+        row a position, values its first 512 columns) over layer 1 of a
+        latent pool laid out as ``paged_case`` lays its own, against the
+        jnp step over the gathered view."""
+        from skypilot_tpu.models import mla_moe
+        cfg = mla_moe.MlaMoeConfig(n_heads=heads, kv_lora_rank=rank,
+                                   qk_rope_dim=rope)
+        key = jax.random.PRNGKey(slots + 1)
+        q = jax.random.normal(key, (slots, heads, rank + rope), jnp.bfloat16)
+        pool = jax.random.normal(
+            jax.random.fold_in(key, 1),
+            (layers, blocks, 1, block, cfg.latent_width), jnp.bfloat16)
+        pool = pool.at[..., rank + rope:].set(0)
+        valid, tables = pool_layout(slots, blocks, block, max_blocks)
+        args = (q, pool, jnp.asarray(tables), jnp.asarray(valid))
+        assert interpret or decode_attention.mla_fits(
+            slots, max_blocks, block, pool.dtype)
+        scale = mla_moe.softmax_scale(cfg)
+        # skylint: allow-jit(one-shot numerics check, not a program)
+        got = jax.jit(lambda q_, p_, t_, n_: decode_attention.mla_decode(
+            q_, p_, jnp.int32(1), t_, n_, rank, scale,
+            interpret=interpret))(*args)
+        # skylint: allow-jit(one-shot numerics check, not a program)
+        want = jax.jit(lambda q_, p_, t_, n_: mla_moe._absorbed_view(
+            cfg, q_, mla_moe._pool_view(p_, 1, t_), n_))(*args)
+        live = valid > 0
+        err = rel_err(np.asarray(got, np.float32)[live],
+                      np.asarray(want, np.float32)[live])
+        emit(f'mla_decode B{slots} NB{blocks} P{block} MB{max_blocks} '
+             f'H{heads} R{rank}+{rope} bf16',
+             np.isfinite(err) and err <= tol
+             and not np.asarray(got, np.float32)[~live].any(),
+             err=round(err, 5), tol=tol)
+
     hq, hkv, d = c['hq'], c['hkv'], c['d']
     guarded('paged_decode', lambda: paged_case(**c['paged']))
+    guarded('mla_decode', lambda: mla_case(**c['mla']))
     for s in c['flash_seqs']:
         guarded(f'flash S{s}', lambda s=s: flash_case(2, hq, hkv, s, d))
     # The VMEM caps themselves, as the code has them: one group each.

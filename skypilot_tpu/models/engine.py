@@ -91,6 +91,7 @@ import numpy as np
 
 from skypilot_tpu.models import generate as gen_lib
 from skypilot_tpu.models import llama
+from skypilot_tpu.models import model_ops
 from skypilot_tpu.models import sampling
 # Flight recorder (observability/blackbox.py): record() is one deque
 # append under its own lock — no I/O, no host sync — so the engine
@@ -290,6 +291,9 @@ class _Inflight:
     reqs: List[Optional[_Request]]
     toks: jax.Array
     steps: int
+    # The experts' token counts [E] of the chunk (drop-free expert
+    # models; None otherwise): fetched with ``toks``, no sync of its own.
+    counts: Optional[jax.Array] = None
 
 
 class KVImportError(RuntimeError):
@@ -569,6 +573,7 @@ class ContinuousEngine:
         'import_errors': '_lock', 'dispatches': '_lock',
         'host_overlap_ms': '_lock', 'bubble_ms': '_lock',
         '_gap_ms_total': '_lock', '_gap_count': '_lock',
+        '_moe_load': '_lock',
     }
 
     def __init__(self, params, cfg: llama.LlamaConfig, *,
@@ -591,6 +596,13 @@ class ContinuousEngine:
                  role: Optional[str] = None):
         self.params = params
         self.cfg = cfg
+        # Everything that depends on WHICH model this is goes through
+        # this row of models/model_ops.py: cache construction, the
+        # jitted programs, and which features compose.
+        self._ops = model_ops.ops_for(cfg)
+        # Capacity-dropping experts couple co-batched rows (expert
+        # capacity is per forward CALL); drop-free routing does not.
+        rows_couple = self._ops.rows_couple(cfg)
         # Disaggregated serving role (serve/disagg.py): 'prefill'
         # engines mostly see export admissions (submit_prefill — retire
         # at first token with a handoff), 'decode' engines mostly see
@@ -613,7 +625,8 @@ class ContinuousEngine:
         if draft_cfg is not None:
             if self.spec_k < 1:
                 raise ValueError(f'spec_k must be >= 1, got {self.spec_k}')
-            if cfg.num_experts > 0:
+            self._ops.refuse('speculative decoding')
+            if rows_couple:
                 # Expert capacity is per forward CALL: a k+1-token verify
                 # routes (and drops) differently than sequential decode,
                 # breaking the byte-identical greedy-exactness contract
@@ -637,6 +650,8 @@ class ContinuousEngine:
         if kv_quantize is None:
             kv_quantize = os.environ.get('SKYTPU_LLM_KV_CACHE') == 'int8'
         self.kv_quantize = bool(kv_quantize)
+        if self.kv_quantize:
+            self._ops.refuse('kv_quantize')
         # KV layout: 'slot' pins one [max_len] cache row per slot (the
         # default; zero gather cost); 'paged' shares fixed-size blocks
         # from a pool sized below slots*max_len (models/paged.py — the
@@ -649,6 +664,7 @@ class ContinuousEngine:
         if self.kv_layout not in ('slot', 'paged'):
             raise ValueError(f'Unknown kv_layout {self.kv_layout!r}; '
                              "'slot' or 'paged'")
+        self._ops.refuse(f'kv_layout={self.kv_layout}')
         self.kv_block = kv_block or int(
             os.environ.get('SKYTPU_LLM_KV_BLOCK', '16'))
         # Pipelined dispatch (default ON): keep one decode chunk in
@@ -657,7 +673,7 @@ class ContinuousEngine:
         if pipeline is None:
             pipeline = os.environ.get('SKYTPU_LLM_PIPELINE', '1') != '0'
         self.pipeline_depth = 1 if pipeline else 0
-        if cfg.num_experts > 0:
+        if rows_couple:
             # Expert capacity is per forward CALL and couples co-batched
             # rows: an in-flight chunk runs with a slot-snapshot active
             # mask one retirement stale, so a row freed meanwhile would
@@ -682,7 +698,9 @@ class ContinuousEngine:
             prefill_chunk = int(os.environ.get('SKYTPU_LLM_PREFILL_CHUNK',
                                                '0'))
         self.prefill_chunk = max(int(prefill_chunk), 0)
-        if cfg.num_experts > 0:
+        if self.prefill_chunk:
+            self._ops.refuse('prefill_chunk')
+        if rows_couple:
             # Expert capacity is per forward CALL (token count of the
             # call), so a chunked prefill routes/drops differently than
             # the monolithic prefill the greedy-exactness oracle uses —
@@ -703,8 +721,10 @@ class ContinuousEngine:
         # co-batched rows (a busy prefill group can drop a prefix
         # token's expert routing), so stored prefix KV would replay its
         # store-time batchmates' contention — reuse is only exact for
-        # dense models, where rows are independent.
-        if cfg.num_experts > 0:
+        # models whose rows are independent.
+        if self.prefix_slots:
+            self._ops.refuse('prefix_slots')
+        if rows_couple:
             self.prefix_slots = 0
         # The prefix pool composes with BOTH cache layouts: it lives
         # entirely on the dense prefill side (pool rows, gather, store
@@ -719,7 +739,7 @@ class ContinuousEngine:
         # — a hit is a table write, not a KV copy — and prefills only
         # its unshared tail directly over the pool. A partially-matched
         # tail block copy-on-write-forks; eviction is refcount-aware
-        # LRU over idle blocks. Dense models only (same MoE capacity
+        # LRU over idle blocks. Independent rows only (same capacity
         # coupling as the prefix pool); spec mode keeps its own dense
         # draft-cache prefill path and opts out.
         if prefix_share is None:
@@ -727,7 +747,7 @@ class ContinuousEngine:
                                           '1') != '0'
         self.prefix_share = (bool(prefix_share)
                              and self.kv_layout == 'paged'
-                             and cfg.num_experts == 0
+                             and not rows_couple
                              and draft_cfg is None)
         # Fleet prefix-affinity advert (utils/prefix_affinity.py): hard
         # entry bound on the trie summary /health ships — the replica
@@ -749,6 +769,7 @@ class ContinuousEngine:
         self.rules = rules
         self._shard_ctx = None
         if mesh is not None:
+            self._ops.refuse('tensor parallelism')
             from skypilot_tpu.models import quantization as quant_lib
             from skypilot_tpu.parallel import sharding as sharding_lib
             self.rules = rules or sharding_lib.ShardingRules()
@@ -793,8 +814,12 @@ class ContinuousEngine:
         # recomputing its prefill. Host/spill state lives entirely off
         # device; a corrupt entry quarantines and the request
         # recomputes, so tiering can never fail a request.
+        if kv_tiers:
+            self._ops.refuse('kv_tiers')     # asked for by name
         if kv_tiers is None:
-            kv_tiers = os.environ.get('SKYTPU_KV_TIERS', '1') != '0'
+            # default ON only where the family has it
+            kv_tiers = (os.environ.get('SKYTPU_KV_TIERS', '1') != '0'
+                        and 'kv_tiers' not in self._ops.refuses)
         self._kv_tiers = None
         if kv_tiers and self.prefix_share:
             from skypilot_tpu.serve import kv_tiers as kv_tiers_lib
@@ -858,6 +883,10 @@ class ContinuousEngine:
         self.bubble_ms = 0.0
         self._gap_ms_total = 0.0
         self._gap_count = 0
+        # Tokens each expert took in decode chunks (drop-free expert
+        # models; stays None otherwise), summed from what the chunks
+        # return.
+        self._moe_load: Optional[np.ndarray] = None
 
     # -- public API (any thread) ------------------------------------------
 
@@ -886,7 +915,8 @@ class ContinuousEngine:
         MoE expert capacity couples co-batched rows, so exported KV
         would replay its batchmates' contention on a different replica
         — same reason the prefix pool refuses MoE."""
-        if self.cfg.num_experts > 0:
+        self._ops.refuse('KV handoff')
+        if self._ops.rows_couple(self.cfg):
             raise ValueError('KV handoff requires a dense model (MoE '
                              'expert capacity is per forward call, so '
                              'exported prompt KV is not batch-'
@@ -918,7 +948,8 @@ class ContinuousEngine:
         if layout != self.kv_layout:
             raise ValueError(f'handoff layout {layout!r} does not match '
                              f'engine kv_layout {self.kv_layout!r}')
-        if self.cfg.num_experts > 0 or self.draft_cfg is not None:
+        self._ops.refuse('KV handoff')
+        if self._ops.rows_couple(self.cfg) or self.draft_cfg is not None:
             raise ValueError('KV handoff requires a dense, '
                              'non-speculative engine')
         if ((k_s is not None) != self.kv_quantize) and k is not None:
@@ -1137,8 +1168,21 @@ class ContinuousEngine:
             # could see a snapshot where e.g. queue state and the
             # token/prefill counters disagree mid-emission. The whole
             # snapshot now builds under the lock.
+            load = (None if self._moe_load is None
+                    else self._moe_load.tolist())
             return {'slots': self.slots, 'active_slots': active,
                 'kv_cache': 'int8' if self.kv_quantize else 'bf16',
+                # What one token costs the cache over all layers, by
+                # the family's own count (a latent cache: c_kv | k_rope).
+                'kv_bytes_per_token': self._ops.kv_bytes_per_token(
+                    self.cfg),
+                # Drop-free expert models: (token, choice) pairs the
+                # decode chunks routed, and the busiest and the mean
+                # expert's share of them (None: no such experts).
+                'moe_tokens_routed': load and sum(load),
+                'moe_expert_load_max': load and max(load),
+                'moe_expert_load_mean': load and sum(load) / len(load),
+                'moe_expert_load': load,
                 'kv_layout': self.kv_layout,
                 # How the paged decode step reads K/V: 'paged_kernel'
                 # (through the block table, by length) or 'gather'
@@ -1389,7 +1433,7 @@ class ContinuousEngine:
                 pool_s = sharding_lib.logical_sharding(
                     self.mesh, self.rules,
                     ('layers', None, 'kv_heads', None))
-            self._cache = paged_lib.init_pool(
+            self._cache = self._ops.init_pool(
                 self.cfg, self.slots, self.max_len, self.kv_blocks,
                 self.kv_block, quantize=self.kv_quantize,
                 kv_sharding=pool_kv, scale_sharding=pool_s,
@@ -1410,11 +1454,10 @@ class ContinuousEngine:
             # verify is S = k + 1: the gather).
             self.decode_attention = (
                 'gather' if self.draft_cfg is not None
-                else paged_lib.decode_path(
-                    self._cache.tables.shape, self._cache.k.shape,
-                    self._cache.k.dtype, self.kv_quantize))
+                else self._ops.decode_attention(self._cache,
+                                                self.kv_quantize))
         else:
-            self._cache = gen_lib.init_cache(
+            self._cache = self._ops.init_cache(
                 self.cfg, self.slots, self.max_len, kv_sharding=kv,
                 lengths_sharding=vec, quantize=self.kv_quantize,
                 kv_scale_sharding=kv_s)
@@ -1898,7 +1941,7 @@ class ContinuousEngine:
             # First append past the shared partial block forks it: copy
             # the donor into our first owned block; the tail prefill
             # then writes from in-block offset ``plen``.
-            self._cache = paged_lib.jit_fork_block(
+            self._cache = self._ops.fork_block(
                 self._cache, jnp.int32(partial.block), jnp.int32(owned[0]))
         suffix = row[covered:]
         # The padded width must not overhang max_len: positions past
@@ -1911,7 +1954,7 @@ class ContinuousEngine:
         w = min(prompt_bucket(len(suffix)), self.max_len - covered)
         padded = np.zeros((1, w), np.int32)
         padded[0, :len(suffix)] = suffix
-        logits, self._cache = paged_lib.jit_prefill_shared(
+        logits, self._cache = self._ops.prefill_shared(
             self.cfg, self.params, self._cache, padded, table[None],
             jnp.int32(slot), np.asarray([covered], np.int32),
             np.asarray([len(suffix)], np.int32), self._shard_ctx)
@@ -2338,9 +2381,9 @@ class ContinuousEngine:
                 self.prefix_hits += hits
                 self.prefix_hit_tokens += sum(p_lens)
         else:
-            cache_n = gen_lib.init_cache(self.cfg, n, cache_width,
-                                         quantize=self.kv_quantize)
-        logits, cache_n = gen_lib._jit_prefill(  # noqa: SLF001 — same pkg
+            cache_n = self._ops.init_cache(self.cfg, n, cache_width,
+                                           quantize=self.kv_quantize)
+        logits, cache_n = self._ops.prefill(
             self.params, padded, cache_n, self.cfg,
             np.asarray(lens))
         now = time.perf_counter()
@@ -2376,7 +2419,7 @@ class ContinuousEngine:
                     self._slot_blocks[slots[i]] = blocks
                     tables_host[i, :nb] = blocks
                     self._slot_table[slots[i]] = tables_host[i].copy()
-            self._cache = paged_lib.jit_insert(
+            self._cache = self._ops.insert_paged(
                 self._cache, cache_n, tables_host,
                 # skylint: allow-host-sync(slots is a host list of slot
                 # indices — asarray builds the jit operand, no transfer)
@@ -2939,8 +2982,9 @@ class ContinuousEngine:
                             ms=round(bubble_closed_ms, 3),
                             edge='dispatch')
         tk, tp = _filters_or_none(top_ks, top_ps)
+        counts = None
         if self.kv_layout == 'paged':
-            self._cache, self._last, toks = _jit_paged_chunk(
+            self._cache, self._last, toks, counts = self._ops.paged_chunk(
                 self.cfg, self.chunk_steps, self.params, self._cache,
                 self._last, np.asarray(temps), tk, tp,
                 np.asarray(active), self._next_key(), self._shard_ctx)
@@ -2949,7 +2993,8 @@ class ContinuousEngine:
                 self.cfg, self.chunk_steps, self.params, self._cache,
                 self._last, np.asarray(temps), tk, tp,
                 np.asarray(active), self._next_key(), self._shard_ctx)
-        return _Inflight(reqs=reqs, toks=toks, steps=self.chunk_steps)
+        return _Inflight(reqs=reqs, toks=toks, steps=self.chunk_steps,
+                         counts=counts)
 
     # skylint: engine-thread
     def _note_decode_quiet(self) -> None:
@@ -2992,10 +3037,15 @@ class ContinuousEngine:
             # skylint: allow-host-sync(designed fetch point — THE chunk
             # result transfer; under pipelining it lands while the next
             # chunk computes, which is the whole overlap design)
-            toks_host = np.asarray(jax.device_get(flight.toks))  # [K, B]
+            toks_host, counts = jax.device_get((flight.toks, flight.counts))
+            toks_host = np.asarray(toks_host)  # [K, B]
         t0 = time.perf_counter()
         with self._lock:
             self.chunks_run += 1
+            if counts is not None:
+                self._moe_load = (counts.astype(np.int64)
+                                  if self._moe_load is None
+                                  else self._moe_load + counts)
         done: List[_Request] = []
         emitted: List[tuple] = []
         with self._lock:

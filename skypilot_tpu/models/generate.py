@@ -20,7 +20,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from skypilot_tpu.models import llama, moe
+from skypilot_tpu.models import llama, model_ops, moe
 from skypilot_tpu.models.quantization import mm as _mm
 # Compile ledger (observability/profiler.py): module-level jits
 # register by name so the compile-once-per-shape promise in the
@@ -75,9 +75,12 @@ class KVCache:
     HBM, so halving KV bytes is the same lever as int8 weights; both
     scales fold into the attention matmuls per POSITION (keys: post-QK
     logits product; values: into the probs before PV), never
-    rematerializing a full-precision cache."""
+    rematerializing a full-precision cache.
+
+    A latent (MLA) cache is ONE plane: ``k`` [L, B, 1, max_len, W] and
+    ``v`` None (models/mla_moe.py)."""
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     lengths: jax.Array  # [B] int32: tokens currently cached per row
     k_s: Optional[jax.Array] = None
     v_s: Optional[jax.Array] = None
@@ -465,13 +468,14 @@ def _decode_scan_impl(params, cache, first, key, cfg, n, temps,
     gives a second cached variant (same scheme as the engine's
     ``_chunk_impl``)."""
     from skypilot_tpu.models import sampling
+    forward = model_ops.ops_for(cfg).forward_cached
 
     def step(carry, _):
         cache, token, key = carry
         row_lens = (None if uniform
                     else jnp.ones((token.shape[0],), jnp.int32))
-        logits, cache = forward_cached(params, token[:, None], cache, cfg,
-                                       row_lens)
+        logits, cache = forward(params, token[:, None], cache, cfg,
+                                row_lens)
         key, sub = jax.random.split(key)
         nxt = sampling.sample(logits, temps, sub, top_ks, top_ps)
         return (cache, nxt, key), nxt
@@ -508,14 +512,15 @@ def generate(params: Params, cfg: llama.LlamaConfig,
     if top_k < 0 or not 0.0 < top_p <= 1.0:
         # top_p <= 0 would mask every token (uniform-random garbage).
         raise ValueError('top_k must be >= 0 and top_p in (0, 1]')
-    cache = init_cache(cfg, b, max_len, quantize=kv_quantize)
+    ops = model_ops.ops_for(cfg)
+    cache = ops.init_cache(cfg, b, max_len, quantize=kv_quantize)
     if temperature > 0.0 and key is None:
         raise ValueError('temperature > 0 requires a PRNG key')
     if key is None:
         key = jax.random.PRNGKey(0)  # unused in the greedy branch
 
-    logits, cache = _jit_prefill(params, prompt, cache, cfg,
-                                 prompt_lengths)
+    logits, cache = ops.prefill(params, prompt, cache, cfg,
+                                prompt_lengths)
     if temperature > 0.0:
         key, first_key = jax.random.split(key)
     else:

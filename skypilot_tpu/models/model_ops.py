@@ -1,0 +1,138 @@
+"""The one place the serving code asks "which model is this?".
+
+``generate.py``, ``paged.py`` and ``engine.py`` reach a model through
+``ops_for(cfg)``: a small table keyed on the config's TYPE that names
+the functions building and driving its caches, and says which engine
+features compose with it. No ``isinstance`` / ``num_experts`` tests in
+the loop: a new architecture is a new row here (and its own module),
+not a new branch there.
+
+The Llama row names exactly the functions the engine called before the
+table existed (its programs are unchanged); it is built lazily because
+those functions live in the modules that import this one.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, Callable, Dict
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelOps:
+    """What the serving code needs of a model family.
+
+    Caches: ``init_cache`` (dense, [B, max_len] rows; prefill scratch and
+    the slot layout), ``init_pool`` (paged). Programs, all jitted:
+    ``prefill(params, tokens, cache, cfg, row_lens) -> (logits, cache)``;
+    ``insert_paged(pool, cache_n, tables, slots) -> pool``;
+    ``fork_block(pool, src, dst) -> pool``;
+    ``prefill_shared(cfg, params, pool, tokens, table_row, slot, start,
+    slen, shard_ctx) -> (logits, pool)``;
+    ``paged_chunk(cfg, k, params, pool, last, temps, top_ks, top_ps,
+    active, key, shard_ctx) -> (pool, last, toks, counts)`` where
+    ``counts`` is None or the experts' token counts [E] of the chunk;
+    ``forward_cached``: the un-jitted dense forward (``generate``'s
+    window path scans it).
+
+    ``decode_attention(pool, quantized) -> str``: how the S = 1 step
+    reads the pool (``stats()['decode_attention']``).
+    ``rows_couple(cfg)``: True where co-batched rows influence each
+    other (capacity-dropping experts): pipelining, chunked prefill, the
+    prefix pool, block sharing, speculation and KV handoff all need
+    independent rows. ``refuses``: engine features this family does not
+    implement, each with the reason its error gives."""
+    name: str
+    init_cache: Callable
+    init_pool: Callable
+    prefill: Callable
+    forward_cached: Callable
+    insert_paged: Callable
+    fork_block: Callable
+    prefill_shared: Callable
+    paged_chunk: Callable
+    decode_attention: Callable
+    kv_bytes_per_token: Callable[[Any], int]
+    rows_couple: Callable[[Any], bool]
+    refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
+
+    def refuse(self, feature: str) -> None:
+        """Raise the family's reason if it refuses ``feature``."""
+        why = self.refuses.get(feature)
+        if why is not None:
+            raise ValueError(f'{feature} is not available for a '
+                             f'{self.name} model: {why}')
+
+
+def _llama() -> ModelOps:
+    from skypilot_tpu.models import engine, generate, paged
+
+    def paged_chunk(*args):
+        return (*engine._jit_paged_chunk(*args), None)
+
+    def kv_bytes(cfg) -> int:
+        import jax.numpy as jnp
+        return (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
+                * jnp.dtype(cfg.dtype).itemsize)
+
+    return ModelOps(
+        name='llama',
+        init_cache=generate.init_cache, init_pool=paged.init_pool,
+        prefill=generate._jit_prefill,
+        forward_cached=generate.forward_cached,
+        insert_paged=paged.jit_insert, fork_block=paged.jit_fork_block,
+        prefill_shared=paged.jit_prefill_shared, paged_chunk=paged_chunk,
+        decode_attention=lambda pool, quantized: paged.decode_path(
+            pool.tables.shape, pool.k.shape, pool.k.dtype, quantized),
+        kv_bytes_per_token=kv_bytes,
+        # The capacity path (models/moe.moe_mlp): expert capacity is per
+        # forward CALL, so a row's routing depends on its batchmates.
+        rows_couple=lambda cfg: cfg.num_experts > 0)
+
+
+def _mla_moe() -> ModelOps:
+    from skypilot_tpu.models import mla_moe, paged
+    one_plane = ('the latent cache is one plane of c_kv | k_rope rows; '
+                 'this path moves K and V planes')
+    return ModelOps(
+        name='mla_moe',
+        init_cache=mla_moe.init_cache, init_pool=mla_moe.init_pool,
+        prefill=mla_moe.jit_prefill,
+        forward_cached=mla_moe.forward_cached,
+        insert_paged=paged.jit_insert, fork_block=paged.jit_fork_block,
+        prefill_shared=mla_moe.jit_prefill_shared,
+        paged_chunk=mla_moe.jit_paged_chunk,
+        decode_attention=lambda pool, quantized: mla_moe.decode_path(
+            pool.tables.shape, pool.k.shape, pool.k.dtype),
+        kv_bytes_per_token=lambda cfg: cfg.kv_bytes_per_token,
+        rows_couple=lambda cfg: False,     # drop-free routing
+        refuses={
+            'kv_layout=slot': 'only the paged pool is wired into the '
+                              'engine (generate() serves the dense cache)',
+            'kv_quantize': 'the latent pool has no int8 mode',
+            'kv_tiers': one_plane,
+            'KV handoff': one_plane,
+            'speculative decoding': 'no S = k + 1 verify over the latent '
+                                    'pool',
+            'prefix_slots': 'the dense prefix pool stores K and V rows',
+            'prefill_chunk': 'the chunked long prefill seeds a dense K/V '
+                             'scratch row',
+            'tensor parallelism': 'the latent plane has one head: no '
+                                  'head-sharded pool or kernel'})
+
+
+_BUILDERS = {'LlamaConfig': _llama, 'MlaMoeConfig': _mla_moe}
+
+
+@functools.lru_cache(maxsize=None)
+def _ops(type_name: str) -> ModelOps:
+    return _BUILDERS[type_name]()
+
+
+def ops_for(cfg) -> ModelOps:
+    """The table's row for ``cfg``'s type."""
+    name = type(cfg).__name__
+    if name not in _BUILDERS:
+        raise TypeError(f'no serving ops for a config of type {name!r}; '
+                        f'known: {sorted(_BUILDERS)}')
+    return _ops(name)

@@ -67,9 +67,13 @@ class PagedKVCache:
     """Block pool + per-slot tables. ``k``/``v``: [L, NB, Hkv, P, D];
     ``tables``: [B, MB] int32 block ids (0 = junk sink / unallocated);
     ``lengths``: [B] tokens cached per slot. INT8 mode adds per-position
-    scales [L, NB, Hkv, P] (same recipe as the dense cache)."""
+    scales [L, NB, Hkv, P] (same recipe as the dense cache). A latent
+    (MLA) pool is ONE plane of ``c_kv | k_rope`` rows: ``k``
+    [L, NB, 1, P, W] and ``v`` None (models/mla_moe.py); the table, the
+    block accounting, the insert and the fork do not care how wide a
+    row is or how many planes there are."""
     k: jax.Array
-    v: jax.Array
+    v: Optional[jax.Array]
     tables: jax.Array
     lengths: jax.Array
     k_s: Optional[jax.Array] = None
@@ -172,7 +176,7 @@ def _insert_impl(pool: PagedKVCache, cache_n, tables_new: jax.Array,
         return pool_s.at[:, tables_new[:, :nb].reshape(-1)].set(v)
 
     k = scatter(pool.k, cache_n.k)
-    v = scatter(pool.v, cache_n.v)
+    v = None if pool.v is None else scatter(pool.v, cache_n.v)
     k_s, v_s = pool.k_s, pool.v_s
     if pool.quantized:
         k_s = scatter_s(pool.k_s, cache_n.k_s)
@@ -734,7 +738,7 @@ def _fork_block_impl(pool: PagedKVCache, src: jax.Array,
     partial length are overwritten by the tail prefill / decode writes
     and never attended before that)."""
     k = pool.k.at[:, dst].set(pool.k[:, src])
-    v = pool.v.at[:, dst].set(pool.v[:, src])
+    v = None if pool.v is None else pool.v.at[:, dst].set(pool.v[:, src])
     k_s, v_s = pool.k_s, pool.v_s
     if pool.quantized:
         k_s = k_s.at[:, dst].set(k_s[:, src])

@@ -156,6 +156,18 @@ PROGRAMS: Tuple[Program, ...] = (
     Program('paged.prefill_shared',
             'Suffix prefill directly over the pool (the block-share '
             'hit path): one shape per tail bucket.', budget=12),
+    # -- models/mla_moe.py --------------------------------------------
+    Program('mla_moe.prefill',
+            'Latent-attention prefill into a dense one-plane cache: one '
+            'shape per prompt bucket x admission-group size.', budget=24),
+    Program('mla_moe.prefill_shared',
+            'Suffix prefill directly over the latent pool (the '
+            'block-share hit path): one shape per tail bucket.',
+            budget=12),
+    Program('mla_moe.paged_chunk',
+            'The K-step decode chunk over the latent pool (absorbed '
+            'attention, drop-free experts); also returns the experts\' '
+            'token counts.', budget=4),
     # -- models/speculative.py ----------------------------------------
     Program('spec.propose',
             'k+1 greedy draft proposal steps (solo speculative '

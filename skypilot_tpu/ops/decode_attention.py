@@ -335,3 +335,130 @@ def paged_decode(q: jax.Array, k_plane: jax.Array, v_plane: jax.Array,
         interpret=interpret, name='paged_decode',
     )(tables, valid, q.reshape(b, hkv, g, d), k_plane, v_plane)
     return out.reshape(b, hq, d)
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) paged decode: the absorbed form. One shared "KV head" of
+# ``R + Dr`` numbers a position (the compressed c_kv and the one rotary
+# key), all query heads against it, and the values are the first ``R``
+# columns of the same rows: the plane is read ONCE.
+
+
+def mla_fits(slots: int, max_blocks: int, block: int, dtype) -> bool:
+    """``paged_fits`` for a latent plane: a float pool, blocks of whole
+    sublane tiles, rows of whole lane tiles (``latent_width``), tables
+    and lengths inside SMEM."""
+    return paged_fits(slots, max_blocks, block, 128, dtype)
+
+
+def latent_width(rank: int, rope_dim: int) -> int:
+    """Width of a latent row in the pool: ``rank + rope_dim`` rounded up
+    to whole 128-lane tiles, the tail zero. HBM arrays are tiled
+    (8, 128) x dtype packing, so a [.., P, 576] plane occupies 640
+    columns a row whether it says so or not (and 512 + 64 as two planes
+    would occupy 512 + 128); Mosaic refuses to slice 576 of the 640 for
+    a DMA ("must be aligned to tiling (128)"), so the plane says so."""
+    return -(-(rank + rope_dim) // 128) * 128
+
+
+def _mla_kernel(layer_ref, tables_ref, valid_ref, q_ref, kv_hbm, o_ref,
+                kv_buf, sem, *, block: int, group: int, rank: int,
+                scale: float):
+    """One slot per program. q_ref [H, W] (the absorbed queries, zero
+    past R + Dr), o_ref [H, R]; kv_hbm the WHOLE latent pool
+    [L, NB, 1, P, W], left in HBM, of which layer ``layer_ref[0]`` is
+    read (a sliced plane would be a copy of the plane a layer a step). ``_paged_kernel``'s walk (groups of blocks by DMA into
+    a double buffer, as far as ``valid`` reaches) and online softmax;
+    the values are ``kv_buf[..., :rank]``: no second plane, no second
+    DMA."""
+    b = pl.program_id(0)
+    q = q_ref[...]
+    h = q.shape[0]
+    span = group * block
+    valid = valid_ref[b]
+    n_blocks = pl.cdiv(valid, block)
+    n_groups = pl.cdiv(valid, span)
+
+    @pl.when(b == 0)
+    def _():
+        # see _paged_kernel: 0 x (uninitialized VMEM) must not be NaN
+        kv_buf[...] = jnp.zeros(kv_buf.shape, kv_buf.dtype)
+
+    def group_dma(gi, slot, act):
+        for j in range(group):
+            i = gi * group + j
+
+            @pl.when(i < n_blocks)
+            def _(i=i, j=j):
+                act(pltpu.make_async_copy(
+                    kv_hbm.at[layer_ref[0], tables_ref[b, i], 0],
+                    kv_buf.at[slot, pl.ds(j * block, block), :],
+                    sem.at[slot]))
+
+    group_dma(0, 0, lambda c: c.start())
+
+    def body(gi, carry):
+        acc, m_prev, l_prev = carry
+        slot = gi % 2
+
+        @pl.when(gi + 1 < n_groups)
+        def _():
+            group_dma(gi + 1, 1 - slot, lambda c: c.start())
+
+        group_dma(gi, slot, lambda c: c.wait())
+        kv = kv_buf[slot].astype(q.dtype)                 # [span, R + Dr]
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # [H, span]
+        ki = gi * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(ki < valid, s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(q.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [H, R]
+        return acc, m_new, l_new
+
+    acc0 = jnp.zeros((h, rank), jnp.float32)
+    m0 = jnp.full((h, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((h, 1), jnp.float32)
+    acc, _, l = jax.lax.fori_loop(0, n_groups, body, (acc0, m0, l0))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def mla_decode(q: jax.Array, pool: jax.Array, layer: jax.Array,
+               tables: jax.Array, valid: jax.Array, rank: int,
+               scale: float, interpret: bool = False) -> jax.Array:
+    """Absorbed queries q [B, H, R + Dr] (the single decode position)
+    against layer ``layer`` (int32 scalar) of the latent pool
+    [L, NB, 1, P, W] (W = ``latent_width``: rows ``c_kv | k_rope | 0``)
+    under block tables [B, MB]: row b
+    attends positions < valid[b]; the values are the rows' first
+    ``rank`` columns. -> [B, H, R] (still latent: the caller
+    up-projects). ``scale`` multiplies the logits. valid[b] == 0 reads
+    nothing and returns zeros. Callers gate on ``mla_fits``."""
+    b, h, _ = q.shape
+    _, nb, _, block, width = pool.shape
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, width - q.shape[-1])))
+    mb = tables.shape[1]
+    group = _pick_group(mb)
+    valid = jnp.clip(valid.astype(jnp.int32), 0, mb * block)
+    tables = jnp.clip(tables.astype(jnp.int32), 0, nb - 1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(b,),
+        in_specs=[pl.BlockSpec((None, h, width), lambda bi, *_: (bi, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, h, rank), lambda bi, *_: (bi, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, group * block, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    return pl.pallas_call(
+        functools.partial(_mla_kernel, block=block, group=group, rank=rank,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=interpret, name='mla_decode',
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tables, valid, q, pool)

@@ -125,3 +125,125 @@ def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
     pool_layouts = set(re.findall(
         r'bf16\[2,2049,8,16,128\]\{([\d,]+)', hlo))
     assert pool_layouts == {'4,3,2,1,0'}, pool_layouts
+
+
+# -- the latent (MLA) cell: xing-docs-sessions -------------------------------
+
+# 48 slots, max_len 4096 in blocks of 16 out of a pool of 8,193, 32 heads
+# over one 512 + 64 latent row (stored 640 wide), bf16.
+MLA_CELL = dict(slots=48, max_blocks=256, block=16, blocks=8193)
+
+
+def _mla_cfg(layers=3):
+    from skypilot_tpu.models import mla_moe
+    return mla_moe.MlaMoeConfig(vocab_size=1024, n_layers=layers,
+                                n_dense_layers=1, max_seq_len=32768)
+
+
+def test_mla_decode_compiles_at_the_cells_geometry(one_chip):
+    """One 576-number row a position pads to 640 in the HBM tiling
+    whichever way it is stored; Mosaic refuses a 576-wide slice of it,
+    so the pool states 640 (``decode_attention.latent_width``)."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = MLA_CELL
+    assert decode_attention.latent_width(512, 64) == 640
+    assert decode_attention.mla_fits(c['slots'], c['max_blocks'], c['block'],
+                                     jnp.bfloat16)
+
+    def compile_at(width):
+        # skylint: allow-jit(test-only compile check)
+        return jax.jit(lambda q, p, t, v: decode_attention.mla_decode(
+            q, p, jnp.int32(1), t, v, 512, 0.1)).lower(
+            sds((c['slots'], 32, 576), jnp.bfloat16),
+            sds((2, c['blocks'], 1, c['block'], width), jnp.bfloat16),
+            sds((c['slots'], c['max_blocks']), jnp.int32),
+            sds((c['slots'],), jnp.int32)).compile()
+
+    hlo = compile_at(640).as_text()
+    assert re.search(r'%mla_decode[.\d]* = .*custom-call\(', hlo)
+    with pytest.raises(Exception, match='aligned to tiling'):
+        compile_at(576)
+
+
+def test_mla_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
+                                                              monkeypatch):
+    """The whole decode chunk of the latent model as the engine builds
+    it (one dense and two expert layers at the published widths): the
+    kernel takes the WHOLE pool and a layer index, the row scatter
+    updates the carried pool in place, and the experts' stacked weights
+    reach the grouped matmul without a slice. So no copy and no layout
+    change of the pool or of an expert stack stands in front of a Mosaic
+    call (a sliced stack did: 64% of the step on the chip, PR 28)."""
+    from skypilot_tpu.models import mla_moe
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg = _mla_cfg()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    c = MLA_CELL
+    slots = c['slots']
+    params = on_chip(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: mla_moe.init_pool(
+        cfg, slots, c['max_blocks'] * c['block'], c['blocks'], c['block'])))
+    assert pool.v is None and pool.k.shape == (3, 8193, 1, 16, 640)
+    assert mla_moe.decode_path(pool.tables.shape, pool.k.shape,
+                               pool.k.dtype) == 'mla_kernel'
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (slots,), dtype,
+                                    sharding=one_chip)
+
+    hlo = mla_moe.jit_paged_chunk.lower(
+        cfg, 2, params, pool, vec(jnp.int32), vec(jnp.float32), None, None,
+        vec(jnp.bool_), vec(jnp.uint32, 2), None).compile().as_text()
+    # name -> (result type, op) of every instruction
+    defs = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r'(%[\w.-]+) = (\S+) ([\w-]+)\(', hlo)}
+    calls = re.findall(r'%(mla_decode|ragged-dot-none)[.\d]* = [^\n]*'
+                       r'custom-call\(([^)]*)\)', hlo)
+    assert {name for name, _ in calls} == {'mla_decode', 'ragged-dot-none'}
+    for name, operands in calls:
+        # the pool, or a whole expert stack (2 layers x 64): the last
+        # operand, reached as a view, never through a copy or a slice
+        shape, op = defs[operands.split(',')[-1].strip()]
+        want = (r'bf16\[3,8193,1,16,640\]' if name == 'mla_decode'
+                else r'bf16\[128,\d+,\d+\]')
+        assert re.match(want, shape), (name, shape)
+        assert op in ('bitcast', 'get-tuple-element', 'parameter',
+                      'copy-done'), (name, shape, op)
+    # the pool keeps its row-major layout from the arguments to the call
+    assert set(re.findall(r'bf16\[3,8193,1,16,640\]\{([\d,]+)', hlo)) == {
+        '4,3,2,1,0'}
+    assert 'kernel-fallback' not in hlo
+
+
+def test_mla_prefill_takes_the_flash_kernel_at_the_cells_widest(one_chip,
+                                                                monkeypatch):
+    """A cold 2,048-token document pads to 4,096 positions: qk 192 wide
+    puts S x D at 786k of the kernel's 1M cap, V rides zero-padded to
+    192. No jnp fallback, and the program fits beside the weights."""
+    from skypilot_tpu.models import mla_moe
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg = _mla_cfg(layers=2)
+    assert attention._unsupported((1, 32, 4096, 192)) is None
+    assert attention._unsupported((1, 32, 8192, 192)) is not None
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: mla_moe.init_cache(cfg, 1, 4096)))
+    compiled = mla_moe.jit_prefill.lower(
+        params, jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip),
+        cache, cfg, jax.ShapeDtypeStruct((1,), jnp.int32,
+                                         sharding=one_chip)).compile()
+    hlo = compiled.as_text()
+    assert re.search(r'%flash_fwd[.\d]* = .*custom-call\(', hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
