@@ -741,29 +741,31 @@ def child_kernels(rehearse: bool, meshes) -> int:
         tables[2, 0] = tables[0, 0]
         return valid, tables
 
-    def paged_case(slots, blocks, block, max_blocks, hq, hkv, d):
-        """``paged_decode`` over a pool laid out as the engine leaves
-        it (live rows of every length class, empty slots, a prefix
-        shared by two rows, tables padded with the junk sink) against
-        the gather + einsum path on the same pool."""
+    def paged_case(slots, blocks, block, max_blocks, hq, hkv, d, layers=2):
+        """``paged_decode`` over layer 1 of pools laid out as the engine
+        leaves them (live rows of every length class, empty slots, a
+        prefix shared by two rows, tables padded with the junk sink)
+        against the gather + einsum path on the same pools."""
         from skypilot_tpu.models import paged as paged_lib
         key = jax.random.PRNGKey(slots)
         q = jax.random.normal(key, (slots, hq, d), jnp.bfloat16)
         kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
-                                    (blocks, hkv, block, d), jnp.bfloat16)
-                  for i in (1, 2))
+                                    (layers, blocks, hkv, block, d),
+                                    jnp.bfloat16) for i in (1, 2))
         valid, tables = pool_layout(slots, blocks, block, max_blocks)
         args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(valid))
         assert interpret or decode_attention.paged_fits(
             slots, max_blocks, block, d, kp.dtype)
         # skylint: allow-jit(one-shot numerics check, not a program)
-        got = jax.jit(lambda *a: decode_attention.paged_decode(
-            *a, interpret=interpret))(*args)
+        got = jax.jit(lambda q_, k_, v_, t_, n_:
+                      decode_attention.paged_decode(
+                          q_, k_, v_, jnp.int32(1), t_, n_,
+                          interpret=interpret))(*args)
         # skylint: allow-jit(one-shot numerics check, not a program)
         want = jax.jit(lambda q_, k_, v_, t_, n_:
                        paged_lib._gather_attention(
-                           q_[:, None], k_, v_, t_, (n_ - 1)[:, None], n_,
-                           None, None, None)[:, 0])(*args)
+                           q_[:, None], k_, v_, None, None, 1, t_,
+                           n_ - 1)[:, 0])(*args)
         live = valid > 0
         err = rel_err(np.asarray(got, np.float32)[live],
                       np.asarray(want, np.float32)[live])

@@ -45,7 +45,7 @@ import numpy as np
 from skypilot_tpu.models import moe, sampling
 from skypilot_tpu.models.generate import KVCache
 from skypilot_tpu.models.llama import rms_norm
-from skypilot_tpu.models.paged import PagedKVCache, _scatter_rows
+from skypilot_tpu.models.paged import PagedKVCache, pool_view, pool_write
 from skypilot_tpu.observability.profiler import profiled_jit
 from skypilot_tpu.ops import attention as attention_ops
 from skypilot_tpu.ops import decode_attention
@@ -627,22 +627,10 @@ def init_pool(cfg: MlaMoeConfig, slots: int, max_len: int, n_blocks: int,
         lengths=jnp.zeros((slots,), jnp.int32, device=lengths_sharding))
 
 
-def _pool_write(pool: jax.Array, l, tables: jax.Array, lengths: jax.Array,
-                latent: jax.Array, active_rows) -> jax.Array:
-    """Write latent rows [B, S, W] of layer ``l`` at positions
-    [lengths, lengths + S) through the tables: ``paged._scatter_rows``
-    on the pool seen as one plane of L * NB blocks (row-major, so the
-    view is free and the scatter updates the carried pool in place)."""
-    n_l, nb = pool.shape[:2]
-    flat = pool.reshape((n_l * nb,) + pool.shape[2:])
-    flat = _scatter_rows(flat, tables + l * nb, lengths,
-                         latent[:, None].astype(pool.dtype), active_rows)
-    return flat.reshape(pool.shape)
-
-
 def _pool_view(pool: jax.Array, l, tables: jax.Array) -> jax.Array:
-    """Every row's whole table out of layer ``l``: [B, MB * P, W]."""
-    g = pool[l][tables]                          # [B, MB, 1, P, W]
+    """Every row's whole table out of layer ``l`` (``paged.pool_view``:
+    the named blocks only, no plane is sliced out): [B, MB * P, W]."""
+    g = pool_view(pool, l, tables)               # [B, MB, 1, P, W]
     return g.reshape(g.shape[0], -1, g.shape[-1])
 
 
@@ -667,7 +655,8 @@ def forward_paged(params: Params, tokens: jax.Array, cache: PagedKVCache,
 
     def attend(h, layer, pool, l):
         q, latent = _q_and_latent(cfg, h, layer, positions)
-        pool = _pool_write(pool, l, tables, lengths, latent, active_rows)
+        pool = pool_write(pool, l, tables, lengths, latent[:, None],
+                          active_rows)
         if s > 1:
             att = _attend_view(cfg, q, _pool_view(pool, l, tables), layer,
                                positions, lengths + s)

@@ -16,23 +16,30 @@ TPU-first shape discipline (vs the GPU original's per-block kernels):
 * the pool is one static ``[L, NB, Hkv, P, D]`` buffer; block tables
   are a ``[B, MB]`` int32 array — every shape is fixed at engine
   construction, so decode remains ONE compiled program;
-* decode writes are per-row scatters at ``table[b, len // P]``, offset
-  ``len % P``. The decode step (S = 1, float pool) then READS THROUGH
-  THE TABLE, BY LENGTH: ``ops/decode_attention.paged_decode`` walks
-  each slot's blocks out of the layer's plane by DMA, only as far as
-  the slot's length, with the einsum path's precision (``_kernel_step``;
+* the pool is never taken apart. The layer scan CARRIES it
+  (``forward_paged``), as the chunk's step scan carries the cache, and
+  every write and read addresses it by layer index: a plane sliced out
+  of ``xs`` and stacked back into ``ys`` was a copy of the plane a
+  layer and of the whole pool a step (PR 29: 20.7 of a 28.5 ms step);
+* writes are row scatters at ``table[b, len // P]``, offset ``len % P``,
+  into the pool seen as ``L * NB`` blocks (``pool_write``). The decode
+  step (S = 1, float pool) then READS THROUGH THE TABLE, BY LENGTH:
+  ``ops/decode_attention.paged_decode`` is handed the whole pools and
+  the layer, and walks each slot's blocks by DMA only as far as the
+  slot's length, with the einsum path's precision (``_cache_step``;
   ``decode_path`` is the one rule for when). Measured on a v5e at 48
   slots x 2,048 positions, 8 kv heads x 128 (PR 26, PERF.md §6): 66 us
   a layer with 24 rows live at ~230 positions, 0.6 ms with every row
   full, against 2.7 ms a layer whatever the slots hold for the dense
-  view below; the step fell from 92 ms to 28.5;
+  view below;
 * everything else — the speculative verify (S = k + 1), the
   shared-prefix prefill (S = W, one row), int8 pools, backends without
-  the kernel — GATHERS each row's whole table into the standard
-  ``[B, H, MB·P, D]`` attention view and reuses the dense cache's math
-  (``_gather_attention`` -> ``generate._cached_attention``). That is
-  all of ``max_len`` for every slot, live or not, per layer per step:
-  70% of the decode step when S = 1 still took it (ledger, PR 25);
+  the kernel — GATHERS each row's whole table out of the same flat view
+  (``pool_view``) into the standard ``[B, H, MB·P, D]`` attention view
+  and reuses the dense cache's math (``_gather_attention`` ->
+  ``generate._cached_attention``). That is all of ``max_len`` for every
+  slot, live or not, per layer per step: 70% of the decode step when
+  S = 1 still took it (ledger, PR 25);
 * unallocated table entries point at block 0, a dedicated JUNK SINK no
   request ever owns: freed slots keep decoding (static shapes forbid
   shrinking the batch) and their overflow writes land harmlessly there.
@@ -217,28 +224,17 @@ def _block_offsets(tables: jax.Array, lengths: jax.Array, s: int,
     return blk.reshape(-1), (pos % p).reshape(-1)
 
 
-def _scatter_multi(pool: jax.Array, tables: jax.Array,
-                   lengths: jax.Array, new: jax.Array,
-                   active_rows) -> jax.Array:
-    """Scatter ``new`` [B, H, S, D] at positions [lengths, lengths+S)
-    per row into ``pool`` [NB, H, P, D] under ``tables`` [B, MB]."""
-    b, h, s, d = new.shape
-    blk, off = _block_offsets(tables, lengths, s, pool.shape[2],
-                              active_rows)
-    vals = new.transpose(0, 2, 1, 3).reshape(b * s, h, d)
-    return pool.at[blk, :, off].set(vals)
-
-
 def _scatter_rows(pool: jax.Array, tables: jax.Array,
                   lengths: jax.Array, new: jax.Array,
                   active_rows) -> jax.Array:
-    """``_scatter_multi`` written as a scatter of [D] rows into the
-    plane seen as [NB*H*P, D]: the same writes, but the window is the
+    """Scatter ``new`` [B, H, S, D] at positions [lengths, lengths+S)
+    per row into ``pool`` [NB, H, P, D] under ``tables`` [B, MB], as
+    [D] rows into the plane seen as [NB*H*P, D]: the window is the
     plane's minor dim, so XLA keeps the pool in its row-major layout —
-    the one a Mosaic call's operands must have. For the [H, D] slabs of
-    ``_scatter_multi`` the TPU compiler re-lays the whole pool as
-    [NB, P, H, D] inside the decode loop and converts every layer's
-    plane back in front of the kernel (PR 26: 9 ms of a 38 ms step)."""
+    the one a Mosaic call's operands must have. Written as a scatter of
+    [H, D] slabs (``pool.at[blk, :, off]``) the TPU compiler re-lays the
+    whole pool as [NB, P, H, D] inside the decode loop and converts it
+    back in front of the kernel (PR 26: 9 ms of a 38 ms step)."""
     b, h, s, d = new.shape
     nb, _, p, _ = pool.shape
     blk, off = _block_offsets(tables, lengths, s, p, active_rows)
@@ -252,12 +248,40 @@ def _scatter_rows(pool: jax.Array, tables: jax.Array,
 def _scatter_multi_s(pool_s: jax.Array, tables: jax.Array,
                      lengths: jax.Array, new_s: jax.Array,
                      active_rows) -> jax.Array:
-    """[B, H, S] scale-plane counterpart of ``_scatter_multi``."""
+    """[B, H, S] scale-plane counterpart of ``_scatter_rows`` ([H]
+    slabs: a scale plane feeds no Mosaic call)."""
     b, h, s = new_s.shape
     blk, off = _block_offsets(tables, lengths, s, pool_s.shape[2],
                               active_rows)
     vals = new_s.transpose(0, 2, 1).reshape(b * s, h)
     return pool_s.at[blk, :, off].set(vals)
+
+
+def _layer_blocks(pool: jax.Array, l, tables: jax.Array):
+    """The pool [L, NB, ...] seen as one plane of L * NB blocks
+    (row-major, so the view is free) and ``tables`` moved to layer
+    ``l``'s blocks of it. A write through it updates the carried pool
+    in place and a read touches the named blocks only, where ``pool[l]``
+    is a copy of the plane. Inactive rows still land in block 0 (layer
+    0's junk sink)."""
+    return pool.reshape((-1,) + pool.shape[2:]), tables + l * pool.shape[1]
+
+
+def pool_write(pool: jax.Array, l, tables: jax.Array, lengths: jax.Array,
+               new: jax.Array, active_rows) -> jax.Array:
+    """Write ``new`` [B, H, S, D] (a scale plane's: [B, H, S]) of layer
+    ``l`` (a traced scalar) at positions [lengths, lengths + S) through
+    the tables."""
+    flat, blocks = _layer_blocks(pool, l, tables)
+    scatter = _scatter_rows if new.ndim == 4 else _scatter_multi_s
+    return scatter(flat, blocks, lengths, new.astype(pool.dtype),
+                   active_rows).reshape(pool.shape)
+
+
+def pool_view(pool: jax.Array, l, tables: jax.Array) -> jax.Array:
+    """Every row's whole table out of layer ``l``: [B, MB, H, P(, D)]."""
+    flat, blocks = _layer_blocks(pool, l, tables)
+    return flat[blocks]
 
 
 def decode_path(tables_shape, plane_shape, dtype, quantized: bool) -> str:
@@ -269,7 +293,7 @@ def decode_path(tables_shape, plane_shape, dtype, quantized: bool) -> str:
     training kernel (a TPU; the interpreter only where a test asks for
     it by name) and the pool's geometry. A pool the kernel cannot take
     on a backend that has the kernel is said once per shape. The ONE
-    definition: ``_paged_layer`` branches on it and the engine reports
+    definition: ``_cache_step`` branches on it and the engine reports
     it (``stats()['decode_attention']``)."""
     if not (attention_ops._use_pallas()
             or decode_attention.PAGED_INTERPRET):
@@ -285,84 +309,94 @@ def decode_path(tables_shape, plane_shape, dtype, quantized: bool) -> str:
     return 'gather'
 
 
-def _kernel_step(q: jax.Array, kt: jax.Array, vt: jax.Array,
-                 k_pool: jax.Array, v_pool: jax.Array, tables: jax.Array,
-                 lengths: jax.Array, active_rows, shard_ctx):
-    """The S = 1 step's cache write and read where ``paged_decode``
-    runs: q [B, Hq, D] and this step's kt/vt [B, Hkv, 1, D] against the
-    planes [NB, Hkv, P, D]. Scatters kt/vt at ``lengths`` (inactive
-    rows into the junk sink, as ever), then attends positions <=
-    ``lengths`` through the block table. Inactive rows read nothing
-    (valid 0): their output is never used and their stale tables may
-    name blocks that now belong to another request.
-    -> (att [B, Hq, D], k_pool, v_pool)."""
-    if active_rows is None:
-        active_rows = jnp.ones(lengths.shape, bool)
+def _gather_attention(q, k_pool, v_pool, k_s, v_s, l, tables,
+                      lengths) -> jax.Array:
+    """Attention of q [B, S, Hq, D] at positions [lengths, lengths + S)
+    over a dense view: every row's whole table gathered out of layer
+    ``l``, [B, MB, H, P, D] -> [B, H, MB*P, D], then the dense cache's
+    math. What S > 1 (speculative verify, shared-prefix prefill), int8
+    pools and backends without the kernel take."""
+    s = q.shape[1]
 
-    def step(q, kt, vt, k_pool, v_pool, tables, lengths, active):
-        k_pool = _scatter_rows(k_pool, tables, lengths, kt, active)
-        v_pool = _scatter_rows(v_pool, tables, lengths, vt, active)
-        att = decode_attention.paged_decode(
-            q, k_pool, v_pool, tables, jnp.where(active, lengths + 1, 0),
-            interpret=not attention_ops._use_pallas())
-        return att, k_pool, v_pool
+    def view(pool):
+        if pool is None:
+            return None
+        g = jnp.moveaxis(pool_view(pool, l, tables), 2, 1)  # B,H,MB,P..
+        return g.reshape(g.shape[:2] + (-1,) + g.shape[4:])
 
-    args = (q, kt, vt, k_pool, v_pool, tables, lengths, active_rows)
+    positions = lengths[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+    return _cached_attention(q, view(k_pool), view(v_pool), positions,
+                             lengths + s, view(k_s), view(v_s))
+
+
+def _cache_step(q: jax.Array, kt: jax.Array, vt: jax.Array, pools, l,
+                tables: jax.Array, lengths: jax.Array, active_rows,
+                shard_ctx):
+    """One layer's cache write and read: q [B, S, Hq, D] and this
+    step's kt/vt [B, Hkv, S, D] against layer ``l`` of the WHOLE pools
+    ``(k, v, k_s, v_s)`` ([L, NB, Hkv, P, D]; the scale planes
+    [L, NB, Hkv, P] or None). Scatters kt/vt (an int8 pool: their codes
+    and scales) at [lengths, lengths + S), inactive rows into the junk
+    sink, as ever. Then attends: where ``decode_path`` says so,
+    ``paged_decode`` through the block table, positions <= ``lengths``
+    (inactive rows read nothing, valid 0: their output is never used and
+    their stale tables may name blocks that now belong to another
+    request); else over the gathered view.
+    -> (att [B, S, Hq, D], pools)."""
+    kernel = q.shape[1] == 1 and decode_path(
+        tables.shape, pools[0].shape, pools[0].dtype,
+        pools[2] is not None) == 'paged_kernel'
+
+    def step(q, kt, vt, pools, l, tables, lengths, active):
+        k_pool, v_pool, k_s, v_s = pools
+        if k_s is not None:
+            (kt, ks_new), (vt, vs_new) = (_quantize_block(kt),
+                                          _quantize_block(vt))
+            k_s = pool_write(k_s, l, tables, lengths, ks_new, active)
+            v_s = pool_write(v_s, l, tables, lengths, vs_new, active)
+        k_pool = pool_write(k_pool, l, tables, lengths, kt, active)
+        v_pool = pool_write(v_pool, l, tables, lengths, vt, active)
+        if kernel:
+            valid = lengths + 1
+            if active is not None:
+                valid = jnp.where(active, valid, 0)
+            att = decode_attention.paged_decode(
+                q[:, 0], k_pool, v_pool, l, tables, valid,
+                interpret=not attention_ops._use_pallas())[:, None]
+        else:
+            att = _gather_attention(q, k_pool, v_pool, k_s, v_s, l,
+                                    tables, lengths)
+        return att, (k_pool, v_pool, k_s, v_s)
+
+    args = (q, kt, vt, pools, l, tables, lengths, active_rows)
     if shard_ctx is None:
         return step(*args)
     # TP serving: write and read per kv-head shard (heads are
     # independent; GSPMD cannot partition a Mosaic call, nor the row
     # scatter's reshape over the sharded head dim: either would gather
-    # the pool). Tables, lengths and every batch dim replicated: a
-    # table indexes the whole pool. check_vma off: see
+    # the pool). The layer, tables, lengths and every batch dim
+    # replicated: a table indexes the whole pool. check_vma off: see
     # generate._cached_attention.
     mesh, p_q, p_kv = shard_ctx[:3]
-    heads = jax.sharding.PartitionSpec(None, p_q[1], None)
-    planes = jax.sharding.PartitionSpec(None, p_kv[1], None, None)
-    rep = jax.sharding.PartitionSpec()
+    spec = jax.sharding.PartitionSpec
+    heads, new = spec(None, None, p_q[1], None), spec(None, p_kv[1])
+    pool = spec(None, None, p_kv[1])
     return jax.shard_map(
         step, mesh=mesh,
-        in_specs=(heads, planes, planes, planes, planes, rep, rep, rep),
-        out_specs=(heads, planes, planes), check_vma=False)(*args)
+        in_specs=(heads, new, new, pool, spec(), spec(), spec(), spec()),
+        out_specs=(heads, pool), check_vma=False)(*args)
 
 
-def _gather_attention(q, k_pool, v_pool, tables, positions, valid, k_s,
-                      v_s, shard_ctx) -> jax.Array:
-    """Attention over a dense view: every row's whole table gathered
-    out of the pool, [B, MB, H, P, D] -> [B, H, MB*P, D], then the
-    dense cache's math. What S > 1 (speculative verify, shared-prefix
-    prefill), int8 pools and backends without the kernel take."""
-    b, mb = tables.shape
-    p = k_pool.shape[2]
-
-    def view(pool):
-        g = pool[tables]  # [B, MB, H, P, D]
-        g = g.transpose(0, 2, 1, 3, 4)
-        return g.reshape(b, g.shape[1], mb * p, g.shape[4])
-
-    def view_s(pool_s):
-        g = pool_s[tables]  # [B, MB, H, P]
-        g = g.transpose(0, 2, 1, 3)
-        return g.reshape(b, g.shape[1], mb * p)
-
-    return _cached_attention(
-        q, view(k_pool), view(v_pool), positions, valid,
-        view_s(k_s) if k_s is not None else None,
-        view_s(v_s) if v_s is not None else None, shard_ctx)
-
-
-def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
-                 lengths: jax.Array, tables: jax.Array,
-                 k_pool: jax.Array, v_pool: jax.Array,
-                 active_rows: Optional[jax.Array],
-                 k_s: Optional[jax.Array], v_s: Optional[jax.Array],
-                 shard_ctx=None):
+def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer, l,
+                 lengths: jax.Array, tables: jax.Array, pools,
+                 active_rows: Optional[jax.Array], shard_ctx=None):
     """One decoder block at S>=1 over the paged pool. x: [B, S, d]
-    (S=1 decode step; S=k+1 speculative verify). The math is
-    generate.py's (_qkv_proj/_cached_attention/_mlp_tail); only the
-    cache write (pool scatter) and read (through the table in the
-    kernel, or the block gather) differ from the dense layer. INACTIVE
-    rows scatter to the junk sink (block 0)
+    (S=1 decode step; S=k+1 speculative verify); ``pools`` the whole
+    ``(k, v, k_s, v_s)`` of every layer, of which this is layer ``l``.
+    The math is generate.py's (_qkv_proj/_cached_attention/_mlp_tail);
+    only the cache write (pool scatter) and read (through the table in
+    the kernel, or the block gather) differ from the dense layer:
+    ``_cache_step``. INACTIVE rows scatter to the junk sink (block 0)
     unconditionally: a freed slot's stale table may point at blocks
     already reallocated to another request, and an unmasked junk write
     there would corrupt the new owner's live KV. Within a chunk a
@@ -372,33 +406,9 @@ def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
     positions = (lengths[:, None]
                  + jnp.arange(s, dtype=jnp.int32)[None])  # [B, S]
     q, k, v = _qkv_proj(cfg, x, layer, positions)
-    kt = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, D]
-    vt = v.transpose(0, 2, 1, 3)
-    if s == 1 and decode_path(tables.shape, k_pool.shape, k_pool.dtype,
-                              k_s is not None) == 'paged_kernel':
-        att, k_pool, v_pool = _kernel_step(
-            q[:, 0], kt.astype(k_pool.dtype), vt.astype(v_pool.dtype),
-            k_pool, v_pool, tables, lengths, active_rows, shard_ctx)
-        att = att[:, None]
-    else:
-        if k_s is not None:
-            k8, ks_new = _quantize_block(kt)
-            v8, vs_new = _quantize_block(vt)
-            k_pool = _scatter_multi(k_pool, tables, lengths, k8,
-                                    active_rows)
-            v_pool = _scatter_multi(v_pool, tables, lengths, v8,
-                                    active_rows)
-            k_s = _scatter_multi_s(k_s, tables, lengths, ks_new,
-                                   active_rows)
-            v_s = _scatter_multi_s(v_s, tables, lengths, vs_new,
-                                   active_rows)
-        else:
-            k_pool = _scatter_multi(k_pool, tables, lengths,
-                                    kt.astype(k_pool.dtype), active_rows)
-            v_pool = _scatter_multi(v_pool, tables, lengths,
-                                    vt.astype(v_pool.dtype), active_rows)
-        att = _gather_attention(q, k_pool, v_pool, tables, positions,
-                                lengths + s, k_s, v_s, shard_ctx)
+    att, pools = _cache_step(
+        q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), pools, l,
+        tables, lengths, active_rows, shard_ctx)
     x = x + _mm(att, layer['wo'], 'bshk,hkd->bsd')
     token_mask = None
     if cfg.num_experts > 0:
@@ -407,7 +417,7 @@ def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer,
             mask = mask & active_rows[:, None]
         token_mask = mask.astype(x.dtype)
     x = _mlp_tail(cfg, x, layer, token_mask)
-    return x, k_pool, v_pool, k_s, v_s
+    return x, pools
 
 
 def forward_paged(params, tokens: jax.Array, cache: PagedKVCache,
@@ -424,31 +434,20 @@ def forward_paged(params, tokens: jax.Array, cache: PagedKVCache,
     every proposed token); ``logit_index`` [B] instead picks each row's
     own last REAL position (padded prefill). The structural twin of
     ``generate.forward_cached`` with pool scatter/gather replacing the
-    dense row update."""
+    dense row update; the pools ride the layer scan as a CARRY, whole
+    (see the module header), for every S and pool kind."""
     x = params['embed'].astype(cfg.dtype)[tokens]
     s = tokens.shape[1]
-    quantized = cache.quantized
 
-    def body(carry, xs):
-        x = carry
-        if quantized:
-            layer, k_p, v_p, ks_p, vs_p = xs
-        else:
-            layer, k_p, v_p = xs
-            ks_p = vs_p = None
-        x, k_p, v_p, ks_p, vs_p = _paged_layer(
-            cfg, x, layer, cache.lengths, cache.tables, k_p, v_p,
-            active_rows, ks_p, vs_p, shard_ctx)
-        ys = (k_p, v_p, ks_p, vs_p) if quantized else (k_p, v_p)
-        return x, ys
+    def body(carry, step):
+        x, pools = carry
+        layer, l = step
+        return _paged_layer(cfg, x, layer, l, cache.lengths, cache.tables,
+                            pools, active_rows, shard_ctx), None
 
-    if quantized:
-        xs = (params['layers'], cache.k, cache.v, cache.k_s, cache.v_s)
-        x, (new_k, new_v, new_ks, new_vs) = jax.lax.scan(body, x, xs)
-    else:
-        xs = (params['layers'], cache.k, cache.v)
-        x, (new_k, new_v) = jax.lax.scan(body, x, xs)
-        new_ks = new_vs = None
+    (x, (new_k, new_v, new_ks, new_vs)), _ = jax.lax.scan(
+        body, (x, (cache.k, cache.v, cache.k_s, cache.v_s)),
+        (params['layers'], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = llama.rms_norm(x, params['final_norm'], cfg.norm_eps)
     new_cache = PagedKVCache(k=new_k, v=new_v, tables=cache.tables,
                              lengths=cache.lengths + s,

@@ -11,14 +11,16 @@ probabilities cast to the query's dtype before P·V):
 
 ``paged_decode`` — the paged layout's decode step, ON BY ITSELF
 wherever it fits (``models/paged.decode_path``: a TPU, S = 1, a float
-pool, ``paged_fits``). It reads each slot's blocks out of the pool
-plane [NB, Hkv, P, D] through the block table, only as far as the
-slot's length: tables and lengths arrive by scalar prefetch, the plane
-stays in HBM, a program per slot loops over groups of ``PAGED_GROUP``
-blocks, each block one DMA ([Hkv, P, D]: 32 KB contiguous at 8 x 16 x
-128 bf16) into double-buffered VMEM. No dense view is built and no
-logits tensor exists in HBM. Measured on a v5e (PR 26, PERF.md §6) at
-48 slots, 2,049 blocks of 16, 16/8 heads x 128: 66 us a call with 24
+pool, ``paged_fits``). It reads each slot's blocks out of one layer of
+the WHOLE pool [L, NB, Hkv, P, D] through the block table, only as far
+as the slot's length: the layer, tables and lengths arrive by scalar
+prefetch, the pool stays in HBM (a plane sliced out for the call would
+be a copy of it a layer a step), a program per slot loops over groups
+of ``PAGED_GROUP`` blocks, each block one DMA ([Hkv, P, D]: 32 KB
+contiguous at 8 x 16 x 128 bf16) into double-buffered VMEM. No dense
+view is built and no logits tensor exists in HBM. Measured on a v5e
+(PR 26, PERF.md §6) at 48 slots, 2,049 blocks of 16, 16/8 heads x
+128: 66 us a call with 24
 rows live at ~230 positions (the DMAs alone 54, the arithmetic alone
 27), 303 us with 32 rows at ~1,500 (193 MB of K/V: 78% of the HBM
 peak), 586 us with all 48 at 2,048 (84%); the gather + einsum it
@@ -226,11 +228,12 @@ def paged_fits(slots: int, max_blocks: int, block: int, head_dim: int,
             and (slots * lanes + slots) * 4 <= PAGED_SMEM_CAP_BYTES)
 
 
-def _paged_kernel(tables_ref, valid_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, *, block: int, group: int):
+def _paged_kernel(layer_ref, tables_ref, valid_ref, q_ref, k_hbm, v_hbm,
+                  o_ref, k_buf, v_buf, sem, *, block: int, group: int):
     """One slot per program. q_ref/o_ref [Hkv, G, D]; k_hbm/v_hbm the
-    whole pool plane [NB, Hkv, P, D], left in HBM; tables_ref [B, MB]
-    and valid_ref [B] scalar-prefetched. The slot's blocks arrive
+    WHOLE pools [L, NB, Hkv, P, D], left in HBM, of which layer
+    ``layer_ref[0]`` is read; tables_ref [B, MB] and valid_ref [B]
+    scalar-prefetched too. The slot's blocks arrive
     ``group`` at a time by DMA into k_buf/v_buf [2, Hkv, group*P, D]
     (double-buffered: the next group is in flight while this one is
     multiplied), only as far as ``valid`` reaches; the online softmax is
@@ -262,8 +265,8 @@ def _paged_kernel(tables_ref, valid_ref, q_ref, k_hbm, v_hbm, o_ref,
                 dst = pl.ds(j * block, block)
                 for plane, buf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
                     act(pltpu.make_async_copy(
-                        plane.at[blk], buf.at[slot, :, dst, :],
-                        sem.at[s, slot]))
+                        plane.at[layer_ref[0], blk],
+                        buf.at[slot, :, dst, :], sem.at[s, slot]))
 
     group_dma(0, 0, lambda c: c.start())
 
@@ -299,16 +302,17 @@ def _paged_kernel(tables_ref, valid_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def paged_decode(q: jax.Array, k_plane: jax.Array, v_plane: jax.Array,
-                 tables: jax.Array, valid: jax.Array,
+def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
+                 layer: jax.Array, tables: jax.Array, valid: jax.Array,
                  interpret: bool = False) -> jax.Array:
-    """q [B, Hq, D] (the single decode position) against one layer's
-    pool planes [NB, Hkv, P, D] under block tables [B, MB] int32: row b
-    attends positions < valid[b] of the blocks its table names, in
-    order. valid[b] == 0 reads nothing and returns zeros. -> [B, Hq, D].
+    """q [B, Hq, D] (the single decode position) against layer ``layer``
+    (int32 scalar) of the pools [L, NB, Hkv, P, D] under block tables
+    [B, MB] int32: row b attends positions < valid[b] of the blocks its
+    table names, in order; no other layer and no other block is read.
+    valid[b] == 0 reads nothing and returns zeros. -> [B, Hq, D].
     Callers gate on ``paged_fits``."""
     b, hq, d = q.shape
-    nb, hkv, block, _ = k_plane.shape
+    _, nb, hkv, block, _ = k_pool.shape
     mb = tables.shape[1]
     g = hq // hkv
     group = _pick_group(mb)
@@ -319,9 +323,9 @@ def paged_decode(q: jax.Array, k_plane: jax.Array, v_plane: jax.Array,
     valid = jnp.clip(valid.astype(jnp.int32), 0, mb * block)
     tables = jnp.clip(tables.astype(jnp.int32), 0, nb - 1)
     qspec = pl.BlockSpec((None, hkv, g, d), lambda bi, *_: (bi, 0, 0, 0))
-    buf = pltpu.VMEM((2, hkv, group * block, d), k_plane.dtype)
+    buf = pltpu.VMEM((2, hkv, group * block, d), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(b,),
+        num_scalar_prefetch=3, grid=(b,),
         in_specs=[qspec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=qspec,
@@ -333,7 +337,8 @@ def paged_decode(q: jax.Array, k_plane: jax.Array, v_plane: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret, name='paged_decode',
-    )(tables, valid, q.reshape(b, hkv, g, d), k_plane, v_plane)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tables, valid,
+      q.reshape(b, hkv, g, d), k_pool, v_pool)
     return out.reshape(b, hq, d)
 
 
@@ -367,8 +372,8 @@ def _mla_kernel(layer_ref, tables_ref, valid_ref, q_ref, kv_hbm, o_ref,
     """One slot per program. q_ref [H, W] (the absorbed queries, zero
     past R + Dr), o_ref [H, R]; kv_hbm the WHOLE latent pool
     [L, NB, 1, P, W], left in HBM, of which layer ``layer_ref[0]`` is
-    read (a sliced plane would be a copy of the plane a layer a step). ``_paged_kernel``'s walk (groups of blocks by DMA into
-    a double buffer, as far as ``valid`` reaches) and online softmax;
+    read. ``_paged_kernel``'s walk (groups of blocks by DMA into a
+    double buffer, as far as ``valid`` reaches) and online softmax;
     the values are ``kv_buf[..., :rank]``: no second plane, no second
     DMA."""
     b = pl.program_id(0)

@@ -43,11 +43,11 @@ def _compile_kernel(one_chip, slots, max_blocks, block, blocks, hq, hkv, d):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    plane = sds((blocks, hkv, block, d), jnp.bfloat16)
+    pool = sds((2, blocks, hkv, block, d), jnp.bfloat16)
     # skylint: allow-jit(test-only compile check)
     return jax.jit(lambda *a: decode_attention.paged_decode(*a)).lower(
-        sds((slots, hq, d), jnp.bfloat16), plane, plane,
-        sds((slots, max_blocks), jnp.int32),
+        sds((slots, hq, d), jnp.bfloat16), pool, pool,
+        sds((), jnp.int32), sds((slots, max_blocks), jnp.int32),
         sds((slots,), jnp.int32)).compile()
 
 
@@ -75,17 +75,9 @@ def test_paged_fits_is_inside_what_the_chip_takes(one_chip):
         _compile_kernel(one_chip, **dict(CELL, slots=1024, max_blocks=256))
 
 
-def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
-                                                          monkeypatch):
-    """The whole decode chunk as the engine builds it (two layers of the
-    cells' width). XLA lays the pool out for whatever touches it: with
-    the [H, D]-slab scatter it re-laid the pool as [NB, P, H, D] inside
-    the loop and converted each layer's plane back in front of the
-    Mosaic call (PR 26: 9 ms of a 38 ms step on the chip). With
-    ``_scatter_rows`` the pool keeps its row-major layout from the
-    program's arguments to the call."""
-    # The backend is the CPU here; the program under test is the TPU's.
-    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+def _llama_cell(one_chip):
+    """Two layers of the cells' width, parameters and pool described on
+    the chip: (cfg, params, pool)."""
     cfg = llama.LlamaConfig(
         vocab_size=1024, d_model=2048, n_layers=2, n_heads=CELL['hq'],
         n_kv_heads=CELL['hkv'], d_ff=8192, head_dim=CELL['d'],
@@ -97,10 +89,59 @@ def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
 
     params = on_chip(jax.eval_shape(
         lambda: llama.init_params(jax.random.PRNGKey(0), cfg)))
-    slots = CELL['slots']
     pool = on_chip(jax.eval_shape(lambda: paged.init_pool(
-        cfg, slots, CELL['max_blocks'] * CELL['block'], CELL['blocks'],
-        CELL['block'])))
+        cfg, CELL['slots'], CELL['max_blocks'] * CELL['block'],
+        CELL['blocks'], CELL['block'])))
+    return cfg, params, pool
+
+
+_POOL = r'bf16\[2,2049,8,16,128\]'
+_PLANE_ELEMS = 2049 * 8 * 16 * 128
+_MOVES = ('copy', 'copy-start', 'copy-done', 'dynamic-slice',
+          'dynamic-update-slice', 'slice', 'transpose', 'concatenate')
+
+
+def _no_pool_is_taken_apart(hlo):
+    """No instruction PRODUCES a pool or a plane by moving it, under any
+    shape of the same size (the flat views included): the pool enters as
+    a parameter, rides the loops' tuples, is seen through bitcasts, and
+    is written in place by the row scatter's fusion."""
+    seen = set()
+    for m in re.finditer(r'(%[\w.-]+) = bf16\[([\d,]+)\]\S* ([\w-]+)\('
+                         r'([^\n]*)', hlo):
+        name, dims, op, rest = m.groups()
+        elems = 1
+        for n in dims.split(','):
+            elems *= int(n)
+        if elems not in (_PLANE_ELEMS, 2 * _PLANE_ELEMS):
+            continue
+        if op == 'fusion':  # judged by what it is rooted in
+            body = hlo[hlo.index(re.search(r'calls=(%[\w.-]+)',
+                                           rest).group(1) + ' '):]
+            op = 'fusion:' + re.search(r'ROOT %[\w.-]+ = \S+ ([\w-]+)\(',
+                                       body).group(1)
+        seen.add(op)
+        assert op.split(':')[-1] not in _MOVES, (name, dims, op)
+    assert 'fusion:scatter' in seen, seen  # the write, in place
+    # the pool keeps its row-major layout from the arguments on
+    assert set(re.findall(_POOL + r'\{([\d,]+)', hlo)) == {'4,3,2,1,0'}
+
+
+def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
+                                                          monkeypatch):
+    """The whole decode chunk as the engine builds it (two layers of the
+    cells' width). The pools ride the step scan and the layer scan as a
+    carry; the kernel is handed BOTH WHOLE and a layer index, and the
+    row scatter writes them in place. As ``xs``/``ys`` of the layer
+    scan each layer's plane was sliced out, stacked back and the new
+    pool copied into the step's carry (PR 28's ledger: 73% of the
+    device's time in ``chat-steady``); and XLA lays a pool out for
+    whatever touches it: an [H, D]-slab scatter had it re-laid as
+    [NB, P, H, D] inside the loop (PR 26: 9 ms of a 38 ms step)."""
+    # The backend is the CPU here; the program under test is the TPU's.
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg, params, pool = _llama_cell(one_chip)
+    slots = CELL['slots']
     assert paged.decode_path(pool.tables.shape, pool.k.shape, pool.k.dtype,
                              False) == 'paged_kernel'
 
@@ -114,17 +155,36 @@ def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
     call = re.search(r'%paged_decode[.\d]* = [^\n]*custom-call\(([^)]*)\)',
                      hlo)
     assert call, 'the decode step does not call the kernel'
-    planes = [name.strip() for name in call.group(1).split(',')][-2:]
-    defs = {m.group(1): m.group(2) for m in re.finditer(
-        r'(%[\w.-]+) = \S+ ([\w-]+)\(', hlo)}
-    # Each plane reaches the call as a view or at most out of XLA's
-    # alternate memory (copy-done): never through a layout-changing copy.
-    assert all(defs[p] in ('bitcast', 'copy-done', 'get-tuple-element',
-                           'fusion') for p in planes), \
-        {p: defs[p] for p in planes}
-    pool_layouts = set(re.findall(
-        r'bf16\[2,2049,8,16,128\]\{([\d,]+)', hlo))
-    assert pool_layouts == {'4,3,2,1,0'}, pool_layouts
+    defs = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r'(%[\w.-]+) = (\S+) ([\w-]+)\(', hlo)}
+    for name in call.group(1).split(',')[-2:]:
+        shape, op = defs[name.split('*/')[-1].strip()]
+        assert re.match(_POOL, shape), (name, shape)
+        assert op in ('bitcast', 'get-tuple-element', 'parameter'), (name,
+                                                                     op)
+    _no_pool_is_taken_apart(hlo)
+    assert 'kernel-fallback' not in hlo
+
+
+@pytest.mark.parametrize('width', [32, 256])
+def test_shared_prefix_prefill_writes_the_pool_in_place(one_chip,
+                                                        monkeypatch, width):
+    """The suffix prefill over the pool (S = W, one row, the gather
+    path), at two of the widths the engine warms: the same carry, so the
+    tail's rows are scattered into the donated pool and the prefix is
+    gathered out of it, block by block, with no plane in between."""
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg, params, pool = _llama_cell(one_chip)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hlo = paged.jit_prefill_shared.lower(
+        cfg, params, pool, vec(jnp.int32, 1, width),
+        vec(jnp.int32, 1, CELL['max_blocks']), vec(jnp.int32),
+        vec(jnp.int32, 1), vec(jnp.int32, 1), None).compile().as_text()
+    assert 'paged_decode' not in hlo
+    _no_pool_is_taken_apart(hlo)
 
 
 # -- the latent (MLA) cell: xing-docs-sessions -------------------------------

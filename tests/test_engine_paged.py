@@ -418,6 +418,63 @@ def test_paged_kernel_engine_matches_gather_engine(wide, kernel_traces,
                                atol=1e-4)
 
 
+@pytest.mark.parametrize('quant,s,kernel', [
+    (False, 1, False), (False, 1, True), (False, 5, False),
+    (True, 1, False), (True, 5, False)],
+    ids=['s1', 's1-kernel', 's5', 'int8-s1', 'int8-s5'])
+def test_forward_paged_writes_its_rows_and_nothing_else(wide, monkeypatch,
+                                                        quant, s, kernel):
+    """The pools ride the layer scan whole and every layer writes its
+    own rows of them in place: after a forward, each (layer, block, :,
+    offset) row a live slot's positions name is new, and every other
+    element of every plane (codes and scales) holds the bits it held.
+    An inactive row, whose stale table names a live row's blocks, wrote
+    the junk sink only: block 0, of layer 0."""
+    from skypilot_tpu.models import paged as paged_lib
+    from skypilot_tpu.ops import decode_attention
+    cfg, params = wide
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', kernel)
+    p, nb = 16, 12
+    pool = paged_lib.init_pool(cfg, 4, 64, nb, p, quantize=quant)
+    key = jax.random.PRNGKey(3)
+
+    def noise(i, like):
+        k = jax.random.fold_in(key, i)
+        if like.dtype == jnp.int8:
+            return jax.random.randint(k, like.shape, -127, 128, jnp.int8)
+        return jax.random.uniform(k, like.shape, like.dtype, 0.01, 1.0)
+
+    tables = np.asarray([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9],
+                         [4, 5, 0, 0]], np.int32)  # row 3: row 1's, stale
+    lengths = np.asarray([30, 14, 44, 20], np.int32)
+    active = np.asarray([True, True, True, False])
+    planes = {n: noise(i, getattr(pool, n)) for i, n in enumerate(
+        ('k', 'v', 'k_s', 'v_s')) if getattr(pool, n) is not None}
+    cache = paged_lib.PagedKVCache(tables=jnp.asarray(tables),
+                                   lengths=jnp.asarray(lengths), **planes)
+    toks = jax.random.randint(key, (4, s), 0, cfg.vocab_size)
+    path = paged_lib.decode_path(tables.shape, pool.k.shape, pool.k.dtype,
+                                 quant) if s == 1 else 'gather'
+    assert path == ('paged_kernel' if kernel else 'gather')
+    _, new = paged_lib.forward_paged(params, toks, cache, cfg,
+                                     jnp.asarray(active))
+    np.testing.assert_array_equal(new.lengths, lengths + s)
+    written = np.zeros((cfg.n_layers, nb, p), bool)  # [L, NB, P]
+    for b in np.flatnonzero(active):
+        for pos in range(lengths[b], lengths[b] + s):
+            written[:, tables[b, pos // p], pos % p] = True
+    assert written.sum() == cfg.n_layers * 3 * s
+    for name, before in planes.items():
+        before, after = np.asarray(before), np.asarray(getattr(new, name))
+        same = np.moveaxis(before == after, 2, -1 if before.ndim == 4
+                           else -2)  # heads last (of the row's numbers)
+        same = same.reshape(written.shape + (-1,))
+        assert not same[written].all(axis=-1).any(), name  # every row new
+        junk = np.zeros_like(written)
+        junk[0, 0] = True
+        assert same[~written & ~junk].all(), name
+
+
 def test_paged_kernel_leaves_int8_and_spec_on_the_gather(
         wide, kernel_traces, monkeypatch):
     """An int8 pool carries scales the kernel does not fold, and the

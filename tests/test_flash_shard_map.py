@@ -138,53 +138,65 @@ def test_the_three_kernels_carry_their_names_into_the_program():
 # -- paged decode per kv-head shard (ops/decode_attention.paged_decode) -----
 
 
+@pytest.mark.parametrize('s', [1, 3], ids=['s1-kernel', 's3-gather'])
 @pytest.mark.parametrize('axes', [dict(tensor=4), dict(data=2, tensor=2)],
                          ids=['tensor4', 'data2-tensor2'])
-def test_sharded_paged_step_equals_unsharded(axes):
-    """Under a TP mesh the S = 1 write and the kernel run per kv-head
-    shard of the pool plane (heads are independent); tables and lengths
-    go to every shard whole, whatever the mesh does with the batch.
-    Four virtual devices, interpret mode: the sharded step equals the
-    unsharded one, keeps heads and planes sharded, and gathers no
-    plane."""
+def test_sharded_paged_step_equals_unsharded(axes, s, monkeypatch):
+    """Under a TP mesh the cache write and read of a layer run per
+    kv-head shard of the WHOLE pools (heads are independent): the row
+    scatter at every S, then the kernel (S = 1) or the gathered view
+    (S = 3: the verify, the shared-prefix prefill). The layer, tables
+    and lengths go to every shard whole, whatever the mesh does with
+    the batch. Four virtual devices, interpret mode: the sharded step
+    equals the unsharded one, keeps heads and pools sharded, and
+    gathers no pool."""
     from skypilot_tpu.models import paged
+    from skypilot_tpu.ops import decode_attention
 
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
     mesh = _mesh(**axes)
     ctx = gen_lib.kernel_shard_ctx(mesh, RULES)
     b, hq, hkv, p, d, nb = 4, 8, 4, 16, 128, 9
     key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (b, hq, d))
+    q = jax.random.normal(key, (b, s, hq, d))
     kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
-                                (nb, hkv, p, d)) for i in (1, 2))
+                                (3, nb, hkv, p, d)) for i in (1, 2))
     kt, vt = (jax.random.normal(jax.random.fold_in(key, i),
-                                (b, hkv, 1, d)) for i in (3, 4))
+                                (b, hkv, s, d)) for i in (3, 4))
     tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [1, 2, 7, 0],
                           [8, 0, 0, 0]], jnp.int32)
-    lengths = jnp.asarray([63, 16, 39, 2], jnp.int32)
+    lengths = jnp.asarray([61, 16, 39, 2], jnp.int32)
     active = jnp.asarray([True, True, True, False])
     args = (q, kt, vt, kp, vp, tables, lengths, active)
 
     def step(shard_ctx):  # off the TPU it runs the interpreter
         # skylint: allow-jit(test-only numerics check)
-        return jax.jit(lambda *a: paged._kernel_step(*a, shard_ctx))
+        return jax.jit(lambda q, kt, vt, kp, vp, *a: paged._cache_step(
+            q, kt, vt, (kp, vp, None, None), jnp.int32(1), *a, shard_ctx))
 
     got, want = step(ctx)(*args), step(None)(*args)
-    for g, w in zip(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, atol=2e-5)
-    att, k_new, _ = got
-    assert not np.asarray(att[3]).any()  # the inactive row read nothing
-    # The write landed: row 1's 17th position is block 6, offset 0.
-    np.testing.assert_array_equal(k_new[6, :, 0], kt[1, :, 0])
+    att, (k_new, _, _, _) = got
+    if s == 1:  # the kernel: the inactive row read nothing
+        assert not np.asarray(att[3]).any()
+    # The write landed in layer 1 alone: row 1's 17th position is block
+    # 6, offset 0; the inactive row's went to layer 0's junk sink.
+    np.testing.assert_array_equal(k_new[1, 6, :, 0], kt[1, :, 0])
+    np.testing.assert_array_equal(k_new[2], kp[2])
+    np.testing.assert_array_equal(k_new[0, 1:], kp[0, 1:])
     spec = jax.sharding.PartitionSpec
     assert att.sharding.is_equivalent_to(jax.sharding.NamedSharding(
-        mesh, spec(None, ctx[1][1], None)), att.ndim)
+        mesh, spec(None, None, ctx[1][1], None)), att.ndim)
     assert k_new.sharding.is_equivalent_to(jax.sharding.NamedSharding(
-        mesh, spec(None, ctx[2][1], None, None)), k_new.ndim)
+        mesh, spec(None, None, ctx[2][1], None, None)), k_new.ndim)
     place = lambda x, s: jax.device_put(  # noqa: E731
         x, jax.sharding.NamedSharding(mesh, s))
-    sharded = (place(q, spec(None, ctx[1][1], None)),
+    sharded = (place(q, spec(None, None, ctx[1][1], None)),
                *(place(x, spec(None, ctx[2][1], None, None))
-                 for x in (kt, vt, kp, vp)), tables, lengths, active)
+                 for x in (kt, vt)),
+               *(place(x, spec(None, None, ctx[2][1], None, None))
+                 for x in (kp, vp)), tables, lengths, active)
     hlo = step(ctx).lower(*sharded).compile().as_text()
     assert 'all-gather' not in hlo and 'all-to-all' not in hlo
 

@@ -238,24 +238,25 @@ _PAGED_CASES = {
 }
 
 
-def _paged_inputs(group, valid, tables, dtype):
+def _paged_inputs(group, valid, tables, dtype, layer=1):
+    """(q, k_pool, v_pool, layer, tables, valid): pools of three layers,
+    of which ``layer`` is the one attended."""
     hkv, d = 2, 128
     key = jax.random.PRNGKey(len(valid) + group)
     q = jax.random.normal(key, (len(valid), hkv * group, d), dtype)
     kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
-                                (_NB, hkv, _P, d), dtype) for i in (1, 2))
-    return (q, kp, vp, jnp.asarray(tables, jnp.int32),
+                                (3, _NB, hkv, _P, d), dtype) for i in (1, 2))
+    return (q, kp, vp, jnp.int32(layer), jnp.asarray(tables, jnp.int32),
             jnp.asarray(valid, jnp.int32))
 
 
-def _paged_reference(q, kp, vp, tables, valid):
+def _paged_reference(q, kp, vp, layer, tables, valid):
     """What the layer did before the kernel and still does off the
     TPU: every row's whole table gathered into a dense view, then the
     einsum path."""
     from skypilot_tpu.models import paged
     return paged._gather_attention(  # noqa: SLF001 — oracle
-        q[:, None], kp, vp, tables, (valid - 1)[:, None], valid, None,
-        None, None)[:, 0]
+        q[:, None], kp, vp, None, None, layer, tables, valid - 1)[:, 0]
 
 
 @pytest.mark.parametrize('group', [1, 2], ids=['mha', 'gqa2'])
@@ -276,6 +277,30 @@ def test_paged_decode_matches_gather_path(monkeypatch, case, group):
     # A row with nothing valid reads nothing and returns zeros (the
     # gather path attends uniformly over junk there; neither is used).
     assert not got[~live].any()
+
+
+@pytest.mark.parametrize('layer', [0, 2])
+def test_paged_decode_reads_its_layers_named_blocks_only(layer):
+    """The kernel is handed the WHOLE pools and a layer index. Every
+    other layer, and every block of this one that no live row's table
+    names below its length, holds NaN: one DMA off its address and the
+    output says so (0 x NaN is NaN). The gather path on the clean pools
+    is the oracle."""
+    from skypilot_tpu.ops import decode_attention
+
+    valid, tables = _PAGED_CASES['ragged']
+    q, kp, vp, l, t, n = _paged_inputs(2, valid, tables, jnp.float32, layer)
+    want = np.asarray(_paged_reference(q, kp, vp, l, t, n))
+    named = np.zeros((3, _NB), bool)
+    for row, length in zip(tables, valid):
+        named[layer, row[:-(-length // _P)]] = True
+    poison = jnp.asarray(~named)[:, :, None, None, None]
+    got = np.asarray(decode_attention.paged_decode(
+        q, jnp.where(poison, jnp.nan, kp), jnp.where(poison, jnp.nan, vp),
+        l, t, n, interpret=True))
+    assert np.isfinite(got).all()
+    live = np.asarray(valid) > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
 
 
 def test_paged_decode_bf16_tolerance():
