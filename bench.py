@@ -261,8 +261,7 @@ def prefix_share_probe(assert_gates: bool = False) -> dict:
 
     def _engine(share):
         return ContinuousEngine(params, cfg, slots=4, max_len=64,
-                                chunk_steps=2, kv_layout='paged',
-                                prefix_share=share)
+                                chunk_steps=2, prefix_share=share)
 
     # (a) parity + savings on the 80% mix. The first request runs alone
     # so its blocks are committed before the sharers arrive (concurrent
@@ -332,8 +331,7 @@ def prefix_share_probe(assert_gates: bool = False) -> dict:
 
     # (c) the CLI-reproducible form: loadgen --shared-prefix against a
     # paged replica, per-mix TTFT + engine hit rate in one report.
-    server = llm_mod.LlmServer('tiny', max_len=64, engine='continuous',
-                               kv_layout='paged')
+    server = llm_mod.LlmServer('tiny', max_len=64, engine='continuous')
     port = common_utils.find_free_port(23600)
     started = threading.Event()
 
@@ -436,8 +434,7 @@ def kvtier_probe(assert_gates: bool = False) -> dict:
         os.environ.update(env)
         try:
             return ContinuousEngine(params, cfg, slots=4, max_len=64,
-                                    chunk_steps=2, kv_layout='paged',
-                                    kv_blocks=5)
+                                    chunk_steps=2, kv_blocks=5)
         finally:
             for k, v in saved.items():
                 if v is None:

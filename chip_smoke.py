@@ -16,17 +16,15 @@ line; any phase not ``ok`` makes the exit code non-zero):
   devices       python -m skypilot_tpu.utils.jax_env — what JAX finds.
                 Anything but a TPU ends the run here (unless rehearsing).
   train-1chip   python -m skypilot_tpu.train.run, six steps.
-  serve-slot    python -m skypilot_tpu.serve.llm_server with today's
+  serve         python -m skypilot_tpu.serve.llm_server with today's
                 defaults, driven by python -m skypilot_tpu.serve.loadgen,
-                one greedy request twice, SIGTERM -> drain -> exit 0.
-  serve-paged   the same with --kv-layout paged (the greedy request three
-                times: past the first the prefix trie serves its prompt),
-                plus a shared-prefix hit; decode through paged_decode.
+                one greedy request three times (past the first the prefix
+                trie serves its prompt), plus a shared-prefix hit; decode
+                through paged_decode; SIGTERM -> drain -> exit 0.
   kernels       each Pallas kernel compiled (not interpreted) against its
                 jnp reference (paged_decode and mla_decode at the benchmark
-                cells' pool geometries), the train step's HLO searched for the
-                Mosaic call, and the greedy request once more under
-                SKYTPU_DECODE_KERNEL=pallas.
+                cells' pool geometries) and the train step's HLO searched
+                for the Mosaic call.
   launch-local  execution.launch(Task(run='python -m ...train.run'),
                 cloud='local'): the orchestrator's own path.
   train-4chip, serve-tp4   with four or more devices; else a stated skip.
@@ -65,8 +63,7 @@ FALLBACK_TAG = '[kernel-fallback]'
 
 REAL = dict(model='bench-1b', tp_model='bench-1b', vocab=32768,
             seq=2048, batch=4, prompt=128, new=32, head=64,
-            flash_seqs=(2048, 4096), decode_lens=(1024, 4096),
-            hq=16, hkv=8, d=128, decode_kernel='pallas',
+            flash_seqs=(2048, 4096), hq=16, hkv=8, d=128,
             # The benchmark cells' pool: 48 slots, 2,049 blocks of 16,
             # max_len 2048, 16/8 heads x 128, bf16.
             paged=dict(slots=48, blocks=2049, block=16, max_blocks=128,
@@ -79,8 +76,7 @@ REAL = dict(model='bench-1b', tp_model='bench-1b', vocab=32768,
 REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
                  seq=128, batch=2, prompt=16, new=8, head=32,
                  flash_seqs=(128, 256), flash_cap_seq=256,
-                 decode_lens=(128, 256), decode_cap_len=256,
-                 hq=4, hkv=2, d=64, decode_kernel='interpret',
+                 hq=4, hkv=2, d=64,
                  paged=dict(slots=4, blocks=33, block=16, max_blocks=8,
                             hq=4, hkv=2, d=128),
                  mla=dict(slots=4, blocks=33, block=16, max_blocks=8,
@@ -393,25 +389,22 @@ class Smoke:
 
     # serve -----------------------------------------------------------------
 
-    def serve(self, phase, args=(), model=None, env_extra=None,
-              load=True, shared_prefix=False, want_balance=False) -> bool:
-        """Start a replica, drive it, stop it. ``load=False`` sends only
-        the greedy request (the kernels phase's SKYTPU_DECODE_KERNEL
-        leg)."""
+    def serve(self, phase, args=(), model=None, shared_prefix=False,
+              want_balance=False) -> bool:
+        """Start a replica, drive it, stop it."""
         t0 = time.monotonic()
         c = self.cfg
         model = model or c['model']
         checks = {}
-        replica = Replica(phase, ['--model', model, *args],
-                          self.env(**(env_extra or {})), 480)
+        replica = Replica(phase, ['--model', model, *args], self.env(),
+                          480)
         ok = replica.ready_s is not None
         checks['ready_s'] = replica.ready_s
         try:
-            if ok and load:
+            if ok:
                 ok = self.drive_loadgen(phase, replica, checks)
             if ok:
-                ok = self.drive_greedy(phase, replica, checks,
-                                       trie=shared_prefix)
+                ok = self.drive_greedy(phase, replica, checks)
             if ok and shared_prefix:
                 ok = self.drive_shared_prefix(replica, checks)
             if not ok and replica.ready_s is not None:
@@ -437,11 +430,10 @@ class Smoke:
                 if shared_prefix:
                     checks['prefix_hits'] = engine['prefix_share']['hits']
                     ok = ok and checks['prefix_hits'] > 0
-                if engine.get('kv_layout') == 'paged':
-                    # On the chip the bf16 pool is read by the kernel.
-                    path = checks['decode_attention'] = engine.get(
-                        'decode_attention')
-                    ok = ok and (self.rehearse or path == 'paged_kernel')
+                # On the chip the bf16 pool is read by the kernel.
+                path = checks['decode_attention'] = engine.get(
+                    'decode_attention')
+                ok = ok and (self.rehearse or path == 'paged_kernel')
                 if device.get('bytes_in_use'):
                     checks['bytes_in_use'] = device['bytes_in_use']
                 if want_balance and not self.rehearse:
@@ -475,18 +467,17 @@ class Smoke:
         return (rc == 0 and out.get('ok') == n
                 and out.get('new_tokens') == n * c['new'])
 
-    def drive_greedy(self, phase, replica, checks, trie=False) -> bool:
-        """One greedy /generate, twice: identical tokens, exactly the
-        number asked for. Where the prefix trie runs (``trie``), three
-        times, and the last two are compared: the first prefills the
-        whole prompt and the later ones only its last token over the
-        shared blocks — two bf16 paths that agree to tolerance, not to
-        the token (PR 26: with the decode kernel a 0.02 gap between the
+    def drive_greedy(self, phase, replica, checks) -> bool:
+        """One greedy /generate, three times: exactly the number of
+        tokens asked for, and the last two identical. The first
+        prefills the whole prompt and the later ones only its last
+        token over the prefix trie's shared blocks — two bf16 paths
+        that agree to tolerance, not to the token (PR 26: with the decode kernel a 0.02 gap between the
         float32 reference's two best logits flipped at the 22nd token;
         the reference's best was the later requests')."""
         c = self.cfg
         prompt = prompt_tokens(1, c['prompt'], c['vocab'])
-        n = 3 if trie else 2
+        n = 3
         t0 = time.monotonic()
         replies = [replica.generate(prompt, c['new']) for _ in range(n)]
         checks['greedy_s'] = round((time.monotonic() - t0) / n, 3)
@@ -494,12 +485,12 @@ class Smoke:
         rows = [body.get('tokens', [[]])[0] for _, body in replies]
         self.greedy[phase] = rows[0]
         checks['greedy_identical'] = rows[-2] == rows[-1]
-        if trie:  # for the record, not a check
-            checks['greedy_miss_same_as_hit'] = rows[0] == rows[1]
-        if phase != 'serve-slot':  # for the record, not a check: other
-            # layouts and kernels match to tolerance, not to the token
-            checks['greedy_same_as_serve_slot'] = (
-                rows[0] == self.greedy.get('serve-slot'))
+        # for the record, not a check
+        checks['greedy_miss_same_as_hit'] = rows[0] == rows[1]
+        if phase != 'serve':  # for the record, not a check: a mesh
+            # matches to tolerance, not to the token
+            checks['greedy_same_as_serve'] = (
+                rows[0] == self.greedy.get('serve'))
         return (all(status == 200 for status, _ in replies)
                 and all(len(r) == c['new'] for r in rows)
                 and rows[-2] == rows[-1]
@@ -536,13 +527,7 @@ class Smoke:
         checks['cases'] = results
         ok = (rc == 0 and results and all(r['ok'] for r in results)
               and 'error' not in checks)
-        self.report('kernels', t0, ok, device, text, **checks)
-        if not ok:
-            return False
-        # The greedy request once more, through the decode kernel.
-        mode = self.cfg['decode_kernel']
-        return self.serve(f'kernels.serve-{mode}', load=False,
-                          env_extra={'SKYTPU_DECODE_KERNEL': mode})
+        return self.report('kernels', t0, ok, device, text, **checks)
 
     # launch-local ----------------------------------------------------------
 
@@ -582,9 +567,7 @@ class Smoke:
             return 1
         four = self.device['device_count'] >= 4
         self.train('train-1chip')
-        self.serve('serve-slot')
-        self.serve('serve-paged', args=['--kv-layout', 'paged'],
-                   shared_prefix=True)
+        self.serve('serve', shared_prefix=True)
         self.kernels(meshes=('fsdp=4', 'data=2,tensor=2') if four else ())
         self.launch_local()
         if four:
@@ -627,7 +610,6 @@ def child_kernels(rehearse: bool, meshes) -> int:
     import jax.numpy as jnp
     import numpy as np
 
-    from skypilot_tpu.models import generate as gen_lib
     from skypilot_tpu.ops import attention, decode_attention
     from skypilot_tpu.train import run as train_run
 
@@ -695,33 +677,6 @@ def child_kernels(rehearse: bool, meshes) -> int:
         emit(f'flash fwd+bwd B{b} Hq{hq} Hkv{hkv} S{s} D{d}',
              all(np.isfinite(e) and e <= tol for e in errs.values()),
              err={k_: round(e, 5) for k_, e in errs.items()}, tol=tol)
-
-    def decode_case(b, hq, hkv, m, d, quant):
-        key = jax.random.PRNGKey(m + quant)
-        q = jax.random.normal(key, (b, hq, d), jnp.bfloat16)
-        kf = jax.random.normal(jax.random.fold_in(key, 1), (b, hkv, m, d))
-        vf = jax.random.normal(jax.random.fold_in(key, 2), (b, hkv, m, d))
-        lengths = jnp.asarray(
-            [m, m // 2 + 3, 5, m - 1][:b], jnp.int32)
-        if quant:
-            (k_c, k_s), (v_c, v_s) = (gen_lib._quantize_block(kf),
-                                      gen_lib._quantize_block(vf))
-        else:
-            k_c, v_c = kf.astype(jnp.bfloat16), vf.astype(jnp.bfloat16)
-            k_s = v_s = None
-        # skylint: allow-jit(one-shot numerics check, not a program)
-        got = jax.jit(lambda *a: decode_attention.flash_decode(
-            *a, interpret=interpret))(q, k_c, v_c, lengths, k_s, v_s)
-        # The XLA einsum path the engine runs by default.
-        # skylint: allow-jit(one-shot numerics check, not a program)
-        want = jax.jit(lambda q_, k_, v_, l_, ks_, vs_:
-                       gen_lib._cached_attention(
-                           q_[:, None], k_, v_, (l_ - 1)[:, None], l_,
-                           ks_, vs_)[:, 0])(q, k_c, v_c, lengths, k_s, v_s)
-        err = rel_err(got, want)
-        emit(f'flash_decode B{b} Hq{hq} Hkv{hkv} M{m} D{d} '
-             f'{"int8+scales" if quant else "bf16"}',
-             np.isfinite(err) and err <= tol, err=round(err, 5), tol=tol)
 
     def pool_layout(slots, blocks, block, max_blocks):
         """(valid [slots], tables [slots, max_blocks]) of a pool as the
@@ -816,19 +771,10 @@ def child_kernels(rehearse: bool, meshes) -> int:
     guarded('mla_decode', lambda: mla_case(**c['mla']))
     for s in c['flash_seqs']:
         guarded(f'flash S{s}', lambda s=s: flash_case(2, hq, hkv, s, d))
-    # The VMEM caps themselves, as the code has them: one group each.
+    # The VMEM cap itself, as the code has it: one group.
     flash_cap = c.get('flash_cap_seq') or attention._VMEM_CAP_ELEMS // d
-    decode_cap = (c.get('decode_cap_len')
-                  or decode_attention.VMEM_CAP_ELEMS // d)
     guarded('flash at the cap', lambda: flash_case(
         1, hq // hkv, 1, flash_cap, d))
-    for m in c['decode_lens']:
-        for quant in (False, True):
-            guarded(f'decode M{m}', lambda m=m, quant=quant: decode_case(
-                4, hq, hkv, m, d, quant))
-    for quant in (False, True):
-        guarded('decode at the cap', lambda quant=quant: decode_case(
-            1, hq // hkv, 1, decode_cap, d, quant))
 
     # The train step as train/run.py builds it: the Mosaic custom call
     # must be in its HLO (the reference did not stand in), and under a

@@ -155,16 +155,17 @@ FLAGS: Tuple[Flag, ...] = (
     Flag('SKYTPU_REPLICA_PORT', 'int', '8001',
          'Port a serving replica binds.'),
     Flag('SKYTPU_LLM_ENGINE', 'str', 'continuous',
-         "Serving engine: 'continuous' (batching engine) or 'simple'."),
+         "Serving engine: 'continuous' (batching engine) or 'off' "
+         '(window batching only).'),
     Flag('SKYTPU_LLM_ROLE', 'str', 'colocated',
          "Disaggregated-serving role: 'prefill', 'decode', or "
          "'colocated'."),
     Flag('SKYTPU_LLM_SLOTS', 'int', '16',
          'Engine decode slots (continuous-batch width).'),
     Flag('SKYTPU_LLM_MAX_BATCH', 'int', '32',
-         'Max rows per simple-engine batch window.'),
+         'Max rows per window-path batch (engine off).'),
     Flag('SKYTPU_LLM_BATCH_WINDOW_MS', 'float', '0',
-         'Simple-engine arrival-batching window.'),
+         'Window-path arrival-batching window (engine off).'),
     Flag('SKYTPU_LLM_CHUNK_STEPS', 'int', '8',
          'Decode steps fused per dispatched chunk.'),
     Flag('SKYTPU_LLM_PIPELINE', 'bool', '1',
@@ -172,17 +173,13 @@ FLAGS: Tuple[Flag, ...] = (
          'device compute); 0 = serial dispatch.'),
     Flag('SKYTPU_LLM_TP', 'int', '1',
          'Tensor-parallel ways for the serving engine.'),
-    Flag('SKYTPU_LLM_PREFILL_BATCH', 'int', '4',
+    Flag('SKYTPU_LLM_PREFILL_BATCH', 'int', '8',
          'Max prompts prefilled per admission group.'),
     Flag('SKYTPU_LLM_PREFILL_CHUNK', 'int', '0',
          'Chunked-prefill chunk length (0 = whole prompt).'),
-    Flag('SKYTPU_LLM_PREFIX_CACHE', 'int', '0',
-         'Dense-layout prefix-cache slots (0 = off).'),
     Flag('SKYTPU_LLM_PREFIX_SHARE', 'bool', '1',
          'Copy-on-write block-level prefix sharing in the paged KV '
          'pool.'),
-    Flag('SKYTPU_LLM_KV_LAYOUT', 'str', 'paged',
-         "KV cache layout: 'paged' or 'dense'."),
     Flag('SKYTPU_LLM_KV_CACHE', 'str', 'bf16',
          "KV cache dtype: 'bf16' or 'int8'."),
     Flag('SKYTPU_LLM_KV_BLOCK', 'int', '16',
@@ -197,10 +194,6 @@ FLAGS: Tuple[Flag, ...] = (
          'Speculative-decoding proposal length.'),
     Flag('SKYTPU_LLM_DRAIN_S', 'float', '30',
          'Graceful drain window before a replica exits.'),
-    Flag('SKYTPU_DECODE_KERNEL', 'str', None,
-         "Set to 'pallas' to enable the fused decode attention "
-         "kernel ('interpret' runs it in the Pallas interpreter: "
-         'tests and the CPU rehearsal).'),
     # -- serving: QoS gate --------------------------------------------
     Flag('SKYTPU_QOS', 'bool', '0',
          'Enable the QoS admission gate on serving replicas.'),
@@ -373,7 +366,7 @@ FLAGS: Tuple[Flag, ...] = (
          'server; /health-probe rate limit on replicas).'),
     Flag('SKYTPU_PROFILE_BUDGETS', 'map', None,
          "Per-program shape-budget overrides, e.g. "
-         "'generate.prefill=1,engine.chunk=2' — the recompile-storm "
+         "'generate.prefill=1,engine.paged_chunk=2' — the recompile-storm "
          'injection lever for probes/tests.'),
     # -- cold-start collapse (compile cache / AOT warm-up / restore) --
     Flag('SKYTPU_COMPILE_CACHE', 'path', None,

@@ -4,10 +4,11 @@ Reference analog: the reference's headline TPU serving recipe runs
 Google's JetStream (``/root/reference/examples/tpu/v6e/README.md:112-118``,
 2500 tok/s baseline), whose defining design is SLOT-BASED CONTINUOUS
 BATCHING: one persistent decode batch of B slots over a single resident
-KV cache; arriving requests are PREFILLED in small padded groups, their
-cache rows INSERTED into free slots, and one jitted decode step advances
-all slots together. Short requests drain and their slots refill from the
-queue while long ones keep streaming — unlike window batching
+PAGED KV pool (models/paged.py: fixed-size blocks, a block table a
+slot); arriving requests are PREFILLED in small padded groups, their
+cache rows INSERTED into the blocks they reserved, and one jitted decode
+step advances all slots together. Short requests drain and their slots
+refill from the queue while long ones keep streaming — unlike window batching
 (``serve/llm_server.py``'s legacy path), where the whole batch waits for
 its slowest member before the next batch starts.
 
@@ -32,23 +33,19 @@ TPU-first shape discipline (everything compiles exactly once per shape):
   capped at ONE so a paged slot's stale-active writes always precede
   (in device program order) any insert that re-populates its released
   blocks — see ``_dispatch_chunk``;
-* inserts are ``dynamic_update_slice`` on the batch axis and the big
-  cache buffers are donated, so steady state allocates nothing.
+* inserts scatter a group's rows into the blocks it reserved and the
+  pool is donated, so steady state allocates nothing.
 
 Freed slots keep decoding junk until reused (static shapes forbid
 shrinking the batch); junk rows are masked out of MoE expert routing via
-``forward_cached``'s ``active_rows`` — attention is per-row, so expert
+``forward_paged``'s ``active_rows`` — attention is per-row, so expert
 capacity is the only cross-row coupling.
 
-PREFIX CACHING (vLLM/JetStream-style, ``SKYTPU_LLM_PREFIX_CACHE``
-slots; opt-in — the pool costs extra HBM — and dense models only, see
-``__init__``): popular prompt prefixes keep their KV rows in a small
-device pool; a matching request gathers the prefix row and prefills
-only its suffix. Matching/storage happen at power-of-two lengths
-(bounded lookups and compile shapes), and a prefix is stored only on
-its second sighting so one-shot prompts never thrash the pool. For
-dense models causality makes reuse exact: a prompt's first p cache
-positions depend only on its first p tokens.
+PREFIX REUSE is the pool's own (``SKYTPU_LLM_PREFIX_SHARE``, default
+on; see ``__init__``): committed full prompt blocks are indexed in a
+refcounted trie, and a hit is a block-table write. For models whose
+rows are independent causality makes reuse exact: a prompt's first p
+cache positions depend only on its first p tokens.
 
 Sampling: per-slot temperature rides the decode step (greedy rows take
 ``argmax``, sampled rows ``categorical`` with a fresh per-step key).
@@ -73,7 +70,7 @@ exactly one token per round, drawn from the verify's position-0 logits
 shares the engine instead of forcing it off. Dense targets only: MoE
 expert capacity is per forward CALL, so a k+1-token verify routes
 differently than sequential decode and would break greedy exactness
-(same capacity-coupling reason as chunked prefill / the prefix pool).
+(same capacity-coupling reason as chunked prefill / block sharing).
 """
 from __future__ import annotations
 
@@ -92,6 +89,7 @@ import numpy as np
 from skypilot_tpu.models import generate as gen_lib
 from skypilot_tpu.models import llama
 from skypilot_tpu.models import model_ops
+from skypilot_tpu.models import paged as paged_lib
 from skypilot_tpu.models import sampling
 # Flight recorder (observability/blackbox.py): record() is one deque
 # append under its own lock — no I/O, no host sync — so the engine
@@ -196,11 +194,8 @@ class _Request:
     # prefill-role admission — it prefills normally (block reservation
     # sized to the prompt only), then retires at its first sampled
     # token with the future resolving to a PrefillHandoff instead of
-    # ever decoding. ``export_src`` carries the dense-layout source
-    # (prefill cache, row index) from prefill to the drain that
-    # serializes it; paged exports gather from the pool instead.
+    # ever decoding; the drain gathers its blocks out of the pool.
     export: bool = False
-    export_src: Optional[tuple] = None
     # Hierarchical KV tiers (serve/kv_tiers.py): how many times this
     # request has parked on a background spill fetch — bounded so a
     # pathological spill state degrades to recompute, never a loop.
@@ -216,13 +211,12 @@ class PrefillHandoff:
     """One prompt's computed KV state, host-side, ready to transfer to
     a decode-role engine (the disaggregated-serving handoff unit).
 
-    Paged layout: ``k``/``v`` are [L, nb, Hkv, P, D] in pool block
-    layout (block i covers prompt positions [i*P, (i+1)*P)); the last
-    block may be partial — positions past ``prompt_len`` carry junk
-    that is never attended. The full-block CHAIN (the trie keys) is
-    derivable from ``row`` + ``block``, which is what lets shared
-    prefixes transfer as references instead of bytes. Dense ('slot')
-    layout: ``k``/``v`` are [L, 1, Hkv, prompt_len, D].
+    ``k``/``v`` are [L, nb, Hkv, P, D] in pool block layout (block i
+    covers prompt positions [i*P, (i+1)*P)); the last block may be
+    partial — positions past ``prompt_len`` carry junk that is never
+    attended. The full-block CHAIN (the trie keys) is derivable from
+    ``row`` + ``block``, which is what lets shared prefixes transfer
+    as references instead of bytes.
     Scale planes (``k_s``/``v_s``) present iff the KV cache is int8."""
     row: List[int]
     first: int
@@ -231,10 +225,9 @@ class PrefillHandoff:
     top_k: int
     top_p: float
     eos: Optional[frozenset]
-    layout: str
     prompt_len: int
-    block: int = 0
-    n_blocks: int = 0
+    block: int
+    n_blocks: int
     k: Optional[np.ndarray] = None
     v: Optional[np.ndarray] = None
     k_s: Optional[np.ndarray] = None
@@ -243,7 +236,7 @@ class PrefillHandoff:
     @property
     def full_blocks(self) -> int:
         """Blocks fully covered by the prompt — the shareable chain."""
-        return self.prompt_len // self.block if self.block else 0
+        return self.prompt_len // self.block
 
 
 @dataclasses.dataclass
@@ -255,7 +248,6 @@ class _ImportEntry:
     blocks were negotiated away as local trie references."""
     req: _Request
     first: int
-    layout: str
     block_start: int = 0
     k: Optional[np.ndarray] = None
     v: Optional[np.ndarray] = None
@@ -317,78 +309,6 @@ def prompt_bucket(n: int, lo: int = 16) -> int:
     return b
 
 
-def _insert_impl(cache: gen_lib.KVCache, last: jax.Array,
-                 cache_n: gen_lib.KVCache, firsts: jax.Array,
-                 slots: jax.Array):
-    """Scatter a prefilled N-row cache into engine slots ``slots`` [N].
-    The prefill cache is only ``width`` (prompt bucket) positions long —
-    prefilling and copying full engine-max_len rows would make every
-    admission allocate a second near-slot-cache-sized buffer and stream
-    mostly zeros. Only [0, width) is written; whatever the slot's
-    previous occupant left beyond that is never attended (valid-length
-    masking) and is progressively overwritten by decode writes."""
-    width = cache_n.k.shape[3]
-    k = cache.k.at[:, slots, :, :width].set(cache_n.k)
-    v = cache.v.at[:, slots, :, :width].set(cache_n.v)
-    lengths = cache.lengths.at[slots].set(cache_n.lengths)
-    k_s, v_s = cache.k_s, cache.v_s
-    if cache.quantized:
-        k_s = k_s.at[:, slots, :, :width].set(cache_n.k_s)
-        v_s = v_s.at[:, slots, :, :width].set(cache_n.v_s)
-    return (gen_lib.KVCache(k=k, v=v, lengths=lengths, k_s=k_s, v_s=v_s),
-            last.at[slots].set(firsts))
-
-
-# Donation: the engine cache is the big resident buffer (often most of
-# HBM); donating it makes insert/chunk update in place on TPU. The
-# N-row prefill cache (arg 2) is NOT donated — its [L, N, ...] shapes
-# match no output, so donating it only buys a warning.
-_jit_insert = profiled_jit('engine.insert', _insert_impl,
-                           donate_argnums=(0, 1))
-
-
-def _gather_prefix_impl(pool: gen_lib.KVCache, idx: jax.Array,
-                        lengths: jax.Array, width: int) -> gen_lib.KVCache:
-    """Assemble a prefill cache whose row i starts as pool row idx[i]'s
-    first ``width`` positions with ``lengths[i]`` valid prefix tokens
-    (0 = miss: the junk gathered from slot 0 is never attended and the
-    suffix write starts at 0)."""
-    ks = vs = None
-    if pool.quantized:
-        ks = pool.k_s[:, idx, :, :width]
-        vs = pool.v_s[:, idx, :, :width]
-    return gen_lib.KVCache(k=pool.k[:, idx, :, :width],
-                           v=pool.v[:, idx, :, :width],
-                           lengths=lengths, k_s=ks, v_s=vs)
-
-
-_jit_gather_prefix = profiled_jit('engine.gather_prefix',
-                                  _gather_prefix_impl,
-                                  static_argnums=(3,))
-
-
-def _store_prefix_impl(pool: gen_lib.KVCache, cache_n: gen_lib.KVCache,
-                       row: jax.Array, slot: jax.Array,
-                       p: int) -> gen_lib.KVCache:
-    """Copy the first ``p`` cache positions of prefill row ``row`` into
-    pool slot ``slot``. Causality makes this exact: position i's KV
-    depends only on tokens <= i, so a longer prompt's first p positions
-    ARE the prefix's KV (quantized per position, so codes/scales copy
-    verbatim)."""
-    k = pool.k.at[:, slot, :, :p].set(cache_n.k[:, row, :, :p])
-    v = pool.v.at[:, slot, :, :p].set(cache_n.v[:, row, :, :p])
-    ks, vs = pool.k_s, pool.v_s
-    if pool.quantized:
-        ks = ks.at[:, slot, :, :p].set(cache_n.k_s[:, row, :, :p])
-        vs = vs.at[:, slot, :, :p].set(cache_n.v_s[:, row, :, :p])
-    return gen_lib.KVCache(k=k, v=v, lengths=pool.lengths, k_s=ks, v_s=vs)
-
-
-_jit_store_prefix = profiled_jit('engine.store_prefix',
-                                 _store_prefix_impl, static_argnums=(4,),
-                                 donate_argnums=(0,))
-
-
 _jit_sample = profiled_jit('engine.sample', sampling.sample)
 
 
@@ -396,10 +316,10 @@ def _paged_chunk_impl(cfg: llama.LlamaConfig, k_steps: int, params,
                       cache, last: jax.Array, temps: jax.Array,
                       top_ks, top_ps, active: jax.Array, key: jax.Array,
                       shard_ctx=None):
-    """K decode steps over the PAGED pool (models/paged.py): the
-    structural twin of ``_chunk_impl`` with block scatter/gather
-    replacing the dense row update."""
-    from skypilot_tpu.models import paged as paged_lib
+    """K decode steps over ALL slots of the paged pool
+    (models/paged.py): returns (cache, last, toks[K, B]). Per-slot
+    sampling params ride as data (temps 0 = greedy, top_ks 0 /
+    top_ps 1 = filters off) — no recompile per request mix."""
 
     def step(carry, key_t):
         cache, last = carry
@@ -431,40 +351,15 @@ def _filters_or_none(top_ks: np.ndarray, top_ps: np.ndarray):
     return None, None
 
 
-def _chunk_impl(cfg: llama.LlamaConfig, k_steps: int, params,
-                cache: gen_lib.KVCache, last: jax.Array,
-                temps: jax.Array, top_ks: jax.Array, top_ps: jax.Array,
-                active: jax.Array, key: jax.Array, shard_ctx=None):
-    """K decode steps over ALL slots: returns (cache, last, toks[K, B]).
-    Per-slot sampling params ride as data (temps 0 = greedy, top_ks 0 /
-    top_ps 1 = filters off) — no recompile per request mix."""
-    b = last.shape[0]
-    row_lens = jnp.ones((b,), jnp.int32)
-
-    def step(carry, key_t):
-        cache, last = carry
-        logits, cache = gen_lib.forward_cached(params, last[:, None],
-                                               cache, cfg, row_lens,
-                                               active,
-                                               shard_ctx=shard_ctx)
-        nxt = sampling.sample(logits, temps, key_t, top_ks, top_ps)
-        return (cache, nxt), nxt
-
-    keys = jax.random.split(key, k_steps)
-    (cache, last), toks = jax.lax.scan(step, (cache, last), keys)
-    return cache, last, toks
-
-
-_jit_chunk = profiled_jit('engine.chunk', _chunk_impl,
-                          static_argnums=(0, 1, 10),
-                          donate_argnums=(3, 4))
-
-
 def _insert_cache_impl(cache: gen_lib.KVCache, cache_n: gen_lib.KVCache,
                        slots: jax.Array) -> gen_lib.KVCache:
-    """Cache-only variant of ``_insert_impl`` for the DRAFT cache: the
-    committed token stream (``last``) is shared with the target, so the
-    draft insert carries no firsts."""
+    """Scatter a prefilled N-row DRAFT cache into slots ``slots`` [N]
+    (the draft cache is dense rows; the committed token stream ``last``
+    is the target's). The prefill cache is only ``width`` (prompt
+    bucket) positions long; only [0, width) is written — whatever the
+    slot's previous occupant left beyond that is never attended
+    (valid-length masking) and is progressively overwritten by decode
+    writes."""
     width = cache_n.k.shape[3]
     k = cache.k.at[:, slots, :, :width].set(cache_n.k)
     v = cache.v.at[:, slots, :, :width].set(cache_n.v)
@@ -484,7 +379,7 @@ def _rewind_impl(cache, adj: jax.Array):
     """Per-row rollback: positions past a row's valid length are never
     attended and get overwritten, so rejecting proposals is just a
     lengths subtraction (models/speculative.py's invariant, per row).
-    Works for the dense KVCache and the paged pool alike."""
+    Works for the draft's dense KVCache and the paged pool alike."""
     return dataclasses.replace(cache, lengths=cache.lengths - adj)
 
 
@@ -493,7 +388,7 @@ _jit_rewind = profiled_jit('engine.rewind', _rewind_impl,
 
 
 def _spec_impl(t_cfg: llama.LlamaConfig, d_cfg: llama.LlamaConfig,
-               k: int, t_params, d_params, t_cache: gen_lib.KVCache,
+               k: int, t_params, d_params, t_cache,
                d_cache: gen_lib.KVCache, last: jax.Array,
                temps: jax.Array, top_ks, top_ps, active: jax.Array,
                key: jax.Array, shard_ctx=None):
@@ -504,7 +399,8 @@ def _spec_impl(t_cfg: llama.LlamaConfig, d_cfg: llama.LlamaConfig,
     The draft runs k+1 proposal steps (the surplus step writes p_k's KV
     so a fully-accepted window leaves the draft cache complete —
     models/speculative.py's trade); the target scores the whole window
-    [last, p_1..p_k] in one forward with per-position logits. ``samp``
+    [last, p_1..p_k] in one forward over the paged pool (multi-token
+    block writes) with per-position logits. ``samp``
     is drawn from the verify's position-0 logits with each row's
     sampling params — for sampled rows one round == one plain decode
     step on exactly the logits that step would have produced."""
@@ -514,8 +410,7 @@ def _spec_impl(t_cfg: llama.LlamaConfig, d_cfg: llama.LlamaConfig,
     def dstep(carry, _):
         dc, tok = carry
         logits, dc = gen_lib.forward_cached(d_params, tok[:, None], dc,
-                                            d_cfg, ones, active,
-                                            shard_ctx=shard_ctx)
+                                            d_cfg, ones, active)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (dc, nxt), nxt
 
@@ -523,15 +418,9 @@ def _spec_impl(t_cfg: llama.LlamaConfig, d_cfg: llama.LlamaConfig,
                                        length=k + 1)
     props = props.transpose(1, 0)  # [B, k+1]
     window = jnp.concatenate([last[:, None], props[:, :k]], axis=1)
-    if isinstance(t_cache, gen_lib.KVCache):
-        logits_all, t_cache = gen_lib.forward_cached(
-            t_params, window, t_cache, t_cfg, (k + 1) * ones, active,
-            all_logits=True)
-    else:  # paged target: multi-token block writes + lengths rewind
-        from skypilot_tpu.models import paged as paged_lib
-        logits_all, t_cache = paged_lib.forward_paged(
-            t_params, window, t_cache, t_cfg, active,
-            shard_ctx=shard_ctx, all_logits=True)
+    logits_all, t_cache = paged_lib.forward_paged(
+        t_params, window, t_cache, t_cfg, active,
+        shard_ctx=shard_ctx, all_logits=True)
     tgt = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)  # [B, k+1]
     samp = sampling.sample(logits_all[:, 0].astype(jnp.float32), temps,
                            key, top_ks, top_ps)
@@ -559,8 +448,7 @@ class ContinuousEngine:
         '_unfetched': '_lock', '_slot_req': '_lock',
         '_tier_waiting': '_lock',
         'prefills': '_lock', 'failures': '_lock',
-        'prefill_chunks': '_lock', 'prefix_hits': '_lock',
-        'prefix_hit_tokens': '_lock', 'prefix_stores': '_lock',
+        'prefill_chunks': '_lock',
         'share_hits': '_lock', 'share_hit_tokens': '_lock',
         'share_misses': '_lock', 'share_commits': '_lock',
         'share_evictions': '_lock', 'cow_forks': '_lock',
@@ -631,7 +519,7 @@ class ContinuousEngine:
                 # routes (and drops) differently than sequential decode,
                 # breaking the byte-identical greedy-exactness contract
                 # (same capacity coupling that disables chunked prefill
-                # and the prefix pool for MoE).
+                # and block sharing for MoE).
                 raise ValueError('speculative decoding requires a dense '
                                  'target (MoE expert capacity is per '
                                  'forward call; a k+1-token verify would '
@@ -652,19 +540,23 @@ class ContinuousEngine:
         self.kv_quantize = bool(kv_quantize)
         if self.kv_quantize:
             self._ops.refuse('kv_quantize')
-        # KV layout: 'slot' pins one [max_len] cache row per slot (the
-        # default; zero gather cost); 'paged' shares fixed-size blocks
-        # from a pool sized below slots*max_len (models/paged.py — the
-        # vLLM-style memory innovation, r4 verdict Next #3). Requests
+        # ONE KV layout: slots share fixed-size blocks from a pool that
+        # may be sized below slots*max_len (models/paged.py). Requests
         # reserve ceil((prompt+max_new)/block) blocks at admission and
         # QUEUE when the pool is exhausted (natural backpressure).
-        self.kv_layout = (kv_layout
-                          or os.environ.get('SKYTPU_LLM_KV_LAYOUT')
-                          or 'slot')
-        if self.kv_layout not in ('slot', 'paged'):
-            raise ValueError(f'Unknown kv_layout {self.kv_layout!r}; '
-                             "'slot' or 'paged'")
-        self._ops.refuse(f'kv_layout={self.kv_layout}')
+        # ``kv_layout`` and ``prefix_slots`` select nothing: they are
+        # kept only to tell a caller that still passes the slot layout
+        # or the dense prefix pool what took their place.
+        if kv_layout not in (None, 'paged'):
+            raise ValueError(
+                f'kv_layout={kv_layout!r}: the engine keeps one KV '
+                "layout, the paged pool ('paged'); size it with "
+                'kv_blocks / kv_block')
+        if prefix_slots not in (None, 0):
+            raise ValueError(
+                f'prefix_slots={prefix_slots!r}: the dense prefix pool '
+                'is gone; the block trie (prefix_share, default on) is '
+                'the prefix cache')
         self.kv_block = kv_block or int(
             os.environ.get('SKYTPU_LLM_KV_BLOCK', '16'))
         # Pipelined dispatch (default ON): keep one decode chunk in
@@ -679,16 +571,13 @@ class ContinuousEngine:
             # mask one retirement stale, so a row freed meanwhile would
             # still consume capacity and change LIVE rows' routing vs
             # the serial oracle — the same coupling that disables
-            # chunked prefill and the prefix pool for MoE.
+            # chunked prefill and block sharing for MoE.
             self.pipeline_depth = 0
         if draft_cfg is not None:
             # Speculative rounds are host-synchronous by construction:
             # acceptance decides the rollback that shapes the next
             # round's inputs, so there is nothing to keep in flight.
             self.pipeline_depth = 0
-        # paged composes with spec (multi-token paged verify) and TP
-        # (pool sharded on kv_heads); the remaining exclusion is the
-        # prefix pool (dense-row storage), handled below.
         # Chunked prefill (opt-in): prompts longer than this advance in
         # prefill_chunk-token pieces interleaved with decode chunks, so
         # long admissions don't stall every active slot's stream. Each
@@ -704,49 +593,24 @@ class ContinuousEngine:
             # Expert capacity is per forward CALL (token count of the
             # call), so a chunked prefill routes/drops differently than
             # the monolithic prefill the greedy-exactness oracle uses —
-            # same reason the prefix pool is disabled for MoE below.
+            # same reason block sharing is disabled for MoE below.
             self.prefill_chunk = 0
-        # Prefix caching (vLLM/JetStream-style): popular prompt prefixes
-        # keep their KV rows in a small device pool; a hit prefills only
-        # the suffix. Prefixes are matched at power-of-two lengths
-        # (bounded lookups + bounded compile shapes) and stored on their
-        # SECOND sighting — one-shot prompts never thrash the pool.
-        if prefix_slots is None:
-            prefix_slots = int(os.environ.get('SKYTPU_LLM_PREFIX_CACHE',
-                                              '0'))
-        self.prefix_slots = max(int(prefix_slots), 0)
-        # OPT-IN (default 0): the pool reserves prefix_slots extra
-        # max_len cache rows of HBM a deployment sized to the edge did
-        # not budget for. And NOT for MoE: expert capacity couples
-        # co-batched rows (a busy prefill group can drop a prefix
-        # token's expert routing), so stored prefix KV would replay its
-        # store-time batchmates' contention — reuse is only exact for
-        # models whose rows are independent.
-        if self.prefix_slots:
-            self._ops.refuse('prefix_slots')
-        if rows_couple:
-            self.prefix_slots = 0
-        # The prefix pool composes with BOTH cache layouts: it lives
-        # entirely on the dense prefill side (pool rows, gather, store
-        # all operate on the prefilled cache_n before insert), and the
-        # paged insert scatters the seeded rows into blocks like any
-        # other prefill.
-        self.prefix_min = 16  # smallest cacheable/matchable prefix
-        # COPY-ON-WRITE BLOCK SHARING (paged layout only; default ON):
+        # COPY-ON-WRITE BLOCK SHARING, the prefix cache (default ON):
         # committed full prompt blocks are indexed in a host-side trie
         # (models/paged.py BlockTrie) with per-block refcounts; a
         # matching request points its block table at the shared blocks
         # — a hit is a table write, not a KV copy — and prefills only
         # its unshared tail directly over the pool. A partially-matched
         # tail block copy-on-write-forks; eviction is refcount-aware
-        # LRU over idle blocks. Independent rows only (same capacity
-        # coupling as the prefix pool); spec mode keeps its own dense
-        # draft-cache prefill path and opts out.
+        # LRU over idle blocks. Independent rows only: expert capacity
+        # couples co-batched rows (a busy prefill group can drop a
+        # prefix token's expert routing), so shared prefix KV would
+        # replay its commit-time batchmates' contention. Spec mode
+        # keeps its own dense draft-cache prefill path and opts out.
         if prefix_share is None:
             prefix_share = os.environ.get('SKYTPU_LLM_PREFIX_SHARE',
                                           '1') != '0'
         self.prefix_share = (bool(prefix_share)
-                             and self.kv_layout == 'paged'
                              and not rows_couple
                              and draft_cfg is None)
         # Fleet prefix-affinity advert (utils/prefix_affinity.py): hard
@@ -756,14 +620,10 @@ class ContinuousEngine:
         # snapshot exactly on the warmed replicas affinity needs.
         self._summary_max = max(
             int(os.environ.get('SKYTPU_PREFIX_SUMMARY_MAX', '64')), 0)
-        self._prefix_index: 'collections.OrderedDict[tuple, int]' = \
-            collections.OrderedDict()  # prefix tokens -> pool row
-        self._prefix_seen: 'collections.OrderedDict[tuple, int]' = \
-            collections.OrderedDict()  # sighting counts (bounded)
         # Sharded serving (JetStream serves 8B+ models sharded the same
         # way): with a mesh, weights are placed by the training stack's
         # logical rules (tensor axis -> heads/mlp/vocab, i.e. classic TP)
-        # and the KV cache shards its kv_heads; every jitted engine fn
+        # and the KV pool shards its kv_heads; every jitted engine fn
         # then compiles to an SPMD program — XLA inserts the collectives.
         self.mesh = mesh
         self.rules = rules
@@ -787,18 +647,14 @@ class ContinuousEngine:
                 mesh, self.rules, ('layers', 'batch', 'kv_heads', None))
             self._vec_sharding = sharding_lib.logical_sharding(
                 mesh, self.rules, ('batch',))
-            if gen_lib._DECODE_KERNEL or self.kv_layout == 'paged':
-                # The pallas decode kernels (the paged layout's own,
-                # and the opt-in dense one) run per head shard under TP
-                # via shard_map (generate.kernel_shard_ctx) — no gate.
-                self._shard_ctx = gen_lib.kernel_shard_ctx(mesh,
-                                                           self.rules)
-        if self.kv_layout == 'paged':
-            # Pool size (INCLUDING the junk-sink block 0): default is
-            # full capacity — no saving, always safe; deployments size
-            # it down (that's the point) and admission backpressures.
-            self.kv_blocks = kv_blocks or (
-                self.slots * (self.max_len // self.kv_block) + 1)
+            # The pool's writes, reads and decode kernel run per head
+            # shard under TP via shard_map (generate.kernel_shard_ctx).
+            self._shard_ctx = gen_lib.kernel_shard_ctx(mesh, self.rules)
+        # Pool size (INCLUDING the junk-sink block 0): default is full
+        # capacity — no saving, always safe; deployments size it down
+        # (that's the point) and admission backpressures.
+        self.kv_blocks = kv_blocks or (
+            self.slots * (self.max_len // self.kv_block) + 1)
         # Spec mode reserves window overhang below max_len: a verify may
         # write k+1 positions past the last committed one before its
         # tail rolls back, and a clamped out-of-range write would smear
@@ -848,9 +704,6 @@ class ContinuousEngine:
         self.prefills = 0
         self.failures = 0  # _fail_everything trips
         self.prefill_chunks = 0
-        self.prefix_hits = 0
-        self.prefix_hit_tokens = 0
-        self.prefix_stores = 0
         # Block-share accounting (prefix_share; see stats()).
         self.share_hits = 0
         self.share_hit_tokens = 0
@@ -858,9 +711,9 @@ class ContinuousEngine:
         self.share_commits = 0
         self.share_evictions = 0
         self.cow_forks = 0
-        # Prefill cost counters (all layouts): real prompt tokens the
-        # prefill actually computed vs tokens skipped via shared/cached
-        # prefix KV — the probe's >= 40% savings gate reads these.
+        # Prefill cost counters: real prompt tokens the prefill
+        # actually computed vs tokens skipped via shared prefix KV —
+        # the probe's >= 40% savings gate reads these.
         self.prefill_tokens = 0
         self.prefill_tokens_saved = 0
         self.prefill_ms = 0.0
@@ -914,7 +767,7 @@ class ContinuousEngine:
         alone. Dense targets only in the exactness sense that matters:
         MoE expert capacity couples co-batched rows, so exported KV
         would replay its batchmates' contention on a different replica
-        — same reason the prefix pool refuses MoE."""
+        — same reason block sharing refuses MoE."""
         self._ops.refuse('KV handoff')
         if self._ops.rows_couple(self.cfg):
             raise ValueError('KV handoff requires a dense model (MoE '
@@ -936,18 +789,13 @@ class ContinuousEngine:
     def submit_import(self, row: List[int], max_new: int, first: int,
                       *, temperature: float = 0.0, top_k: int = 0,
                       top_p: float = 1.0, eos=None, on_tokens=None,
-                      layout: str = 'paged', block_start: int = 0,
-                      k=None, v=None, k_s=None,
+                      block_start: int = 0, k=None, v=None, k_s=None,
                       v_s=None) -> EngineFuture:
         """Decode-role admission of an imported prompt: install the
-        transferred KV (paged: block scatter + table install; dense:
-        row insert), emit ``first`` as the request's first token, and
-        resume continuous decode. Backpressures exactly like local
-        admission — entries queue until a slot and the full block
-        reservation are allocatable."""
-        if layout != self.kv_layout:
-            raise ValueError(f'handoff layout {layout!r} does not match '
-                             f'engine kv_layout {self.kv_layout!r}')
+        transferred KV (block scatter + table install), emit ``first``
+        as the request's first token, and resume continuous decode.
+        Backpressures exactly like local admission — entries queue
+        until a slot and the full block reservation are allocatable."""
         self._ops.refuse('KV handoff')
         if self._ops.rows_couple(self.cfg) or self.draft_cfg is not None:
             raise ValueError('KV handoff requires a dense, '
@@ -961,20 +809,15 @@ class ContinuousEngine:
         # header corruption survives crc32, which covers plane bytes
         # only — must be rejected before it is ever enqueued.
         cfg = self.cfg
-        if self.kv_layout == 'paged':
-            p = self.kv_block
-            nb_prompt = -(-len(row) // p)
-            nb_present = nb_prompt - int(block_start)
-            if nb_present < 0:
-                raise ValueError(
-                    f'handoff block_start {block_start} exceeds the '
-                    f'prompt chain ({nb_prompt} blocks)')
-            want = (cfg.n_layers, nb_present, cfg.n_kv_heads, p,
-                    cfg.head_dim)
-        else:
-            nb_present = 1  # one dense record, exact prompt width
-            want = (cfg.n_layers, 1, cfg.n_kv_heads, len(row),
-                    cfg.head_dim)
+        p = self.kv_block
+        nb_prompt = -(-len(row) // p)
+        nb_present = nb_prompt - int(block_start)
+        if nb_present < 0:
+            raise ValueError(
+                f'handoff block_start {block_start} exceeds the '
+                f'prompt chain ({nb_prompt} blocks)')
+        want = (cfg.n_layers, nb_present, cfg.n_kv_heads, p,
+                cfg.head_dim)
         if nb_present > 0:  # == 0: full local prefix share, no planes
             if k is None or v is None \
                     or tuple(k.shape) != want or tuple(v.shape) != want:
@@ -990,7 +833,7 @@ class ContinuousEngine:
                     f'handoff k_s/v_s scale planes must be {want[:-1]}')
         req = self._build_request(row, max_new, temperature, on_tokens,
                                   top_k, top_p, eos)
-        entry = _ImportEntry(req=req, first=int(first), layout=layout,
+        entry = _ImportEntry(req=req, first=int(first),
                              block_start=int(block_start),
                              k=k, v=v, k_s=k_s, v_s=v_s)
         with self._lock:
@@ -1072,7 +915,7 @@ class ContinuousEngine:
             raise ValueError(
                 f'prompt ({len(row)}) + max_new ({budget}) exceeds '
                 f'engine max_len limit {self._submit_max}{extra}')
-        if self.kv_layout == 'paged' and (max_new > 1 or export):
+        if max_new > 1 or export:
             need = self._blocks_for(len(row), budget)
             if need > self.kv_blocks - 1:
                 # Bigger than the WHOLE pool: admission could never
@@ -1148,13 +991,12 @@ class ContinuousEngine:
             # ONE read: the block states must agree within a snapshot
             # (free + owned + shared + cached == usable), or the
             # dashboard can render an impossible state mid-admission.
-            free_blocks = owned_blocks = shared_blocks = cached_blocks = 0
-            if self.kv_layout == 'paged':
-                free_blocks = len(self._free_blocks)
-                owned_blocks = sum(len(b) for b in self._slot_blocks)
-                if self._trie is not None:
-                    shared_blocks = self._trie.referenced
-                    cached_blocks = self._trie.reclaimable
+            free_blocks = len(self._free_blocks)
+            owned_blocks = sum(len(b) for b in self._slot_blocks)
+            shared_blocks = cached_blocks = 0
+            if self._trie is not None:
+                shared_blocks = self._trie.referenced
+                cached_blocks = self._trie.reclaimable
             # Tier snapshot in the SAME critical section as the pool
             # states (lock order engine -> tiers): host/spilled must
             # agree with the kv_tiers block they summarize.
@@ -1183,11 +1025,10 @@ class ContinuousEngine:
                 'moe_expert_load_max': load and max(load),
                 'moe_expert_load_mean': load and sum(load) / len(load),
                 'moe_expert_load': load,
-                'kv_layout': self.kv_layout,
-                # How the paged decode step reads K/V: 'paged_kernel'
+                'kv_layout': 'paged',  # the one there is
+                # How the decode step reads K/V: 'paged_kernel'
                 # (through the block table, by length) or 'gather'
-                # (every slot's whole max_len into a dense view);
-                # None for the slot layout, which has no table.
+                # (every slot's whole max_len into a dense view).
                 'decode_attention': self.decode_attention,
                 # Handoff accounting (serve/disagg.py): exports are
                 # prefill-role retirements, imports are decode-role
@@ -1197,7 +1038,7 @@ class ContinuousEngine:
                            'imports': self.imports,
                            'import_errors': self.import_errors,
                            'queued_imports': queued_imports},
-                'kv_blocks': (None if self.kv_layout != 'paged' else {
+                'kv_blocks': {
                     'total': self.kv_blocks, 'block': self.kv_block,
                     'free': free_blocks,
                     # used/usable are authoritative here (block 0 is
@@ -1225,7 +1066,7 @@ class ContinuousEngine:
                     'host': (tier_stats['host_blocks']
                              if tier_stats else 0),
                     'spilled': (tier_stats['spilled_blocks']
-                                if tier_stats else 0)}),
+                                if tier_stats else 0)},
                 'kv_tiers': tier_stats,
                 'queued': queued, 'prefills': self.prefills,
                 'failures': self.failures,
@@ -1254,18 +1095,11 @@ class ContinuousEngine:
                     'acceptance_rate': (
                         self.spec_accepted / self.spec_proposals
                         if self.spec_proposals else 0.0)},
-                'prefix_cache': {
-                    'slots': self.prefix_slots,
-                    'entries': len(self._prefix_index),
-                    'hits': self.prefix_hits,
-                    'hit_tokens': self.prefix_hit_tokens,
-                    'stores': self.prefix_stores},
-                # Copy-on-write block sharing (paged layout; see the
-                # ctor comment). prefill_tokens is the prompt tokens
-                # prefill actually COMPUTED across all paths;
-                # prefill_tokens_saved is what shared/cached prefix KV
-                # skipped — the pair the perf_probe --prefix savings
-                # gate reads. prefill_bubble_ms is cumulative prefill
+                # Copy-on-write block sharing (see the ctor comment).
+                # prefill_tokens is the prompt tokens prefill actually
+                # COMPUTED across all paths; prefill_tokens_saved is
+                # what shared prefix KV skipped — the pair the
+                # perf_probe --prefix savings gate reads. prefill_bubble_ms is cumulative prefill
                 # host time decode provably waited on.
                 'prefix_share': {
                     'enabled': self.prefix_share,
@@ -1404,63 +1238,51 @@ class ContinuousEngine:
         # fits spread over the slice, a transient single-device
         # allocation would OOM chip 0 — at construction AND at every
         # _fail_everything recovery. (Shardings are None single-device.)
-        kv = self._kv_sharding if self.mesh is not None else None
-        kv_s = self._kv_scale_sharding if self.mesh is not None else None
-        vec = self._vec_sharding if self.mesh is not None else None
-        # Share-trie state exists on every layout (None = sharing off)
-        # so the admission/release paths never branch on layout first.
-        self._trie = None
-        self.decode_attention = None  # the slot layout has no table
+        vec = kv = kv_s = pool_kv = pool_s = None
+        if self.mesh is not None:
+            vec, kv, kv_s = (self._vec_sharding, self._kv_sharding,
+                             self._kv_scale_sharding)
+            # The pool shards on kv_heads over the tensor axis (the
+            # same plane as the draft's dense cache); block tables stay
+            # replicated — scatter/gather index replicated dims only,
+            # so the pool ops partition with no collectives.
+            from skypilot_tpu.parallel import sharding as sharding_lib
+            pool_kv = sharding_lib.logical_sharding(
+                self.mesh, self.rules,
+                ('layers', None, 'kv_heads', None, 'head_dim'))
+            pool_s = sharding_lib.logical_sharding(
+                self.mesh, self.rules,
+                ('layers', None, 'kv_heads', None))
+        self._cache = self._ops.init_pool(
+            self.cfg, self.slots, self.max_len, self.kv_blocks,
+            self.kv_block, quantize=self.kv_quantize,
+            kv_sharding=pool_kv, scale_sharding=pool_s,
+            lengths_sharding=vec)
+        # Host-side accounting: block 0 is the junk sink, never
+        # allocated; per-slot block lists return to the free list when
+        # the slot's request completes. With block sharing,
+        # _slot_blocks holds only the slot's OWNED blocks; shared
+        # (trie-committed, refcounted) blocks live in _slot_shared.
+        self._free_blocks = list(range(1, self.kv_blocks))
+        self._slot_blocks: List[List[int]] = [
+            [] for _ in range(self.slots)]
         self._slot_shared = [[] for _ in range(self.slots)]
-        # The slot's INSTALLED table row (host copy, paged layout):
-        # exports reconstruct the exact device table from it — deriving
-        # it from the owned/shared lists breaks when a commit deduped
-        # against an existing chain node.
+        # The slot's INSTALLED table row (host copy): exports
+        # reconstruct the exact device table from it — deriving it from
+        # the owned/shared lists breaks when a commit deduped against
+        # an existing chain node.
         self._slot_table: List[Optional[np.ndarray]] = \
             [None] * self.slots
-        if self.kv_layout == 'paged':
-            from skypilot_tpu.models import paged as paged_lib
-            pool_kv = pool_s = None
-            if self.mesh is not None:
-                # The pool shards on kv_heads over the tensor axis (the
-                # same plane as the dense cache); block tables stay
-                # replicated — scatter/gather index replicated dims
-                # only, so the pool ops partition with no collectives.
-                from skypilot_tpu.parallel import sharding as sharding_lib
-                pool_kv = sharding_lib.logical_sharding(
-                    self.mesh, self.rules,
-                    ('layers', None, 'kv_heads', None, 'head_dim'))
-                pool_s = sharding_lib.logical_sharding(
-                    self.mesh, self.rules,
-                    ('layers', None, 'kv_heads', None))
-            self._cache = self._ops.init_pool(
-                self.cfg, self.slots, self.max_len, self.kv_blocks,
-                self.kv_block, quantize=self.kv_quantize,
-                kv_sharding=pool_kv, scale_sharding=pool_s,
-                lengths_sharding=vec)
-            # Host-side accounting: block 0 is the junk sink, never
-            # allocated; per-slot block lists return to the free list
-            # when the slot's request completes. With block sharing,
-            # _slot_blocks holds only the slot's OWNED blocks; shared
-            # (trie-committed, refcounted) blocks live in _slot_shared.
-            self._free_blocks = list(range(1, self.kv_blocks))
-            self._slot_blocks: List[List[int]] = [
-                [] for _ in range(self.slots)]
-            self._trie = (paged_lib.BlockTrie(self.kv_block)
-                          if self.prefix_share else None)
-            # Which attention the decode program is built with: the
-            # same call _paged_layer branches on when it is traced.
-            # Speculative mode has no S = 1 step over the pool (its
-            # verify is S = k + 1: the gather).
-            self.decode_attention = (
-                'gather' if self.draft_cfg is not None
-                else self._ops.decode_attention(self._cache,
-                                                self.kv_quantize))
-        else:
-            self._cache = self._ops.init_cache(
-                self.cfg, self.slots, self.max_len, kv_sharding=kv,
-                lengths_sharding=vec, quantize=self.kv_quantize,
-                kv_scale_sharding=kv_s)
+        self._trie = (paged_lib.BlockTrie(self.kv_block)
+                      if self.prefix_share else None)  # None = sharing off
+        # Which attention the decode program is built with: the same
+        # call _paged_layer branches on when it is traced. Speculative
+        # mode has no S = 1 step over the pool (its verify is
+        # S = k + 1: the gather).
+        self.decode_attention = (
+            'gather' if self.draft_cfg is not None
+            else self._ops.decode_attention(self._cache,
+                                            self.kv_quantize))
         self._last = jnp.zeros((self.slots,), jnp.int32, device=vec)
         self._d_cache = None
         if self.draft_cfg is not None:
@@ -1468,15 +1290,6 @@ class ContinuousEngine:
                 self.draft_cfg, self.slots, self.max_len, kv_sharding=kv,
                 lengths_sharding=vec, quantize=self.kv_quantize,
                 kv_scale_sharding=kv_s)
-        self._prefix_pool = None
-        if self.prefix_slots > 0:
-            self._prefix_pool = gen_lib.init_cache(
-                self.cfg, self.prefix_slots, self.max_len, kv_sharding=kv,
-                lengths_sharding=vec, quantize=self.kv_quantize,
-                kv_scale_sharding=kv_s)
-        self._prefix_index.clear()
-        self._prefix_seen.clear()
-        self._prefix_free = list(range(self.prefix_slots))
         # Logical device-memory registration (observability/profiler.py
         # memory accounting): the engine's resident KV footprint by
         # kind, re-registered on every rebuild so the reconciliation
@@ -1488,13 +1301,10 @@ class ContinuousEngine:
         if self._d_cache is not None:
             profiler.register_logical(
                 'kv_draft', profiler.tree_nbytes(self._d_cache))
-        if self._prefix_pool is not None:
-            profiler.register_logical(
-                'prefix_pool', profiler.tree_nbytes(self._prefix_pool))
 
     def _blocks_for(self, row_len: int, max_new: int) -> int:
         """Blocks reserved at admission: the request's actual ask, not
-        max_len — the paged layout's whole point. Spec mode adds the
+        max_len — the pool's whole point. Spec mode adds the
         k+1 verify-window overhang: a verify may WRITE that far past
         the committed length before rollback, and a write diverted to
         the junk sink would lose KV the round then commits. The ONE
@@ -1513,18 +1323,17 @@ class ContinuousEngine:
     # skylint: resource-pair=kv_blocks.release
     def _release_blocks(self, slot: int) -> None:
         self._slot_table[slot] = None
-        if self.kv_layout == 'paged':
-            self._free_blocks.extend(self._slot_blocks[slot])
-            self._slot_blocks[slot] = []
-            if self._trie is not None and self._slot_shared[slot]:
-                # Shared blocks DECREF instead of freeing: refs-0 blocks
-                # park in the trie's idle LRU as reusable cache (a
-                # detached node's block frees for real).
-                for node in self._slot_shared[slot]:
-                    freed = self._trie.release(node)
-                    if freed is not None:
-                        self._free_blocks.append(freed)
-                self._slot_shared[slot] = []
+        self._free_blocks.extend(self._slot_blocks[slot])
+        self._slot_blocks[slot] = []
+        if self._trie is not None and self._slot_shared[slot]:
+            # Shared blocks DECREF instead of freeing: refs-0 blocks
+            # park in the trie's idle LRU as reusable cache (a
+            # detached node's block frees for real).
+            for node in self._slot_shared[slot]:
+                freed = self._trie.release(node)
+                if freed is not None:
+                    self._free_blocks.append(freed)
+            self._slot_shared[slot] = []
 
     def _blocks_avail(self) -> int:
         """Allocatable blocks RIGHT NOW: the free list plus idle
@@ -1561,7 +1370,6 @@ class ContinuousEngine:
         guarantees the gather reads the pre-eviction KV. The device
         handles go to the tier thread; the engine thread never pays
         the device_get or the serialization."""
-        from skypilot_tpu.models import paged as paged_lib
         tiers = self._kv_tiers
         items = []
         for blk, node in pairs:
@@ -1824,30 +1632,28 @@ class ContinuousEngine:
                                 break
                             run += 1
                         n = run
-                    if self.kv_layout == 'paged':
-                        # Backpressure: admit only requests whose block
-                        # reservation fits the allocatable pool (free +
-                        # evictable idle); the rest queue. A later
-                        # block-share HIT also ends the group — it
-                        # becomes the head next iteration and takes the
-                        # pool-direct path instead of re-prefilling its
-                        # shared head.
-                        avail = self._blocks_avail()
-                        run = 0
-                        for p in self._pending:
-                            if run >= n:
-                                break
-                            if (run > 0 and self._trie is not None
-                                    and (p.max_new > 1 or p.export)
-                                    and self._trie.match(p.row)[0]):
-                                break
-                            nb = (self._blocks_needed(p)
-                                  if p.max_new > 1 or p.export else 0)
-                            if nb > avail:
-                                break
-                            avail -= nb
-                            run += 1
-                        n = run
+                    # Backpressure: admit only requests whose block
+                    # reservation fits the allocatable pool (free +
+                    # evictable idle); the rest queue. A later
+                    # block-share HIT also ends the group — it becomes
+                    # the head next iteration and takes the pool-direct
+                    # path instead of re-prefilling its shared head.
+                    avail = self._blocks_avail()
+                    run = 0
+                    for p in self._pending:
+                        if run >= n:
+                            break
+                        if (run > 0 and self._trie is not None
+                                and (p.max_new > 1 or p.export)
+                                and self._trie.match(p.row)[0]):
+                            break
+                        nb = (self._blocks_needed(p)
+                              if p.max_new > 1 or p.export else 0)
+                        if nb > avail:
+                            break
+                        avail -= nb
+                        run += 1
+                    n = run
                     if n == 0:
                         return
                     g = 1
@@ -1889,7 +1695,6 @@ class ContinuousEngine:
         they scatter into the leading owned blocks via
         ``jit_import_blocks`` — a re-import instead of a recompute —
         and then commit into the trie like any other prompt block."""
-        from skypilot_tpu.models import paged as paged_lib
         t0 = time.perf_counter()
         # skylint: locked(engine thread is the sole slot-table mutator;
         # this is a point-in-time bubble-attribution hint only)
@@ -1947,8 +1752,7 @@ class ContinuousEngine:
         # The padded width must not overhang max_len: positions past
         # the table are CLIPPED to its last entry, and with a full
         # reservation that entry is the request's own live block — the
-        # padded junk would scribble over real prompt KV (the same
-        # hazard the dense path's demote guard covers). Room always
+        # padded junk would scribble over real prompt KV. Room always
         # suffices: submit validates row + max_new <= max_len, so
         # max_len - covered >= len(suffix) + max_new.
         w = min(prompt_bucket(len(suffix)), self.max_len - covered)
@@ -2036,54 +1840,6 @@ class ContinuousEngine:
             if had_active and self._inflight is None:
                 self.prefill_bubble_ms += dt_ms
 
-    def _match_prefix(self, row: List[int]):
-        """Longest cached prefix of ``row`` at power-of-two lengths
-        STRICTLY shorter than the prompt (the last prompt token must be
-        prefilled to produce the first logits). Returns (p, pool_row)."""
-        best = (0, 0)
-        b = self.prefix_min
-        while b <= len(row) - 1:
-            slot = self._prefix_index.get(tuple(row[:b]))
-            if slot is not None:
-                best = (b, slot)
-                self._prefix_index.move_to_end(tuple(row[:b]))  # LRU
-            b *= 2
-        return best
-
-    # skylint: engine-thread
-    def _maybe_store_prefixes(self, rows, p_lens,
-                              cache_n: gen_lib.KVCache) -> None:
-        """Store each row's largest bucket prefix on its SECOND sighting
-        (a pool slot is too precious for one-shot prompts); LRU-evict
-        when full."""
-        for i, row in enumerate(rows):
-            p = self.prefix_min
-            while p * 2 <= len(row):
-                p *= 2
-            if p > len(row) or p < self.prefix_min:
-                continue
-            if p_lens[i] >= p:
-                continue  # the hit already covers this prefix
-            key = tuple(row[:p])
-            if key in self._prefix_index:
-                continue
-            self._prefix_seen[key] = self._prefix_seen.get(key, 0) + 1
-            self._prefix_seen.move_to_end(key)
-            while len(self._prefix_seen) > 512:
-                self._prefix_seen.popitem(last=False)
-            if self._prefix_seen[key] < 2:
-                continue
-            if self._prefix_free:
-                slot = self._prefix_free.pop()
-            else:
-                _, slot = self._prefix_index.popitem(last=False)  # LRU
-            self._prefix_pool = _jit_store_prefix(
-                self._prefix_pool, cache_n, jnp.int32(i), jnp.int32(slot),
-                p)
-            self._prefix_index[key] = slot
-            with self._lock:
-                self.prefix_stores += 1
-
     # skylint: engine-thread
     def _prefill_one_chunk(self, params, cfg, cache1, row, consumed):
         """One bounded chunk of a single-row incremental prefill.
@@ -2130,9 +1886,8 @@ class ContinuousEngine:
         req = entry.req
         n = len(req.row)
         spec = self.draft_cfg is not None
-        # Draft advances first: it starts at 0 even when the target got
-        # a prefix-pool head start (the pool stores TARGET KV only), and
-        # a parked target must not stall the draft's remaining chunks.
+        # Draft advances first: a parked target must not stall the
+        # draft's remaining chunks.
         if spec and entry.cache is not None and entry.d_consumed < n:
             _, entry.d_cache, entry.d_consumed = self._prefill_one_chunk(
                 self.draft_params, self.draft_cfg, entry.d_cache,
@@ -2143,13 +1898,11 @@ class ContinuousEngine:
             self._finish_long_prefill(entry)
             return
         if entry.cache is None:
-            # First chunk: seed from the share trie (block granularity,
-            # preferred) or the dense prefix pool when the prompt's
+            # First chunk: seed from the share trie when the prompt's
             # head is cached — long popular prompts (system preambles)
             # are where prefix reuse pays most.
             cache1, p_hit = None, 0
             if self._trie is not None:
-                from skypilot_tpu.models import paged as paged_lib
                 with self._lock:
                     t_nodes, _, _ = self._trie.match(req.row)
                     t_blocks = [nd.block for nd in t_nodes]
@@ -2176,16 +1929,6 @@ class ContinuousEngine:
                 else:
                     with self._lock:
                         self.share_misses += 1
-            if cache1 is None and self._prefix_pool is not None:
-                p_hit, pool_row = self._match_prefix(req.row)
-                if p_hit:
-                    cache1 = _jit_gather_prefix(
-                        self._prefix_pool,
-                        np.asarray([pool_row], np.int32),
-                        np.asarray([p_hit], np.int32), self.max_len)
-                    with self._lock:
-                        self.prefix_hits += 1
-                        self.prefix_hit_tokens += p_hit
             if cache1 is None:
                 cache1 = gen_lib.init_cache(self.cfg, 1, self.max_len,
                                             quantize=self.kv_quantize)
@@ -2201,11 +1944,6 @@ class ContinuousEngine:
             self.prefill_chunks += 1
         if entry.consumed >= n:
             req.timeline.prefill = time.perf_counter()
-            if self._prefix_pool is not None:
-                # Store this prompt's bucket prefix on its second
-                # sighting, like the grouped path (cache row 0 holds
-                # the full prompt's KV).
-                self._maybe_store_prefixes([req.row], [0], entry.cache)
             # Sample the first token ONCE off the final chunk's logits;
             # the entry may then park for a free slot (or, spec mode,
             # for the draft's remaining chunks).
@@ -2240,23 +1978,19 @@ class ContinuousEngine:
             if not done:
                 free = [i for i, r in enumerate(self._slot_req)
                         if r is None]
-                if not free:
-                    return  # park; retried next iteration
-                if self.kv_layout == 'paged':
-                    nb = self._blocks_needed(req)
-                    if self._blocks_avail() < nb:
-                        return  # park until a completion frees blocks
-                    # skylint: allow-leak(engine thread: an escape here
-                    # reaches _fail_everything, which rebuilds the
-                    # device state and the whole block pool)
-                    blocks = self._alloc_blocks(nb)
-                    table_row = np.zeros(
-                        (self.max_len // self.kv_block,), np.int32)
-                    table_row[:nb] = blocks
+                nb = self._blocks_needed(req)
+                if not free or self._blocks_avail() < nb:
+                    return  # park until a completion frees a slot/blocks
+                # skylint: allow-leak(engine thread: an escape here
+                # reaches _fail_everything, which rebuilds the device
+                # state and the whole block pool)
+                blocks = self._alloc_blocks(nb)
+                table_row = np.zeros(
+                    (self.max_len // self.kv_block,), np.int32)
+                table_row[:nb] = blocks
                 slot = free[0]
                 self._slot_req[slot] = req
-                if table_row is not None:
-                    self._slot_blocks[slot] = list(table_row[:nb])
+                self._slot_blocks[slot] = list(blocks)
         with self._lock:
             self._prefilling.pop(0)
             self.prefills += 1
@@ -2266,21 +2000,15 @@ class ContinuousEngine:
         self._emit([(req, [entry.first_host])], [req] if done else [])
         if done:
             return
-        if self.kv_layout == 'paged':
-            from skypilot_tpu.models import paged as paged_lib
-            self._cache = paged_lib.jit_insert(
-                self._cache, entry.cache, np.asarray(table_row[None]),
-                np.asarray([slot], np.int32))
-            self._last = self._last.at[
-                jnp.asarray([slot], jnp.int32)].set(entry.first)
-            if self._trie is not None:
-                with self._lock:
-                    if self._slot_req[slot] is req:
-                        self._commit_prompt_blocks(slot, req.row, [])
-        else:
-            self._cache, self._last = _jit_insert(
-                self._cache, self._last, entry.cache, entry.first,
-                jnp.asarray([slot], jnp.int32))
+        self._cache = paged_lib.jit_insert(
+            self._cache, entry.cache, np.asarray(table_row[None]),
+            np.asarray([slot], np.int32))
+        self._last = self._last.at[
+            jnp.asarray([slot], jnp.int32)].set(entry.first)
+        if self._trie is not None:
+            with self._lock:
+                if self._slot_req[slot] is req:
+                    self._commit_prompt_blocks(slot, req.row, [])
         if self.draft_cfg is not None:
             self._d_cache = _jit_insert_cache(
                 self._d_cache, entry.d_cache,
@@ -2288,41 +2016,36 @@ class ContinuousEngine:
 
     # skylint: engine-thread
     def _finish_long_export(self, entry: _Prefilling) -> None:
-        """Export retirement for a chunked long prefill. Dense engines
-        serialize the scratch row directly (no slot at all); paged
-        engines insert into pool blocks first — COMMITTING the prompt
-        chain, so later sharers and later exports of the same long
-        preamble hit the trie — and gather back out. May PARK (return
-        without popping) awaiting a slot/blocks like a normal finish."""
+        """Export retirement for a chunked long prefill: insert the
+        scratch row into pool blocks — COMMITTING the prompt chain, so
+        later sharers and later exports of the same long preamble hit
+        the trie — and gather back out. May PARK (return without
+        popping) awaiting a slot/blocks like a normal finish."""
         req = entry.req
-        if self.kv_layout == 'paged':
+        with self._lock:
+            free = [i for i, r in enumerate(self._slot_req)
+                    if r is None]
+            nb = self._blocks_needed(req)
+            if not free or self._blocks_avail() < nb:
+                return  # park; retried next iteration
+            # skylint: allow-leak(engine thread: an escape here
+            # reaches _fail_everything, which rebuilds the device
+            # state and the whole block pool)
+            blocks = self._alloc_blocks(nb)
+            table_row = np.zeros((self.max_len // self.kv_block,),
+                                 np.int32)
+            table_row[:nb] = blocks
+            slot = free[0]
+            self._slot_req[slot] = req
+            self._slot_blocks[slot] = list(blocks)
+            self._slot_table[slot] = table_row.copy()
+        self._cache = paged_lib.jit_insert(
+            self._cache, entry.cache, np.asarray(table_row[None]),
+            np.asarray([slot], np.int32))
+        if self._trie is not None:
             with self._lock:
-                free = [i for i, r in enumerate(self._slot_req)
-                        if r is None]
-                nb = self._blocks_needed(req)
-                if not free or self._blocks_avail() < nb:
-                    return  # park; retried next iteration
-                # skylint: allow-leak(engine thread: an escape here
-                # reaches _fail_everything, which rebuilds the device
-                # state and the whole block pool)
-                blocks = self._alloc_blocks(nb)
-                table_row = np.zeros((self.max_len // self.kv_block,),
-                                     np.int32)
-                table_row[:nb] = blocks
-                slot = free[0]
-                self._slot_req[slot] = req
-                self._slot_blocks[slot] = list(blocks)
-                self._slot_table[slot] = table_row.copy()
-            from skypilot_tpu.models import paged as paged_lib
-            self._cache = paged_lib.jit_insert(
-                self._cache, entry.cache, np.asarray(table_row[None]),
-                np.asarray([slot], np.int32))
-            if self._trie is not None:
-                with self._lock:
-                    if self._slot_req[slot] is req:
-                        self._commit_prompt_blocks(slot, req.row, [])
-        else:
-            req.export_src = (entry.cache, 0)
+                if self._slot_req[slot] is req:
+                    self._commit_prompt_blocks(slot, req.row, [])
         with self._lock:
             self._prefilling.pop(0)
             self.prefills += 1
@@ -2338,63 +2061,29 @@ class ContinuousEngine:
         had_active = any(r is not None for r in self._slot_req)
         n = len(reqs)
         rows = [r.row for r in reqs]
-        p_lens = [0] * n
-        pool_rows = [0] * n
-        if self._prefix_pool is not None:
-            for i, row in enumerate(rows):
-                p_lens[i], pool_rows[i] = self._match_prefix(row)
-            # Demote any hit whose prefix + PADDED suffix would overflow
-            # the cache width — dynamic_update_slice clamps out-of-range
-            # starts, which would smear padded junk over real prefix KV.
-            while True:
-                s_b = min(prompt_bucket(max(
-                    len(r) - p for r, p in zip(rows, p_lens))),
+        width = min(prompt_bucket(max(len(r) for r in rows)),
                     self.max_len)
-                bad = [i for i in range(n)
-                       if p_lens[i] and p_lens[i] + s_b > self.max_len]
-                if not bad:
-                    break
-                for i in bad:
-                    p_lens[i], pool_rows[i] = 0, 0
-        suffixes = [row[p:] for row, p in zip(rows, p_lens)]
-        width_s = min(prompt_bucket(max(len(s) for s in suffixes)),
-                      self.max_len)
-        cache_width = min(prompt_bucket(
-            max(p + width_s for p in p_lens)), self.max_len)
-        padded = np.zeros((n, width_s), np.int32)
+        padded = np.zeros((n, width), np.int32)
         lens = np.zeros((n,), np.int32)
         temps = np.zeros((n,), np.float32)
         top_ks = np.zeros((n,), np.int32)
         top_ps = np.ones((n,), np.float32)
-        for i, (r, suf) in enumerate(zip(reqs, suffixes)):
-            padded[i, :len(suf)] = suf
-            lens[i] = len(suf)
+        for i, r in enumerate(reqs):
+            padded[i, :len(r.row)] = r.row
+            lens[i] = len(r.row)
             temps[i] = r.temperature
             top_ks[i] = r.top_k
             top_ps[i] = r.top_p
-        hits = sum(1 for p in p_lens if p)
-        if self._prefix_pool is not None and hits:
-            cache_n = _jit_gather_prefix(
-                self._prefix_pool, np.asarray(pool_rows, np.int32),
-                np.asarray(p_lens, np.int32), cache_width)
-            with self._lock:
-                self.prefix_hits += hits
-                self.prefix_hit_tokens += sum(p_lens)
-        else:
-            cache_n = self._ops.init_cache(self.cfg, n, cache_width,
-                                           quantize=self.kv_quantize)
+        cache_n = self._ops.init_cache(self.cfg, n, width,
+                                       quantize=self.kv_quantize)
         logits, cache_n = self._ops.prefill(
             self.params, padded, cache_n, self.cfg,
             np.asarray(lens))
         now = time.perf_counter()
-        for r, p in zip(reqs, p_lens):
+        for r in reqs:
             r.timeline.prefill = now
-            r.timeline.saved_tokens = p
         with self._lock:
             self.prefill_tokens += int(lens.sum())
-            self.prefill_tokens_saved += sum(p_lens)
-        if self._prefix_pool is not None:
-            self._maybe_store_prefixes(rows, p_lens, cache_n)
         tk, tp = _filters_or_none(top_ks, top_ps)
         firsts = _jit_sample(logits, np.asarray(temps), self._next_key(),
                              tk, tp)
@@ -2403,61 +2092,46 @@ class ContinuousEngine:
         # lazily (``_drain_firsts``) — prefill+insert are then pure async
         # dispatches, and the fetch overlaps the next decode chunk's
         # device time instead of paying its own relay round trip.
-        if self.kv_layout == 'paged':
-            from skypilot_tpu.models import paged as paged_lib
-            mb = self.max_len // self.kv_block
-            tables_host = np.zeros((n, mb), np.int32)
+        mb = self.max_len // self.kv_block
+        tables_host = np.zeros((n, mb), np.int32)
+        with self._lock:
+            for i, r in enumerate(reqs):
+                if r.max_new <= 1 and not r.export:
+                    continue  # resolves at prefill: junk-sink row
+                # Export requests DO take blocks even at
+                # max_new == 1: the handoff serializes from the
+                # pool, and a junk-sink row would lose the KV.
+                nb = self._blocks_needed(r)
+                blocks = self._alloc_blocks(nb)  # _admit reserved
+                self._slot_blocks[slots[i]] = blocks
+                tables_host[i, :nb] = blocks
+                self._slot_table[slots[i]] = tables_host[i].copy()
+        self._cache = self._ops.insert_paged(
+            self._cache, cache_n, tables_host,
+            # skylint: allow-host-sync(slots is a host list of slot
+            # indices — asarray builds the jit operand, no transfer)
+            np.asarray(slots, np.int32))
+        self._last = self._last.at[
+            jnp.asarray(slots, jnp.int32)].set(firsts)
+        if self._trie is not None:
+            # Index the group's full prompt blocks for later
+            # sharers (the insert above was already dispatched, so
+            # any future gather of these blocks is device-ordered
+            # after their content lands).
             with self._lock:
                 for i, r in enumerate(reqs):
-                    if r.max_new <= 1 and not r.export:
-                        continue  # resolves at prefill: junk-sink row
-                    # Export requests DO take blocks even at
-                    # max_new == 1: the handoff serializes from the
-                    # pool, and a junk-sink row would lose the KV.
-                    nb = self._blocks_needed(r)
-                    blocks = self._alloc_blocks(nb)  # _admit reserved
-                    self._slot_blocks[slots[i]] = blocks
-                    tables_host[i, :nb] = blocks
-                    self._slot_table[slots[i]] = tables_host[i].copy()
-            self._cache = self._ops.insert_paged(
-                self._cache, cache_n, tables_host,
-                # skylint: allow-host-sync(slots is a host list of slot
-                # indices — asarray builds the jit operand, no transfer)
-                np.asarray(slots, np.int32))
-            self._last = self._last.at[
-                jnp.asarray(slots, jnp.int32)].set(firsts)
-            if self._trie is not None:
-                # Index the group's full prompt blocks for later
-                # sharers (the insert above was already dispatched, so
-                # any future gather of these blocks is device-ordered
-                # after their content lands).
-                with self._lock:
-                    for i, r in enumerate(reqs):
-                        if r.max_new > 1 or r.export:
-                            self._commit_prompt_blocks(slots[i], rows[i],
-                                                       [])
-                            self.share_misses += 1
-        else:
-            self._cache, self._last = _jit_insert(
-                self._cache, self._last, cache_n, firsts,
-                jnp.asarray(slots, jnp.int32))
+                    if r.max_new > 1 or r.export:
+                        self._commit_prompt_blocks(slots[i], rows[i],
+                                                   [])
+                        self.share_misses += 1
         if self.draft_cfg is not None:
-            # The draft tracks the same committed stream, so its cache
-            # prefills the FULL rows (the prefix pool stores target KV
-            # only — the draft model is small enough that re-prefilling
-            # a cached head costs little).
-            width_f = min(prompt_bucket(max(len(r) for r in rows)),
-                          self.max_len)
-            padded_f = np.zeros((n, width_f), np.int32)
-            lens_f = np.zeros((n,), np.int32)
-            for i, r in enumerate(rows):
-                padded_f[i, :len(r)] = r
-                lens_f[i] = len(r)
-            d_cache_n = gen_lib.init_cache(self.draft_cfg, n, width_f,
+            # The draft tracks the same committed stream: the same
+            # padded rows prefill its own dense cache.
+            d_cache_n = gen_lib.init_cache(self.draft_cfg, n, width,
                                            quantize=self.kv_quantize)
             _, d_cache_n = gen_lib._jit_prefill(  # noqa: SLF001
-                self.draft_params, padded_f, d_cache_n,
-                self.draft_cfg, lens_f)
+                self.draft_params, padded, d_cache_n,
+                self.draft_cfg, lens)
             self._d_cache = _jit_insert_cache(
                 self._d_cache, d_cache_n,
                 # skylint: allow-host-sync(slots is a host list of slot
@@ -2467,13 +2141,9 @@ class ContinuousEngine:
             self.prefills += n
             self._unfetched.append((reqs, firsts))
             for i, req in enumerate(reqs):
-                if req.export and self.kv_layout != 'paged':
-                    # Dense export serializes straight from the prefill
-                    # cache at drain time — no slot occupancy at all.
-                    req.export_src = (cache_n, i)
-                elif req.max_new > 1 or req.export:
-                    # Paged exports hold their slot (and blocks) until
-                    # the drain gathers them out of the pool.
+                if req.max_new > 1 or req.export:
+                    # Exports hold their slot (and blocks) until the
+                    # drain gathers them out of the pool.
                     self._slot_req[slots[i]] = req
         self._note_prefill_time(t0, had_active)
 
@@ -2553,7 +2223,6 @@ class ContinuousEngine:
                     self._slot_req[si] = None
                     self._release_blocks(si)
                     break
-        req.export_src = None  # drop the dense prefill-cache reference
         if req.timeline.first is None:  # the chunked long-prefill path
             req.timeline.first = t0
         if handoff is None:
@@ -2576,19 +2245,6 @@ class ContinuousEngine:
                     max_new=req.max_new, temperature=req.temperature,
                     top_k=req.top_k, top_p=req.top_p, eos=req.eos,
                     prompt_len=n)
-        if self.kv_layout != 'paged':
-            cache_n, i = req.export_src  # retained by _prefill_group
-            k, v, k_s, v_s = jax.device_get(
-                (cache_n.k[:, i], cache_n.v[:, i], cache_n.k_s,
-                 cache_n.v_s))
-            k = np.asarray(k)[:, None, :, :n]     # [L, 1, H, n, D]
-            v = np.asarray(v)[:, None, :, :n]
-            if k_s is not None:
-                k_s = np.asarray(k_s)[:, i][:, None, :, :n]
-                v_s = np.asarray(v_s)[:, i][:, None, :, :n]
-            return PrefillHandoff(layout='slot', k=k, v=v, k_s=k_s,
-                                  v_s=v_s, **base)
-        from skypilot_tpu.models import paged as paged_lib
         p = self.kv_block
         nb = -(-n // p)
         with self._lock:
@@ -2611,8 +2267,8 @@ class ContinuousEngine:
         if k_s is not None:
             k_s = np.asarray(k_s)[:, :nb]
             v_s = np.asarray(v_s)[:, :nb]
-        return PrefillHandoff(layout='paged', block=p, n_blocks=nb,
-                              k=k, v=v, k_s=k_s, v_s=v_s, **base)
+        return PrefillHandoff(block=p, n_blocks=nb, k=k, v=v, k_s=k_s,
+                              v_s=v_s, **base)
 
     # skylint: engine-thread
     @profiler.spanned('engine.admit_imports')
@@ -2625,7 +2281,6 @@ class ContinuousEngine:
         watches via ``queued_imports``. The leading locally-shared
         chain installs as table REFERENCES (trie acquire) and only
         genuinely new blocks scatter."""
-        from skypilot_tpu.models import paged as paged_lib
         while True:
             doomed = None
             with self._lock:
@@ -2646,43 +2301,40 @@ class ContinuousEngine:
                     if len(free) - parked <= 0:
                         return  # backpressure: the head waits
                     slot = free[0]
-                    if self.kv_layout == 'paged':
-                        n = len(req.row)
-                        p = self.kv_block
-                        if self._trie is not None:
-                            nodes, _, _ = self._trie.match(
-                                req.row, limit=(n // p) * p)
-                        if len(nodes) < entry.block_start:
-                            # Blocks negotiated away as references were
-                            # evicted between prepare and import: the
-                            # payload cannot be installed — reject, the
-                            # serving layer falls back to colocated.
-                            self._pending_imports.popleft()
-                            self.import_errors += 1
-                            doomed = req
-                        else:
-                            need = (self._blocks_for(n, req.max_new)
-                                    - len(nodes))
-                            pinned = sum(1 for nd in nodes
-                                         if nd.refs == 0)
-                            if self._blocks_avail() - pinned < need:
-                                return  # backpressure: the head waits
-                            for nd in nodes:
-                                self._trie.acquire(nd)
-                            # skylint: allow-leak(engine thread: an
-                            # escape here reaches _fail_everything,
-                            # which rebuilds the device state and the
-                            # whole block pool)
-                            owned = self._alloc_blocks(need)
-                            mb = self.max_len // p
-                            table_row = np.zeros((mb,), np.int32)
-                            table_row[:len(nodes)] = [nd.block
-                                                      for nd in nodes]
-                            table_row[len(nodes):len(nodes) + len(owned)] \
-                                = owned
-                            self._slot_blocks[slot] = list(owned)
-                            self._slot_shared[slot] = list(nodes)
-                            self._slot_table[slot] = table_row.copy()
+                    n = len(req.row)
+                    p = self.kv_block
+                    if self._trie is not None:
+                        nodes, _, _ = self._trie.match(
+                            req.row, limit=(n // p) * p)
+                    if len(nodes) < entry.block_start:
+                        # Blocks negotiated away as references were
+                        # evicted between prepare and import: the
+                        # payload cannot be installed — reject, the
+                        # serving layer falls back to colocated.
+                        self._pending_imports.popleft()
+                        self.import_errors += 1
+                        doomed = req
+                    else:
+                        need = (self._blocks_for(n, req.max_new)
+                                - len(nodes))
+                        pinned = sum(1 for nd in nodes if nd.refs == 0)
+                        if self._blocks_avail() - pinned < need:
+                            return  # backpressure: the head waits
+                        for nd in nodes:
+                            self._trie.acquire(nd)
+                        # skylint: allow-leak(engine thread: an escape
+                        # here reaches _fail_everything, which rebuilds
+                        # the device state and the whole block pool)
+                        owned = self._alloc_blocks(need)
+                        mb = self.max_len // p
+                        table_row = np.zeros((mb,), np.int32)
+                        table_row[:len(nodes)] = [nd.block
+                                                  for nd in nodes]
+                        table_row[len(nodes):len(nodes) + len(owned)] \
+                            = owned
+                        self._slot_blocks[slot] = list(owned)
+                        self._slot_shared[slot] = list(nodes)
+                        self._slot_table[slot] = table_row.copy()
                     if doomed is None:
                         self._slot_req[slot] = req
                         self._pending_imports.popleft()
@@ -2710,10 +2362,7 @@ class ContinuousEngine:
                 continue
             # Device install (outside the lock: submit() must not wait
             # on a scatter dispatch).
-            if self.kv_layout == 'paged':
-                self._install_import_paged(entry, slot, nodes, table_row)
-            else:
-                self._install_import_dense(entry, slot)
+            self._install_import_paged(entry, slot, nodes, table_row)
             with self._lock:
                 if self._slot_req[slot] is req:
                     self._commit_prompt_blocks(slot, req.row, nodes)
@@ -2737,7 +2386,6 @@ class ContinuousEngine:
         install table/length/last at ``slot`` — one jit dispatch plus
         the ``last`` write. Blocks below the local share point install
         as references (their bytes, if transferred, are ignored)."""
-        from skypilot_tpu.models import paged as paged_lib
         req = entry.req
         n = len(req.row)
         p = self.kv_block
@@ -2775,36 +2423,6 @@ class ContinuousEngine:
             table_row, np.int32(slot), np.int32(n))
         self._last = self._last.at[jnp.asarray([slot], jnp.int32)].set(
             jnp.asarray([entry.first], jnp.int32))
-
-    # skylint: engine-thread
-    def _install_import_dense(self, entry: _ImportEntry,
-                              slot: int) -> None:
-        """Dense ('slot') install: rebuild a 1-row prefill cache from
-        the transferred bytes and reuse the standard insert."""
-        req = entry.req
-        n = len(req.row)
-        w = min(prompt_bucket(n), self.max_len)
-        l, _, h, _, d = entry.k.shape
-        k = np.zeros((l, 1, h, w, d), dtype=entry.k.dtype)
-        v = np.zeros((l, 1, h, w, d), dtype=entry.v.dtype)
-        k[:, :, :, :n] = entry.k
-        v[:, :, :, :n] = entry.v
-        k_s = v_s = None
-        if self.kv_quantize:
-            k_s = np.zeros((l, 1, h, w), np.float32)
-            v_s = np.zeros((l, 1, h, w), np.float32)
-            k_s[:, :, :, :n] = entry.k_s
-            v_s[:, :, :, :n] = entry.v_s
-        cache_n = gen_lib.KVCache(k=jnp.asarray(k), v=jnp.asarray(v),
-                                  lengths=np.asarray([n], np.int32),
-                                  k_s=None if k_s is None
-                                  else jnp.asarray(k_s),
-                                  v_s=None if v_s is None
-                                  else jnp.asarray(v_s))
-        self._cache, self._last = _jit_insert(
-            self._cache, self._last, cache_n,
-            np.asarray([entry.first], np.int32),
-            jnp.asarray([slot], jnp.int32))
 
     # skylint: engine-thread
     def _run_spec_round(self) -> None:
@@ -2933,7 +2551,7 @@ class ContinuousEngine:
         """Issue (async) one K-step decode chunk over ALL slots against
         the current slot snapshot. Dispatch and retirement strictly
         alternate (one of each per _run_chunk), which is exactly the
-        paged layout's safety boundary: a slot is freed (blocks
+        pool's safety boundary: a slot is freed (blocks
         released) during retirement of chunk N, so exactly ONE chunk —
         N+1, dispatched just before that retirement — runs with the
         slot stale-active, writing junk through its own still-current
@@ -2982,17 +2600,10 @@ class ContinuousEngine:
                             ms=round(bubble_closed_ms, 3),
                             edge='dispatch')
         tk, tp = _filters_or_none(top_ks, top_ps)
-        counts = None
-        if self.kv_layout == 'paged':
-            self._cache, self._last, toks, counts = self._ops.paged_chunk(
-                self.cfg, self.chunk_steps, self.params, self._cache,
-                self._last, np.asarray(temps), tk, tp,
-                np.asarray(active), self._next_key(), self._shard_ctx)
-        else:
-            self._cache, self._last, toks = _jit_chunk(
-                self.cfg, self.chunk_steps, self.params, self._cache,
-                self._last, np.asarray(temps), tk, tp,
-                np.asarray(active), self._next_key(), self._shard_ctx)
+        self._cache, self._last, toks, counts = self._ops.paged_chunk(
+            self.cfg, self.chunk_steps, self.params, self._cache,
+            self._last, np.asarray(temps), tk, tp,
+            np.asarray(active), self._next_key(), self._shard_ctx)
         return _Inflight(reqs=reqs, toks=toks, steps=self.chunk_steps,
                          counts=counts)
 

@@ -13,8 +13,6 @@ training stack (``models/llama.py``).
 from __future__ import annotations
 
 import dataclasses
-import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -31,35 +29,19 @@ Params = llama.Params
 _NEG_INF = -1e30
 
 
-# Latched at IMPORT: generate()'s module-level jits cache on shapes and
-# static args only, so a flag that changed mid-process would be
-# silently ignored for already-compiled shapes — latching makes the
-# semantics honest (set the env before the serving process starts).
-# 'pallas' compiles the Mosaic kernel (TPU only); 'interpret' runs it in
-# the Pallas interpreter — something tests and the CPU rehearsal ask
-# for by name, never inferred from the backend. Tests monkeypatch the
-# module attribute directly.
-_DECODE_KERNEL = os.environ.get('SKYTPU_DECODE_KERNEL') or None
-if _DECODE_KERNEL not in (None, 'pallas', 'interpret'):
-    raise ValueError(f'SKYTPU_DECODE_KERNEL={_DECODE_KERNEL!r}: '
-                     "'pallas' or 'interpret'")
-
-
 def kernel_shard_ctx(mesh, rules):
-    """Hashable context that lets the pallas decode kernel run under a
-    TP mesh: ``shard_map`` launches the kernel per head SHARD — its grid
-    is (B, Hkv) with no cross-head communication, so head-sharded
-    inputs need no collectives and the output stays head-sharded for
-    the wo matmul (GSPMD inserts that psum as usual). Without this, a
-    ``pallas_call`` traced under GSPMD would all-gather the full
-    per-layer caches (r4 verdict Next #6's worst remaining ✗)."""
+    """Hashable context that lets the paged pool's write, read and
+    decode kernel run under a TP mesh (``paged._cache_step``):
+    ``shard_map`` launches them per kv-head SHARD — no cross-head
+    communication, so head-sharded inputs need no collectives and the
+    output stays head-sharded for the wo matmul (GSPMD inserts that
+    psum as usual). Without this, a ``pallas_call`` traced under GSPMD
+    would all-gather the pool."""
     if mesh is None:
         return None
     return (mesh,
             rules.mesh_axes(('batch', 'heads', None)),           # q
-            rules.mesh_axes(('batch', 'kv_heads', None, None)),  # k/v
-            rules.mesh_axes(('batch',)),                         # lengths
-            rules.mesh_axes(('batch', 'kv_heads', None)))        # scales
+            rules.mesh_axes(('batch', 'kv_heads', None, None)))  # k/v
 
 
 @dataclasses.dataclass
@@ -124,8 +106,7 @@ def init_cache(cfg: llama.LlamaConfig, batch: int, max_len: int,
 def _cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                       positions: jax.Array, valid_len: jax.Array,
                       k_s: Optional[jax.Array] = None,
-                      v_s: Optional[jax.Array] = None,
-                      shard_ctx=None) -> jax.Array:
+                      v_s: Optional[jax.Array] = None) -> jax.Array:
     """q: [B, S, Hq, D] (absolute ``positions`` [B, S]);
     k/v_cache: [B, Hkv, max_len, D] already containing this block's keys.
     Attends causally over the first ``valid_len[b]`` cache slots per row
@@ -134,41 +115,6 @@ def _cached_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     position: keys scale the post-QK logits, values scale the probs
     before PV — the full-precision cache never materializes."""
     b, s, hq, d = q.shape
-    if s == 1 and _DECODE_KERNEL is not None:
-        # Opt-in pallas flash-decode (ops/decode_attention.py): streams
-        # the cache once with an online softmax instead of
-        # materializing the [B, Hkv, G, 1, M] fp32 logits between two
-        # einsums. Tolerance-level (not bit-exact) vs this path, hence
-        # opt-in: SKYTPU_DECODE_KERNEL=pallas.
-        from skypilot_tpu.ops import attention as attention_ops
-        from skypilot_tpu.ops import decode_attention
-        if decode_attention.fits(k_cache.shape[2], d):
-            lengths = (jnp.broadcast_to(valid_len, (b,)).astype(jnp.int32)
-                       if valid_len.ndim == 0
-                       else valid_len.astype(jnp.int32))
-            kernel = functools.partial(
-                decode_attention.flash_decode,
-                interpret=_DECODE_KERNEL == 'interpret')
-            args = (q[:, 0], k_cache, v_cache, lengths)
-            if k_s is not None:
-                args += (k_s, v_s)
-            if shard_ctx is None:
-                out = kernel(*args)
-            else:
-                # TP serving: run the kernel per head shard (see
-                # kernel_shard_ctx). check_vma off: the scalar-prefetch
-                # grid confuses the replication checker.
-                mesh, p_q, p_kv, p_len, p_s = shard_ctx
-                in_specs = (p_q, p_kv, p_kv, p_len) + (
-                    (p_s, p_s) if k_s is not None else ())
-                out = jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
-                                    out_specs=p_q, check_vma=False)(*args)
-            return out[:, None].astype(q.dtype)
-        # Geometry the kernel can't take (VMEM cap / non-128 cache):
-        # the einsum path below, said once per shape.
-        attention_ops.log_fallback_once(
-            'flash_decode', k_cache.shape,
-            f'cache M={k_cache.shape[2]}, D={d} outside fits()')
     hkv = k_cache.shape[1]
     group = hq // hkv
     max_len = k_cache.shape[2]
@@ -252,7 +198,7 @@ def _write_block(cache_arr: jax.Array, scale_arr: Optional[jax.Array],
 def _qkv_proj(cfg: llama.LlamaConfig, x: jax.Array, layer: Params,
               positions: jax.Array):
     """Shared attention front half (norm + QKV projections + RoPE) —
-    one definition for the dense (slot-pinned) and paged layers; only
+    one definition for the dense-cache and paged layers; only
     the cache write/read strategy differs between them."""
     h = llama.rms_norm(x, layer['attn_norm'], cfg.norm_eps)
     # _mm = einsum that transparently handles int8 weight-only
@@ -290,8 +236,7 @@ def _cached_layer(cfg: llama.LlamaConfig, x: jax.Array, layer: Params,
                   valid: jax.Array,
                   active_rows: Optional[jax.Array] = None,
                   k_s: Optional[jax.Array] = None,
-                  v_s: Optional[jax.Array] = None,
-                  shard_ctx=None):
+                  v_s: Optional[jax.Array] = None):
     """One decoder block writing this block's K/V into the cache.
     x: [B, S, d]; k/v_cache: [B, Hkv, max_len, D]; ``cache_lens`` [B];
     ``valid`` [B] = cache_lens + real new tokens per row (< S for padded
@@ -310,7 +255,7 @@ def _cached_layer(cfg: llama.LlamaConfig, x: jax.Array, layer: Params,
     k_cache, k_s = _write_block(k_cache, k_s, kt, cache_lens)
     v_cache, v_s = _write_block(v_cache, v_s, vt, cache_lens)
     att = _cached_attention(q, k_cache, v_cache, positions, valid,
-                            k_s, v_s, shard_ctx)
+                            k_s, v_s)
     x = x + _mm(att, layer['wo'], 'bshk,hkd->bsd')
     # MoE decode: same GShard dense-einsum dispatch as training
     # (models/moe.py) — at S=1 the "token" dim is just the batch, and
@@ -336,8 +281,8 @@ def forward_cached(params: Params, tokens: jax.Array,
                    cache: KVCache, cfg: llama.LlamaConfig,
                    row_lens: Optional[jax.Array] = None,
                    active_rows: Optional[jax.Array] = None,
-                   all_logits: bool = False,
-                   shard_ctx=None) -> Tuple[jax.Array, KVCache]:
+                   all_logits: bool = False
+                   ) -> Tuple[jax.Array, KVCache]:
     """Run ``tokens`` [B, S] through the model appending to ``cache``;
     returns (logits for each row's LAST REAL position [B, vocab], updated
     cache). Works for prefill (S = padded prompt length) and decode
@@ -376,7 +321,7 @@ def forward_cached(params: Params, tokens: jax.Array,
             ks_c = vs_c = None
         x, k_c, v_c, ks_c, vs_c = _cached_layer(
             cfg, x, layer, positions, k_c, v_c, write_start, valid,
-            active_rows, ks_c, vs_c, shard_ctx)
+            active_rows, ks_c, vs_c)
         ys = (k_c, v_c, ks_c, vs_c) if quantized else (k_c, v_c)
         return x, ys
 
@@ -466,7 +411,7 @@ def _decode_scan_impl(params, cache, first, key, cfg, n, temps,
     costs a full XLA recompile — top_p alone has unbounded distinct
     float values (r4 advisor low). Only the None/array pytree structure
     gives a second cached variant (same scheme as the engine's
-    ``_chunk_impl``)."""
+    ``_paged_chunk_impl``)."""
     from skypilot_tpu.models import sampling
     forward = model_ops.ops_for(cfg).forward_cached
 

@@ -563,14 +563,13 @@ def init_cache(cfg: MlaMoeConfig, batch: int, max_len: int, dtype=None,
 def forward_cached(params: Params, tokens: jax.Array, cache: KVCache,
                    cfg: MlaMoeConfig, row_lens: Optional[jax.Array] = None,
                    active_rows: Optional[jax.Array] = None,
-                   all_logits: bool = False,
-                   shard_ctx=None) -> Tuple[jax.Array, KVCache]:
+                   all_logits: bool = False
+                   ) -> Tuple[jax.Array, KVCache]:
     """``generate.forward_cached`` for this model: run ``tokens`` [B, S]
     appending their latent rows to the dense ``cache``; logits at each
     row's last real position. A cache exactly S wide can hold no prefix,
     so S == max_len is a FRESH prefill (flash kernel); S == 1 is the
     absorbed step; anything else attends expanded over the row's view."""
-    del shard_ctx
     b, s = tokens.shape
     m = cache.k.shape[3]
     if row_lens is None:

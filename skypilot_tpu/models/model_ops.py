@@ -22,8 +22,8 @@ from typing import Any, Callable, Dict
 class ModelOps:
     """What the serving code needs of a model family.
 
-    Caches: ``init_cache`` (dense, [B, max_len] rows; prefill scratch and
-    the slot layout), ``init_pool`` (paged). Programs, all jitted:
+    Caches: ``init_cache`` (dense, [B, width] rows: prefill scratch),
+    ``init_pool`` (the engine's paged pool). Programs, all jitted:
     ``prefill(params, tokens, cache, cfg, row_lens) -> (logits, cache)``;
     ``insert_paged(pool, cache_n, tables, slots) -> pool``;
     ``fork_block(pool, src, dst) -> pool``;
@@ -38,9 +38,9 @@ class ModelOps:
     ``decode_attention(pool, quantized) -> str``: how the S = 1 step
     reads the pool (``stats()['decode_attention']``).
     ``rows_couple(cfg)``: True where co-batched rows influence each
-    other (capacity-dropping experts): pipelining, chunked prefill, the
-    prefix pool, block sharing, speculation and KV handoff all need
-    independent rows. ``refuses``: engine features this family does not
+    other (capacity-dropping experts): pipelining, chunked prefill,
+    block sharing, speculation and KV handoff all need independent
+    rows. ``refuses``: engine features this family does not
     implement, each with the reason its error gives."""
     name: str
     init_cache: Callable
@@ -107,14 +107,11 @@ def _mla_moe() -> ModelOps:
         kv_bytes_per_token=lambda cfg: cfg.kv_bytes_per_token,
         rows_couple=lambda cfg: False,     # drop-free routing
         refuses={
-            'kv_layout=slot': 'only the paged pool is wired into the '
-                              'engine (generate() serves the dense cache)',
             'kv_quantize': 'the latent pool has no int8 mode',
             'kv_tiers': one_plane,
             'KV handoff': one_plane,
             'speculative decoding': 'no S = k + 1 verify over the latent '
                                     'pool',
-            'prefix_slots': 'the dense prefix pool stores K and V rows',
             'prefill_chunk': 'the chunked long prefill seeds a dense K/V '
                              'scratch row',
             'tensor parallelism': 'the latent plane has one head: no '

@@ -2,12 +2,12 @@
 
 Reference analog: paged attention is the defining memory innovation of
 the reference's serving workloads (``/root/reference/llm/vllm/`` — the
-vLLM recipes its TPU serving docs are built around). The slot-pinned
-engine cache (``models/engine.py``) reserves one full ``[max_len]``
-cache row per slot, so mixed-length traffic strands HBM in tail padding
-(a 64-token chat in a 4096-max_len slot wastes 98% of its row). Paged
-layout carves the cache into fixed-size position BLOCKS shared from one
-pool; each slot holds a small block table, requests reserve only
+vLLM recipes its TPU serving docs are built around). A cache that
+pins one full ``[max_len]`` row per slot strands HBM in tail padding
+under mixed-length traffic (a 64-token chat in a 4096-max_len slot
+wastes 98% of its row). The paged layout, the engine's only one
+(``models/engine.py``), carves the cache into fixed-size position
+BLOCKS shared from one pool; each slot holds a small block table, requests reserve only
 ``ceil((prompt + max_new) / block) `` blocks, and the pool can be sized
 well below ``slots × max_len`` — more concurrent slots at fixed HBM.
 
@@ -375,9 +375,9 @@ def _cache_step(q: jax.Array, kt: jax.Array, vt: jax.Array, pools, l,
     # independent; GSPMD cannot partition a Mosaic call, nor the row
     # scatter's reshape over the sharded head dim: either would gather
     # the pool). The layer, tables, lengths and every batch dim
-    # replicated: a table indexes the whole pool. check_vma off: see
-    # generate._cached_attention.
-    mesh, p_q, p_kv = shard_ctx[:3]
+    # replicated: a table indexes the whole pool. check_vma off: the
+    # kernel's scalar-prefetch grid confuses the replication checker.
+    mesh, p_q, p_kv = shard_ctx
     spec = jax.sharding.PartitionSpec
     heads, new = spec(None, None, p_q[1], None), spec(None, p_kv[1])
     pool = spec(None, None, p_kv[1])

@@ -107,26 +107,14 @@ PROGRAMS: Tuple[Program, ...] = (
             'Window-path decode lax.scan: one shape per (batch, '
             'max_new, filters-on/off) combination.', budget=16),
     # -- models/engine.py ---------------------------------------------
-    Program('engine.insert',
-            'Prefilled-rows → slot-cache scatter: one shape per '
-            'prompt bucket x admission-group size.', budget=24),
-    Program('engine.gather_prefix',
-            'Prefix-pool row gather seeding a prefill cache: one '
-            'shape per prompt bucket.', budget=12),
-    Program('engine.store_prefix',
-            'Prefill row → prefix-pool store: one shape per stored '
-            'power-of-two prefix length.', budget=12),
     Program('engine.sample',
             'Per-slot first-token sampling over prefill logits: one '
             'shape per admission-group size x filter variant.',
             budget=16),
-    Program('engine.chunk',
-            'The K-step dense decode chunk — THE steady-state '
-            'program: one shape per filters-None/array pytree '
-            'variant.', budget=4),
     Program('engine.paged_chunk',
-            'The K-step paged decode chunk (block scatter/gather '
-            'twin of engine.chunk).', budget=4),
+            'The K-step decode chunk over the paged pool — THE '
+            'steady-state program: one shape per filters-None/array '
+            'pytree variant.', budget=4),
     Program('engine.insert_cache',
             'Draft-cache-only insert (speculative mode).', budget=24),
     Program('engine.rewind',

@@ -22,9 +22,8 @@ same checksummed-manifest convention as the ckpt subsystem
 (``skypilot_tpu/ckpt/manifest.py``). A reader rejects any truncation
 or bit-flip before a single byte reaches the device.
 
-Prefix references, not bytes: for the paged layout the prompt's
-full-block CHAIN (trie keys, ``models/paged.py BlockTrie``) is
-derivable from the tokens + block size, so the decode side can be
+Prefix references, not bytes: the prompt's full-block CHAIN (trie
+keys, ``models/paged.py BlockTrie``) is derivable from the tokens + block size, so the decode side can be
 asked (``/v1/kv/prepare``) how many leading blocks it already holds —
 the transfer then STARTS at ``skip_blocks`` and the import installs
 the skipped prefix as local refcounted references. Repeated system
@@ -84,43 +83,37 @@ class DisaggCompatError(DisaggError):
     layout / kv dtype / block-size mismatch)."""
 
 
-def _planes(handoff) -> List[Tuple[str, Optional[int], np.ndarray]]:
-    """(name, block_index_or_None, array) records in stream order.
-    Paged handoffs serialize PER BLOCK (each block a unit with its own
-    checksums, so ``skip_blocks`` slicing and chunked transfer align
-    with validation); dense handoffs are one record."""
-    out: List[Tuple[str, Optional[int], np.ndarray]] = []
-    if handoff.layout == 'paged':
-        for b in range(handoff.n_blocks):
-            out.append(('k', b, handoff.k[:, b]))
-            out.append(('v', b, handoff.v[:, b]))
-            if handoff.k_s is not None:
-                out.append(('k_s', b, handoff.k_s[:, b]))
-                out.append(('v_s', b, handoff.v_s[:, b]))
-    else:
-        out.append(('k', None, handoff.k))
-        out.append(('v', None, handoff.v))
+def _planes(handoff) -> List[Tuple[str, int, np.ndarray]]:
+    """(name, block_index, array) records in stream order. Handoffs
+    serialize PER BLOCK (each block a unit with its own checksums, so
+    ``skip_blocks`` slicing and chunked transfer align with
+    validation)."""
+    out: List[Tuple[str, int, np.ndarray]] = []
+    for b in range(handoff.n_blocks):
+        out.append(('k', b, handoff.k[:, b]))
+        out.append(('v', b, handoff.v[:, b]))
         if handoff.k_s is not None:
-            out.append(('k_s', None, handoff.k_s))
-            out.append(('v_s', None, handoff.v_s))
+            out.append(('k_s', b, handoff.k_s[:, b]))
+            out.append(('v_s', b, handoff.v_s[:, b]))
     return out
 
 
 def build_header(handoff, *, model: str, kv_cache: str,
                  skip_blocks: int = 0) -> Dict[str, Any]:
     """The payload header: request state + plane manifest. With
-    ``skip_blocks`` > 0 (paged only) the first ``skip_blocks`` FULL
-    blocks transfer as references — their plane records are omitted
-    and the importer resolves them against its own trie."""
-    if skip_blocks and handoff.layout != 'paged':
-        raise ValueError('skip_blocks requires the paged layout')
+    ``skip_blocks`` > 0 the first ``skip_blocks`` FULL blocks transfer
+    as references — their plane records are omitted and the importer
+    resolves them against its own trie. ``layout`` is always
+    ``'paged'``: the field stays on the wire so that a replica of an
+    older version meeting this one is refused by name
+    (``check_compat``)."""
     if skip_blocks > handoff.full_blocks:
         raise ValueError(
             f'skip_blocks {skip_blocks} exceeds the shareable chain '
             f'({handoff.full_blocks} full blocks)')
     planes = []
     for name, b, arr in _planes(handoff):
-        if b is not None and b < skip_blocks:
+        if b < skip_blocks:
             continue
         arr = np.ascontiguousarray(arr)
         planes.append({'name': name, 'block': b,
@@ -129,7 +122,7 @@ def build_header(handoff, *, model: str, kv_cache: str,
                        'crc32': zlib.crc32(arr.tobytes()) & 0xFFFFFFFF})
     return {
         'format': FORMAT, 'model': model, 'kv_cache': kv_cache,
-        'layout': handoff.layout, 'block': handoff.block,
+        'layout': 'paged', 'block': handoff.block,
         'n_blocks': handoff.n_blocks, 'skip_blocks': int(skip_blocks),
         'prompt_len': handoff.prompt_len,
         'row': list(handoff.row), 'first': int(handoff.first),
@@ -148,7 +141,7 @@ def serialize(handoff, header: Dict[str, Any]) -> Iterator[bytes]:
     yield MAGIC + _LEN.pack(len(hdr)) + hdr
     skip = int(header.get('skip_blocks') or 0)
     for name, b, arr in _planes(handoff):
-        if b is not None and b < skip:
+        if b < skip:
             continue
         yield np.ascontiguousarray(arr).tobytes()
 
@@ -165,7 +158,7 @@ def payload_nbytes(header: Dict[str, Any]) -> int:
 
 def parse(data: bytes) -> Tuple[Dict[str, Any], Dict[str, np.ndarray]]:
     """Parse + VALIDATE a payload. Returns (header, arrays) where the
-    paged arrays are re-stacked [L, nb_present, ...] starting at
+    arrays are re-stacked [L, nb_present, ...] starting at
     ``skip_blocks``. Raises ``DisaggFormatError`` on any truncation,
     bad magic, or checksum mismatch — corrupt bytes never reach the
     device."""
@@ -205,12 +198,9 @@ def parse(data: bytes) -> Tuple[Dict[str, Any], Dict[str, np.ndarray]]:
         per_plane.setdefault(rec['name'], []).append(arr)
     arrays: Dict[str, np.ndarray] = {}
     for name, parts in per_plane.items():
-        if header.get('layout') == 'paged':
-            # Blocks were serialized [L, H, P(, D)] each; restack on a
-            # new block axis 1 -> [L, nb_present, H, P(, D)].
-            arrays[name] = np.stack(parts, axis=1)
-        else:
-            arrays[name] = parts[0]
+        # Blocks were serialized [L, H, P(, D)] each; restack on a
+        # new block axis 1 -> [L, nb_present, H, P(, D)].
+        arrays[name] = np.stack(parts, axis=1)
     return header, arrays
 
 
@@ -226,23 +216,24 @@ def import_kwargs(header: Dict[str, Any],
         top_k=int(header.get('top_k') or 0),
         top_p=float(header.get('top_p') or 1.0),
         eos=frozenset(int(t) for t in eos) if eos else None,
-        layout=header.get('layout') or 'paged',
         block_start=int(header.get('skip_blocks') or 0),
         k=arrays.get('k'), v=arrays.get('v'),
         k_s=arrays.get('k_s'), v_s=arrays.get('v_s'))
 
 
 def check_compat(header: Dict[str, Any], *, model: str, kv_cache: str,
-                 kv_layout: str, kv_block: int, max_len: int) -> None:
+                 kv_block: int, max_len: int) -> None:
     """Raise ``DisaggCompatError`` unless this replica can install the
-    payload byte-exactly."""
-    want = {'model': model, 'kv_cache': kv_cache, 'layout': kv_layout}
+    payload byte-exactly. ``layout`` anything but ``'paged'`` is a
+    prefill replica from before the engine kept one KV layout (a
+    rolling upgrade can still meet one)."""
+    want = {'model': model, 'kv_cache': kv_cache, 'layout': 'paged'}
     for key, mine in want.items():
         theirs = header.get(key)
         if theirs != mine:
             raise DisaggCompatError(
                 f'handoff {key} {theirs!r} != replica {mine!r}')
-    if kv_layout == 'paged' and int(header.get('block') or 0) != kv_block:
+    if int(header.get('block') or 0) != kv_block:
         raise DisaggCompatError(
             f'handoff block size {header.get("block")} != replica '
             f'{kv_block}')

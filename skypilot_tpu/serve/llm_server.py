@@ -223,9 +223,7 @@ class LlmServer:
                  quantize: Optional[str] = None,
                  engine: Optional[str] = None, tp: Optional[int] = None,
                  kv_cache: Optional[str] = None,
-                 prefix_cache: Optional[int] = None,
                  draft_model: Optional[str] = None,
-                 kv_layout: Optional[str] = None,
                  kv_blocks: Optional[int] = None,
                  pipeline: Optional[str] = None,
                  qos: Optional[str] = None,
@@ -254,20 +252,13 @@ class LlmServer:
         if self.kv_cache not in ('bf16', 'int8'):
             raise ValueError(f'Unknown kv_cache {self.kv_cache!r}; '
                              "'bf16' or 'int8'")
-        self.kv_layout = (kv_layout
-                          or os.environ.get('SKYTPU_LLM_KV_LAYOUT')
-                          or 'slot')
-        if self.kv_layout not in ('slot', 'paged'):
-            raise ValueError(f'Unknown kv_layout {self.kv_layout!r}; '
-                             "'slot' or 'paged'")
-        # Pool size is THE paged knob (a full-capacity pool saves no
+        # Pool size is THE KV knob (a full-capacity pool saves no
         # HBM); 0/None = engine default (full capacity, always safe).
         self.kv_blocks = kv_blocks or int(
             os.environ.get('SKYTPU_LLM_KV_BLOCKS', '0')) or None
-        # Copy-on-write block-level prefix sharing (paged layout;
-        # models/paged.py BlockTrie). Default ON for paged dense
-        # engines — 'off' is the A/B and escape hatch (also via
-        # SKYTPU_LLM_PREFIX_SHARE=0).
+        # Copy-on-write block-level prefix sharing (models/paged.py
+        # BlockTrie). Default ON for dense engines — 'off' is the A/B
+        # and escape hatch (also via SKYTPU_LLM_PREFIX_SHARE=0).
         if prefix_share not in (None, 'on', 'off'):
             raise ValueError(f'Unknown prefix_share {prefix_share!r}; '
                              "'on' or 'off'")
@@ -307,10 +298,6 @@ class LlmServer:
         if engine not in ('continuous', 'off'):
             raise ValueError(f"Unknown engine {engine!r}; 'continuous' "
                              "or 'off'")
-        if prefix_cache is None:
-            prefix_cache = int(os.environ.get('SKYTPU_LLM_PREFIX_CACHE',
-                                              '0'))
-        prefix_cache = int(prefix_cache)
         self.spec_k = int(os.environ.get('SKYTPU_LLM_SPEC_K', '4'))
         if self.spec_k < 1:
             raise ValueError(f'SKYTPU_LLM_SPEC_K must be >= 1, got '
@@ -348,18 +335,6 @@ class LlmServer:
         # (and quantized) SHARDED — a model that only fits spread over
         # the slice must never transit one chip whole.
         self.tp = tp or int(os.environ.get('SKYTPU_LLM_TP', '1'))
-        # SKYTPU_DECODE_KERNEL=pallas composes with --tp > 1 on the
-        # CONTINUOUS engine only: the engine shard_maps the kernel per
-        # head shard (generate.kernel_shard_ctx). The window path
-        # carries no shard ctx, so a pallas_call traced under GSPMD
-        # would all-gather the full per-layer caches — keep the old
-        # startup refusal for --engine off (seeded requests, which also
-        # ride the window path, are refused per-request below).
-        if (self.tp > 1 and gen_lib._DECODE_KERNEL
-                and engine == 'off'):
-            raise ValueError('SKYTPU_DECODE_KERNEL=pallas with --tp > 1 '
-                             'requires the continuous engine (the '
-                             'window path cannot shard the kernel)')
         self.mesh = None
         key = jax.random.PRNGKey(seed)
         if self.tp > 1:
@@ -418,10 +393,8 @@ class LlmServer:
             self.engine = ContinuousEngine(
                 self.params, self.cfg, max_len=self.max_len,
                 mesh=self.mesh, kv_quantize=self.kv_cache == 'int8',
-                prefix_slots=prefix_cache,
                 draft_params=self.draft_params, draft_cfg=self.draft_cfg,
-                spec_k=self.spec_k, kv_layout=self.kv_layout,
-                kv_blocks=self.kv_blocks,
+                spec_k=self.spec_k, kv_blocks=self.kv_blocks,
                 pipeline=(None if self.pipeline is None
                           else self.pipeline == 'on'),
                 prefix_share=(None if self.prefix_share is None
@@ -1026,13 +999,6 @@ class LlmServer:
                           f'{self.max_len}'}, status=400)
         seed = body.get('seed')
         seeded = temperature > 0 and seed is not None
-        if seeded and self.tp > 1 and gen_lib._DECODE_KERNEL:
-            # Seeded requests ride the window path, which cannot shard
-            # the pallas decode kernel (see the --engine off gate).
-            return web.json_response(
-                {'error': 'seeded sampling is unavailable with '
-                          'SKYTPU_DECODE_KERNEL=pallas on a --tp > 1 '
-                          'replica'}, status=400)
         if seeded and self.world > 1:
             # The seeded window path is head-local; a head-only forward
             # over globally sharded weights would deadlock the other
@@ -1418,7 +1384,7 @@ class LlmServer:
             _handoff_nbytes(handoff), disagg_lib.build_header, handoff,
             model=self.model_name, kv_cache=self.kv_cache)
         nbytes = disagg_lib.payload_nbytes(header)
-        resp = {'layout': handoff.layout, 'nbytes': nbytes,
+        resp = {'layout': 'paged', 'nbytes': nbytes,
                 'prompt_len': handoff.prompt_len,
                 'full_blocks': handoff.full_blocks,
                 'block': handoff.block}
@@ -1548,7 +1514,6 @@ class LlmServer:
                 len(data), disagg_lib.parse, data)
             disagg_lib.check_compat(
                 header, model=self.model_name, kv_cache=self.kv_cache,
-                kv_layout=self.kv_layout,
                 kv_block=getattr(self.engine, 'kv_block', 0),
                 max_len=self.max_len)
             # Inside the try: a header whose JSON parses but whose
@@ -1825,13 +1790,8 @@ def build_parser() -> argparse.ArgumentParser:
                         help='int8 = quantized KV cache, halves the '
                              'decode HBM stream (also via '
                              'SKYTPU_LLM_KV_CACHE)')
-    parser.add_argument('--kv-layout', default=None,
-                        choices=('slot', 'paged'),
-                        help='paged = vLLM-style block-table KV pool: '
-                             'requests reserve only their actual ask '
-                             '(also via SKYTPU_LLM_KV_LAYOUT)')
     parser.add_argument('--kv-blocks', type=int, default=None,
-                        help='paged pool size in blocks incl. the junk '
+                        help='KV pool size in blocks incl. the junk '
                              'sink (also via SKYTPU_LLM_KV_BLOCKS; '
                              'default = full capacity — size it BELOW '
                              'slots*max_len/block for the HBM saving; '
@@ -1839,18 +1799,12 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument('--prefix-share', default=None,
                         choices=('on', 'off'),
                         help='copy-on-write block-level prefix sharing '
-                             'on the paged KV pool: committed prompt '
-                             'blocks are refcount-shared via a trie, so '
-                             'a hit is a table write and only the '
-                             'unshared tail prefills (default on with '
-                             '--kv-layout paged; also via '
+                             'on the KV pool: committed prompt blocks '
+                             'are refcount-shared via a trie, so a hit '
+                             'is a table write and only the unshared '
+                             'tail prefills (default on; also via '
                              'SKYTPU_LLM_PREFIX_SHARE; dense models '
-                             'only)')
-    parser.add_argument('--prefix-cache', type=int, default=None,
-                        help='device pool slots for popular prompt '
-                             'prefixes (opt-in, default 0; costs N extra '
-                             'max_len cache rows of HBM; also via '
-                             'SKYTPU_LLM_PREFIX_CACHE; dense models only)')
+                             'only, and not with --draft-model)')
     parser.add_argument('--draft-model', default=None,
                         help='preset name of a small draft model for '
                              'speculative decoding (rides inside the '
@@ -1889,9 +1843,7 @@ def server_from_args(args) -> 'LlmServer':
     return LlmServer(args.model, max_len=args.max_len,
                      quantize=args.quantize, engine=args.engine,
                      tp=args.tp, kv_cache=args.kv_cache,
-                     prefix_cache=args.prefix_cache,
                      draft_model=args.draft_model,
-                     kv_layout=args.kv_layout,
                      kv_blocks=args.kv_blocks,
                      pipeline=args.pipeline,
                      qos=args.qos,

@@ -133,15 +133,15 @@ def _drive_engine(server, buckets: List[int], rnd: int) -> None:
     """One round of the steady-state mix through the continuous
     engine, three arrival patterns per prompt bucket because each
     compiles a DIFFERENT program set (rows are fresh every round, see
-    :func:`_row` — replaying prompts the prefix pool already holds
+    :func:`_row` — replaying prompts the prefix trie already holds
     would validate only the cached path):
 
     * **solo** (submit, wait) — a group-of-one prefill at the bucket's
       padded shape plus its KV insert: the shape sequential
       steady-state arrivals hit;
     * **concurrent duplicate pair** — the grouped-prefill shape AND
-      the second-sighting full-match path (prefix pool / block-share
-      trie serving a repeated prompt);
+      the second-sighting full-match path (the block-share trie
+      serving a repeated prompt);
     * **prefix truncation** (a shorter prefix of the solo row) — a
       PARTIAL trie hit: block fork + remainder prefill, the path a
       shared-prompt-plus-divergence workload compiles."""

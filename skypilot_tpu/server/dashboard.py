@@ -897,9 +897,9 @@ function healthCell(h){
   if(e){
     const parts = [`${(e.tokens_emitted||0).toLocaleString()} tok`,
                    `${e.active_slots??0}/${e.slots??'?'} slots`];
-    const pc = e.prefix_cache;
-    if(pc && pc.slots > 0 && (pc.hits + pc.stores) > 0)
-      parts.push(`pfx ${pc.hits} hit`);
+    const ps = e.prefix_share;
+    if(ps && ps.enabled && (ps.hits + ps.misses) > 0)
+      parts.push(`pfx ${ps.hits} hit`);
     const sp = e.speculative;
     if(sp && sp.rounds > 0)
       parts.push(`spec ${Math.round((sp.acceptance_rate||0)*100)}%`);

@@ -28,10 +28,17 @@ jax.config.update('jax_platforms', 'cpu')
 # agents, gangd, replicas all inherit the environment): the sessionfinish
 # sweep and `stpu doctor --reap` kill ONLY fingerprinted processes — a
 # name-pattern + ppid==1 match alone may be a user's live deployment (r3
-# advisor medium).
-os.environ.setdefault(
-    'SKYTPU_SESSION_FINGERPRINT',
-    f'pytest-{os.uname().nodename}-{os.getpid()}-{int(__import__("time").time())}')
+# advisor medium). An xdist worker inherits the controller's environment,
+# and with it the controller's fingerprint: each worker takes its own,
+# or the first worker to finish its session would sweep the live
+# daemons of the others (it did: the API servers of test_load.py and
+# test_users_rbac.py, whose command line matches 'skypilot_tpu.serve').
+_fingerprint = (f'pytest-{os.uname().nodename}-{os.getpid()}-'
+                f'{int(__import__("time").time())}')
+if os.environ.get('PYTEST_XDIST_WORKER'):
+    os.environ['SKYTPU_SESSION_FINGERPRINT'] = _fingerprint
+else:
+    os.environ.setdefault('SKYTPU_SESSION_FINGERPRINT', _fingerprint)
 
 # Keep black-box incident bundles out of the operator's real spool:
 # engine tests legitimately trip _fail_everything (stop with live work,

@@ -17,6 +17,7 @@ import os
 import pytest
 
 from skypilot_tpu.observability import blackbox
+from skypilot_tpu.observability import trace as trace_lib
 
 
 @pytest.fixture(autouse=True)
@@ -27,6 +28,9 @@ def _isolated_recorder(tmp_path, monkeypatch):
     monkeypatch.delenv('SKYTPU_BLACKBOX_KEEP', raising=False)
     blackbox.reset()
     blackbox.register_health_provider(None)
+    # A span another file's test left open in this worker would ride
+    # into every bundle's open traces.
+    trace_lib.reset()
     yield
     blackbox.reset()
     blackbox.register_health_provider(None)

@@ -28,19 +28,25 @@ def test_rehearsal_passes_end_to_end():
     assert r.returncode == 0, (r.stdout[-3000:], r.stderr[-3000:])
     assert all(l['rehearsal'] is True for l in lines if 'phase' in l)
     phases = {l['phase']: l for l in lines if 'phase' in l}
-    for name in ('devices', 'train-1chip', 'serve-slot', 'serve-paged',
-                 'kernels', 'kernels.serve-interpret', 'launch-local',
-                 'train-4chip-fsdp4', 'train-4chip-data2-tensor2',
-                 'serve-tp4'):
+    for name in ('devices', 'train-1chip', 'serve', 'kernels',
+                 'launch-local', 'train-4chip-fsdp4',
+                 'train-4chip-data2-tensor2', 'serve-tp4'):
         assert phases[name]['ok'] is True, phases[name]
         assert phases[name]['platform'] == 'cpu'
         assert phases[name]['device_count'] == 4
-    assert phases['serve-paged']['prefix_hits'] > 0
+    assert set(phases) == {'devices', 'train-1chip', 'serve', 'kernels',
+                           'launch-local', 'train-4chip-fsdp4',
+                           'train-4chip-data2-tensor2', 'serve-tp4'}
+    assert phases['serve']['prefix_hits'] > 0
+    for name in ('serve', 'serve-tp4'):   # one layout, read the same way
+        assert phases[name]['decode_attention'] == 'gather'   # the CPU's
     assert phases['launch-local']['status'] == 'SUCCEEDED'
     assert phases['launch-local']['framework_processes_left'] == []
     assert phases['launch-local']['gang_runner'] in ('native gangd',
                                                      'python')
-    assert len(phases['kernels']['cases']) >= 9
+    assert len(phases['kernels']['cases']) == 8
+    assert not any('flash_decode' in c['case']
+                   for c in phases['kernels']['cases'])
     assert lines[-1] == {'ok': True, 'rehearsal': True, 'device': {
         'platform': 'cpu', 'kind': 'cpu', 'count': 4}}
 

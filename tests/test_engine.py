@@ -226,110 +226,6 @@ def test_server_tp_quantized_params_born_sharded(tiny):
         server.engine.stop()
 
 
-def test_engine_prefix_cache_exact_on_repeat(tiny):
-    """Second sighting stores the prefix; the third request gathers it
-    and prefills only the suffix — output must stay EXACTLY the solo
-    generation (prefix KV reuse is exact by causality)."""
-    cfg, params = tiny
-    eng = _mk(params, cfg, prefix_slots=4)
-    try:
-        row = list(range(1, 25))  # 24 tokens -> cacheable 16-prefix
-        want = _solo(params, cfg, row, 5)
-        assert eng.submit(row, 5).result(timeout=120) == want  # seen #1
-        assert eng.submit(row, 5).result(timeout=120) == want  # stores
-        assert eng.submit(row, 5).result(timeout=120) == want  # hits
-        st = eng.stats()['prefix_cache']
-        assert st['stores'] >= 1 and st['entries'] >= 1
-        assert st['hits'] >= 1 and st['hit_tokens'] >= 16
-    finally:
-        eng.stop()
-
-
-def test_engine_prefix_cache_shared_prefix_variants(tiny):
-    """Different prompts sharing a popular 16-token prefix all hit the
-    pool and each still exactly matches its own solo generation."""
-    cfg, params = tiny
-    eng = _mk(params, cfg, prefix_slots=4)
-    try:
-        base = list(range(1, 17))  # exactly the bucket length
-        warm = base + [40]
-        eng.submit(warm, 3).result(timeout=120)
-        eng.submit(warm, 3).result(timeout=120)  # second sighting: store
-        assert eng.stats()['prefix_cache']['stores'] == 1
-        variants = [base + [50 + i, 60 + i] for i in range(3)]
-        futs = [eng.submit(v, 6) for v in variants]
-        for v, fut in zip(variants, futs):
-            assert fut.result(timeout=120) == _solo(params, cfg, v, 6), v
-        assert eng.stats()['prefix_cache']['hits'] >= 3
-    finally:
-        eng.stop()
-
-
-def test_engine_prefix_cache_eviction_and_reuse(tiny):
-    """One pool slot, two alternating prefixes: LRU eviction recycles
-    the slot and outputs stay exact throughout."""
-    cfg, params = tiny
-    eng = _mk(params, cfg, prefix_slots=1)
-    try:
-        a = list(range(1, 20))
-        b = list(range(100, 119))
-        for _ in range(2):
-            for row in (a, b):
-                assert (eng.submit(row, 4).result(timeout=120)
-                        == _solo(params, cfg, row, 4)), row
-        st = eng.stats()['prefix_cache']
-        assert st['entries'] == 1 and st['stores'] >= 2  # evict+restore
-    finally:
-        eng.stop()
-
-
-def test_engine_prefix_cache_with_kv_int8(tiny):
-    """Prefix rows carry quantized codes+scales verbatim, so reuse stays
-    exactly equal to the solo int8-KV generation."""
-    cfg, params = tiny
-    eng = _mk(params, cfg, prefix_slots=2, kv_quantize=True)
-    try:
-        row = list(range(3, 27))
-        want = np.asarray(generate.generate(
-            params, cfg, jnp.asarray([row], jnp.int32), max_new_tokens=5,
-            max_len=64, kv_quantize=True)[0]).tolist()
-        for _ in range(3):
-            assert eng.submit(row, 5).result(timeout=120) == want
-        assert eng.stats()['prefix_cache']['hits'] >= 1
-    finally:
-        eng.stop()
-
-
-def test_engine_prefix_demotion_near_max_len(tiny):
-    """A hit whose padded suffix would overflow the cache width is
-    demoted to a full prefill (clamped writes would corrupt the prefix
-    KV) — output stays exact."""
-    cfg, params = tiny
-    eng = _mk(params, cfg, prefix_slots=2)  # max_len 64
-    try:
-        short = list(range(1, 18))  # stores the 16-prefix
-        eng.submit(short, 3).result(timeout=120)
-        eng.submit(short, 3).result(timeout=120)
-        long_row = short[:16] + list(range(200, 246))  # len 62
-        want = _solo(params, cfg, long_row, 2)
-        assert eng.submit(long_row, 2).result(timeout=120) == want
-        # 16 + bucket(46)=64 > 64: the hit was demoted, not used.
-        assert eng.stats()['prefix_cache']['hit_tokens'] == 0
-    finally:
-        eng.stop()
-
-
-def test_engine_prefix_cache_disabled_for_moe(tiny_moe):
-    """MoE expert capacity couples co-batched rows, so stored prefix KV
-    would replay store-time contention — the engine must refuse the
-    pool for MoE configs even when explicitly requested."""
-    cfg, params = tiny_moe
-    eng = engine_lib.ContinuousEngine(params, cfg, slots=2, max_len=32,
-                                      prefix_slots=4)
-    assert eng.prefix_slots == 0
-    assert eng._prefix_pool is None
-
-
 def test_engine_kv_int8_matches_generate_kv_int8(tiny):
     """Engine with the int8 KV cache: same quantization recipe at write
     time as generate(kv_quantize=True), so outputs are exactly equal —
@@ -874,29 +770,6 @@ def test_engine_chunked_prefill_disabled_for_moe(tiny_moe):
     eng = engine_lib.ContinuousEngine(params, cfg, slots=2, max_len=32,
                                       prefill_chunk=8)
     assert eng.prefill_chunk == 0
-
-
-def test_engine_chunked_prefill_with_prefix_cache(tiny):
-    """A long prompt whose head is pooled seeds its incremental prefill
-    from the pool (fewer chunks) and stays exact; completion stores the
-    prompt's own bucket prefix for future hits."""
-    cfg, params = tiny
-    eng = _mk(params, cfg, prefill_chunk=8, prefix_slots=4)
-    try:
-        long_row = list(range(1, 41))  # 40 tokens
-        want = _solo(params, cfg, long_row, 4)
-        assert eng.submit(long_row, 4).result(timeout=120) == want
-        assert eng.submit(long_row, 4).result(timeout=120) == want
-        # Second sighting stored the 32-token bucket prefix...
-        assert eng.stats()['prefix_cache']['stores'] >= 1
-        chunks_before = eng.stats()['prefill_chunks']
-        assert eng.submit(long_row, 4).result(timeout=120) == want
-        # ...so the third prefill seeded from it: 40-32=8 tokens = 1
-        # chunk instead of 5.
-        assert eng.stats()['prefill_chunks'] - chunks_before == 1
-        assert eng.stats()['prefix_cache']['hits'] >= 1
-    finally:
-        eng.stop()
 
 
 def test_llm_server_graceful_drain(tmp_path):

@@ -7,8 +7,8 @@ overlaps device compute. The contract pinned here: greedy output is
 BYTE-IDENTICAL to the serial engine (and to the solo generate()
 oracle) under every scheduling hazard pipelining introduces —
 EOS-mid-chunk, slot reuse after EOS, and ``_drain_firsts`` racing an
-in-flight chunk — for both the dense ('slot') and 'paged' KV layouts;
-and the new overlap stats actually move.
+in-flight chunk — at KV block lengths 16 and 8; and the new overlap
+stats actually move.
 """
 import dataclasses
 import time
@@ -21,7 +21,7 @@ import pytest
 from skypilot_tpu.models import engine as engine_lib
 from skypilot_tpu.models import generate, llama
 
-LAYOUTS = ('slot', 'paged')
+BLOCKS = (16, 8)  # KV block lengths
 
 
 @pytest.fixture(scope='module')
@@ -72,17 +72,17 @@ def test_pipelined_default_greedy_matches_oracle_and_reports_overlap(
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize('layout', LAYOUTS)
-def test_pipelined_stream_byte_identical_to_serial(tiny, layout):
+@pytest.mark.parametrize('block', BLOCKS)
+def test_pipelined_stream_byte_identical_to_serial(tiny, block):
     """The headline equivalence: the same greedy traffic through a
     pipelined and a serial engine yields byte-identical per-request
-    token streams (both equal the oracle), dense and paged alike."""
+    token streams (both equal the oracle), at either block length."""
     cfg, params = tiny
     rows = [[5, 6, 7], [8, 9, 10, 11, 12], [13, 14],
             [15, 16, 17, 18], [19, 20, 21], [3, 4]]
     results = {}
     for pipe in (True, False):
-        eng = _mk(params, cfg, chunk_steps=2, kv_layout=layout,
+        eng = _mk(params, cfg, chunk_steps=2, kv_block=block,
                   pipeline=pipe)
         assert eng.pipeline_depth == (1 if pipe else 0)
         try:
@@ -96,14 +96,14 @@ def test_pipelined_stream_byte_identical_to_serial(tiny, layout):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize('layout', LAYOUTS)
-def test_pipelined_eos_mid_chunk_and_slot_reuse(tiny, layout):
+@pytest.mark.parametrize('block', BLOCKS)
+def test_pipelined_eos_mid_chunk_and_slot_reuse(tiny, block):
     """EOS lands mid-chunk while the NEXT chunk is already in flight:
     the stream truncates at the stop id, the in-flight chunk's junk for
     the freed slot is dropped, and the slot is immediately reusable —
     the reuse insert overwrites the junk-advanced lengths."""
     cfg, params = tiny
-    eng = _mk(params, cfg, slots=1, chunk_steps=2, kv_layout=layout)
+    eng = _mk(params, cfg, slots=1, chunk_steps=2, kv_block=block)
     try:
         row = [5, 6, 7]
         solo = _solo(params, cfg, row, 10)
@@ -124,13 +124,13 @@ def test_pipelined_eos_mid_chunk_and_slot_reuse(tiny, layout):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize('layout', LAYOUTS)
-def test_pipelined_drain_firsts_race(tiny, layout):
+@pytest.mark.parametrize('block', BLOCKS)
+def test_pipelined_drain_firsts_race(tiny, block):
     """_drain_firsts resolving a first-token-eos request races the
     in-flight chunk (which was dispatched with that slot active): the
     delivered list must stay [first], and the slot must be reusable."""
     cfg, params = tiny
-    eng = _mk(params, cfg, slots=1, kv_layout=layout)
+    eng = _mk(params, cfg, slots=1, kv_block=block)
     try:
         row = [5, 6, 7]
         first = _solo(params, cfg, row, 1)[0]

@@ -38,7 +38,6 @@ def _mk(params, cfg, **kw):
     kw.setdefault('slots', 4)
     kw.setdefault('max_len', 64)
     kw.setdefault('chunk_steps', 2)
-    kw.setdefault('kv_layout', 'paged')
     eng = engine_lib.ContinuousEngine(params, cfg, **kw)
     eng.start()
     return eng
@@ -293,15 +292,15 @@ def test_share_disabled_for_moe_and_spec(tiny):
     cfg, params = tiny
     moe = engine_lib.ContinuousEngine(
         llama.init_params(jax.random.PRNGKey(1), llama.MOE_TINY),
-        llama.MOE_TINY, kv_layout='paged', slots=2, max_len=32)
+        llama.MOE_TINY, slots=2, max_len=32)
     assert not moe.prefix_share
     spec = engine_lib.ContinuousEngine(
-        params, cfg, kv_layout='paged', slots=2, max_len=64,
+        params, cfg, slots=2, max_len=64,
         draft_params=params, draft_cfg=cfg)
     assert not spec.prefix_share
-    slot_layout = engine_lib.ContinuousEngine(params, cfg,
-                                              slots=2, max_len=64)
-    assert not slot_layout.prefix_share
+    # A dense target without a draft shares with no keyword at all.
+    plain = engine_lib.ContinuousEngine(params, cfg, slots=2, max_len=64)
+    assert plain.prefix_share and plain._trie is not None
 
 
 def test_stats_surface_share_counters(tiny):

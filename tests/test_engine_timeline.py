@@ -65,7 +65,7 @@ def _grouped(params, cfg):
 
 
 def _shared(params, cfg):
-    eng = _mk(params, cfg, kv_layout='paged', kv_block=16, max_len=96,
+    eng = _mk(params, cfg, kv_block=16, max_len=96,
               prefix_share=True, kv_tiers=False)
     try:
         head = _row(32)
@@ -126,7 +126,7 @@ def _export_then_import(params, cfg):
         out = pre.submit_prefill(row, 8)
         h = out.result(timeout=120)
         assert out.timeline.path == 'group'
-        got = dec.submit_import(row, 8, h.first, layout=h.layout, k=h.k,
+        got = dec.submit_import(row, 8, h.first, k=h.k,
                                 v=h.v)
         assert len(got.result(timeout=120)) == 8
         assert got.timeline.path == 'import'
@@ -214,7 +214,7 @@ def test_the_loop_opens_its_spans_properly_nested(tiny, monkeypatch):
     cfg, params = tiny
     rec = _Recorder()
     monkeypatch.setattr(profiler, 'span', rec)
-    eng = _mk(params, cfg, kv_layout='paged', kv_block=16, max_len=96,
+    eng = _mk(params, cfg, kv_block=16, max_len=96,
               prefix_share=True, kv_tiers=False, prefill_chunk=40)
     try:
         head = _row(32)

@@ -5,10 +5,7 @@ sharded kernel must equal the jnp reference — forward and gradients —
 and the train loss must come out the same through it."""
 import dataclasses
 import functools
-import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -89,33 +86,6 @@ def test_train_loss_through_the_sharded_kernel(monkeypatch):
         llama.loss_fn, cfg=cfg, mesh=mesh, rules=RULES))(params, tokens)
     assert seen and all(s is not None and s[0] is mesh for s in seen)
     np.testing.assert_allclose(float(got), float(want), rtol=2e-2)
-
-
-def test_decode_geometry_fallback_is_said_once(monkeypatch, caplog):
-    """A cache the decode kernel cannot take (M not a multiple of 128)
-    takes the einsum path on a trace-time condition, logged once per
-    shape — never silently."""
-    monkeypatch.setattr(gen_lib, '_DECODE_KERNEL', 'interpret')
-    monkeypatch.setattr(attention, '_logged_fallbacks', set())
-    b, hq, hkv, m, d = 2, 4, 2, 96, 16
-    q = jnp.ones((b, 1, hq, d))
-    cache = jnp.ones((b, hkv, m, d))
-    lengths = jnp.asarray([5, 96], jnp.int32)
-    with caplog.at_level('WARNING', logger=attention.__name__):
-        for _ in range(2):
-            gen_lib._cached_attention(q, cache, cache,
-                                      (lengths - 1)[:, None], lengths)
-    tagged = [r.getMessage() for r in caplog.records
-              if attention.FALLBACK_TAG in r.getMessage()]
-    assert len(tagged) == 1 and 'flash_decode' in tagged[0]
-
-
-def test_decode_kernel_value_is_validated():
-    r = subprocess.run(
-        [sys.executable, '-c', 'import skypilot_tpu.models.generate'],
-        env={**os.environ, 'SKYTPU_DECODE_KERNEL': 'on'},
-        capture_output=True, text=True, timeout=120)
-    assert r.returncode != 0 and "'pallas' or 'interpret'" in r.stderr
 
 
 def test_the_three_kernels_carry_their_names_into_the_program():
@@ -217,8 +187,7 @@ def test_tp_paged_engine_decodes_through_the_sharded_kernel(monkeypatch):
     def run(**kw):
         engine_lib._jit_paged_chunk.clear_cache()
         eng = engine_lib.ContinuousEngine(
-            params, cfg, slots=2, max_len=64, chunk_steps=2,
-            kv_layout='paged', **kw)
+            params, cfg, slots=2, max_len=64, chunk_steps=2, **kw)
         eng.start()
         try:
             futs = [eng.submit(r, 6) for r in rows]

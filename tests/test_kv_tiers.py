@@ -295,8 +295,7 @@ def test_engine_corrupt_spill_degrades_to_recompute(tiny, tmp_path,
         return np.asarray(out[0]).tolist()
 
     eng = engine_lib.ContinuousEngine(params, cfg, slots=4, max_len=64,
-                                      chunk_steps=2, kv_layout='paged',
-                                      kv_blocks=5)
+                                      chunk_steps=2, kv_blocks=5)
     eng.start()
     try:
         heads = [[((17 * h + j) % 250) + 1 for j in range(24)]

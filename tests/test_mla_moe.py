@@ -73,7 +73,10 @@ def _paged_run(params, pcfg, toks, n_prompt, monkeypatch=None):
         p, t, c, pcfg, active_rows=jnp.asarray([False, True])))
     for i in range(n_prompt, len(toks)):
         last[1] = toks[i]
-        logits, pool, load = step(params, jnp.asarray(last)[:, None], pool)
+        # A copy: the CPU backend may alias a numpy buffer and run the
+        # step after the next turn has already rewritten ``last``.
+        logits, pool, load = step(params, jnp.asarray(last.copy())[:, None],
+                                  pool)
         out.append(logits[1])
     return jnp.stack(out), load
 
@@ -301,9 +304,9 @@ def test_the_engine_serves_shared_prefixes_and_forks_of_the_latent_pool():
     reference's pick; the counters the benchmark reads are there."""
     cfg, fam, params, pcfg = setup()
     eng = engine_lib.ContinuousEngine(
-        params, pcfg, slots=4, max_len=96, kv_layout='paged', kv_blocks=25,
+        params, pcfg, slots=4, max_len=96, kv_blocks=25,
         kv_block=16, prefill_batch=2, chunk_steps=4, prefix_share=True,
-        kv_tiers=False, kv_quantize=False, prefix_slots=0)
+        kv_tiers=False, kv_quantize=False)
     try:
         assert eng.pipeline_depth == 1 and eng.prefix_share
         base = tokens(50, seed=11)
@@ -334,15 +337,13 @@ def test_the_engine_serves_shared_prefixes_and_forks_of_the_latent_pool():
 @pytest.mark.parametrize('kwargs, feature', [
     (dict(kv_quantize=True), 'kv_quantize'),
     (dict(kv_tiers=True), 'kv_tiers'),
-    (dict(kv_layout='slot'), 'kv_layout=slot'),
-    (dict(prefix_slots=2), 'prefix_slots'),
     (dict(prefill_chunk=64), 'prefill_chunk'),
     (dict(draft_params={}, draft_cfg=llama.TINY), 'speculative decoding')],
     ids=lambda v: v if isinstance(v, str) else '')
 def test_what_the_latent_cache_does_not_do_is_refused_by_name(kwargs,
                                                               feature):
     _, _, params, pcfg = setup()
-    base = dict(slots=2, max_len=64, kv_layout='paged', kv_blocks=9)
+    base = dict(slots=2, max_len=64, kv_blocks=9)
     with pytest.raises(ValueError, match=feature):
         engine_lib.ContinuousEngine(params, pcfg, **dict(base, **kwargs))
 
@@ -350,7 +351,7 @@ def test_what_the_latent_cache_does_not_do_is_refused_by_name(kwargs,
 def test_handoff_is_refused_and_tiers_default_off_for_the_latent_cache():
     _, _, params, pcfg = setup()
     eng = engine_lib.ContinuousEngine(params, pcfg, slots=2, max_len=64,
-                                      kv_layout='paged', kv_blocks=9)
+                                      kv_blocks=9)
     try:
         assert eng._kv_tiers is None and eng.prefix_share
         with pytest.raises(ValueError, match='KV handoff'):

@@ -17,7 +17,7 @@ def test_paged_engine_with_head_dim_unlike_the_block():
     cfg = dataclasses.replace(llama.TINY, head_dim=32)
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     eng = engine_lib.ContinuousEngine(params, cfg, slots=2, max_len=64,
-                                      chunk_steps=4, kv_layout='paged')
+                                      chunk_steps=4)
     assert eng.kv_block != cfg.head_dim
     eng.start()
     try:

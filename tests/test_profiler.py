@@ -40,15 +40,15 @@ def test_programs_registry_bounded_and_unique():
 
 
 def test_unknown_program_name_rejected_with_hint():
-    with pytest.raises(ValueError, match='engine.chunk'):
+    with pytest.raises(ValueError, match='engine.paged_chunk'):
         # skylint: allow-jit(the typo is the thing under test)
-        profiler.profiled_jit('engine.chnk', lambda x: x)
+        profiler.profiled_jit('engine.paged_chnk', lambda x: x)
 
 
 def test_budget_overrides_parse(monkeypatch):
     monkeypatch.setenv('SKYTPU_PROFILE_BUDGETS',
-                       'engine.chunk=2, generate.prefill=1,junk,x=')
-    assert profiler.budget_for('engine.chunk') == 2
+                       'engine.paged_chunk=2, generate.prefill=1,junk,x=')
+    assert profiler.budget_for('engine.paged_chunk') == 2
     assert profiler.budget_for('generate.prefill') == 1
     # Undeclared overrides are inert; unset programs keep registry
     # budgets.
@@ -74,18 +74,18 @@ def test_compile_counted_once_per_shape(profiling):
 
 
 def test_storm_fires_at_budget_plus_one(profiling, monkeypatch):
-    monkeypatch.setenv('SKYTPU_PROFILE_BUDGETS', 'engine.chunk=2')
-    f = profiler.profiled_jit('engine.chunk', lambda x: x + 1)
+    monkeypatch.setenv('SKYTPU_PROFILE_BUDGETS', 'engine.paged_chunk=2')
+    f = profiler.profiled_jit('engine.paged_chunk', lambda x: x + 1)
     for n in (2, 3):  # within budget: no storm
         f(jnp.ones((n,)))
     assert profiler.snapshot()['storms_total'] == 0
     f(jnp.ones((4,)))  # budget+1: storm
     snap = profiler.snapshot()
-    assert snap['compile']['engine.chunk']['storms'] == 1
+    assert snap['compile']['engine.paged_chunk']['storms'] == 1
     assert snap['storms_total'] == 1
     storms = [e for e in blackbox.events()
               if e['name'] == 'profiler.storm']
-    assert storms and storms[-1]['attrs']['program'] == 'engine.chunk'
+    assert storms and storms[-1]['attrs']['program'] == 'engine.paged_chunk'
     assert storms[-1]['attrs']['budget'] == 2
 
 
@@ -142,7 +142,7 @@ def test_engine_registers_logical_kv_vs_block_accounting(profiling):
     cfg = llama.TINY
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     eng = engine_lib.ContinuousEngine(params, cfg, slots=2, max_len=64,
-                                      kv_layout='paged', kv_block=16)
+                                      kv_block=16)
     try:
         logical = profiler.logical_bytes()
         stats = eng.stats()['kv_blocks']

@@ -1,7 +1,7 @@
 """Disaggregated prefill/decode serving (serve/disagg.py).
 
 Pins the subsystem's contracts: greedy output byte-identical colocated
-vs disaggregated (dense + paged layouts, with and without
+vs disaggregated (two block lengths, with and without
 prefix-share-negotiated transfers), corrupt/truncated handoff payloads
 rejected BEFORE any device install with the LB falling back to
 colocated serving, decode-pool admission backpressure on imported
@@ -54,8 +54,7 @@ def _handoff_bytes(pre, row, max_new, skip_blocks=0, **hkw):
 def _import_tokens(dec, data, max_len=96):
     header, arrays = disagg.parse(data)
     disagg.check_compat(header, model='tiny', kv_cache='bf16',
-                        kv_layout=dec.kv_layout,
-                        kv_block=getattr(dec, 'kv_block', 0),
+                        kv_block=dec.kv_block,
                         max_len=max_len)
     return dec.submit_import(
         **disagg.import_kwargs(header, arrays)).result(timeout=300)
@@ -64,20 +63,20 @@ def _import_tokens(dec, data, max_len=96):
 # -- engine-level byte parity ------------------------------------------------
 
 
-@pytest.mark.parametrize('layout', ['slot', 'paged'])
-def test_greedy_parity_colocated_vs_disaggregated(tiny_params, layout):
+@pytest.mark.parametrize('block', [16, 8])
+def test_greedy_parity_colocated_vs_disaggregated(tiny_params, block):
     """The headline contract: a prompt prefilled on one engine,
     exported, transferred, imported on another, decodes to EXACTLY the
-    tokens a colocated engine produces — on both KV layouts."""
-    colo = _engine(tiny_params, kv_layout=layout)
-    pre = _engine(tiny_params, role='prefill', kv_layout=layout)
-    dec = _engine(tiny_params, role='decode', kv_layout=layout)
+    tokens a colocated engine produces — at either block length."""
+    colo = _engine(tiny_params, kv_block=block)
+    pre = _engine(tiny_params, role='prefill', kv_block=block)
+    dec = _engine(tiny_params, role='decode', kv_block=block)
     try:
         for n, max_new, salt in ((13, 12, 0), (33, 16, 1), (1, 8, 2)):
             row = _row(n, salt)
             want = colo.submit(row, max_new).result(timeout=300)
             got = _import_tokens(dec, _handoff_bytes(pre, row, max_new))
-            assert list(got) == list(want), (layout, n, got, want)
+            assert list(got) == list(want), (block, n, got, want)
         assert pre.exports == 3 and pre.imports == 0
         assert dec.imports == 3 and dec.exports == 0
         assert pre.stats()['disagg']['exports'] == 3
@@ -92,9 +91,9 @@ def test_paged_parity_with_prefix_share_negotiation(tiny_params):
     trie already holds the prompt's leading blocks, the transfer skips
     them (probe_chain -> skip_blocks -> block_start import) and greedy
     output is STILL byte-identical; the skipped payload is smaller."""
-    colo = _engine(tiny_params, kv_layout='paged')
-    pre = _engine(tiny_params, role='prefill', kv_layout='paged')
-    dec = _engine(tiny_params, role='decode', kv_layout='paged',
+    colo = _engine(tiny_params)
+    pre = _engine(tiny_params, role='prefill')
+    dec = _engine(tiny_params, role='decode',
                   prefix_share=True)
     try:
         p = dec.kv_block
@@ -128,9 +127,9 @@ def test_paged_parity_with_full_chain_shared(tiny_params):
     None; the install is a pure table write) and greedy output is still
     byte-identical (review finding: this path used to crash the engine
     thread on entry.k.dtype)."""
-    colo = _engine(tiny_params, kv_layout='paged')
-    pre = _engine(tiny_params, role='prefill', kv_layout='paged')
-    dec = _engine(tiny_params, role='decode', kv_layout='paged',
+    colo = _engine(tiny_params)
+    pre = _engine(tiny_params, role='prefill')
+    dec = _engine(tiny_params, role='decode',
                   prefix_share=True)
     try:
         p = dec.kv_block
@@ -156,8 +155,8 @@ def test_shape_skewed_payload_rejected_before_enqueue(tiny_params):
     rejected SYNCHRONOUSLY at submit_import — an install raising on the
     engine thread would fail every in-flight request — and the engine
     keeps serving afterward."""
-    pre = _engine(tiny_params, role='prefill', kv_layout='paged')
-    dec = _engine(tiny_params, role='decode', kv_layout='paged')
+    pre = _engine(tiny_params, role='prefill')
+    dec = _engine(tiny_params, role='decode')
     try:
         data = _handoff_bytes(pre, _row(13, 9), 8)
         header, arrays = disagg.parse(data)
@@ -184,8 +183,8 @@ def test_import_rejected_when_negotiated_blocks_evicted(tiny_params):
     with KVImportError — the serving layer's 409/fallback signal —
     instead of decoding from junk KV."""
     from skypilot_tpu.models.engine import KVImportError
-    pre = _engine(tiny_params, role='prefill', kv_layout='paged')
-    dec = _engine(tiny_params, role='decode', kv_layout='paged',
+    pre = _engine(tiny_params, role='prefill')
+    dec = _engine(tiny_params, role='decode',
                   prefix_share=True)
     try:
         p = dec.kv_block
@@ -210,7 +209,7 @@ def test_import_rejected_when_negotiated_blocks_evicted(tiny_params):
 
 
 def test_corrupt_and_truncated_payloads_rejected(tiny_params):
-    pre = _engine(tiny_params, role='prefill', kv_layout='paged')
+    pre = _engine(tiny_params, role='prefill')
     try:
         data = _handoff_bytes(pre, _row(13, 7), 8)
         header, _ = disagg.parse(data)  # baseline: parses clean
@@ -227,13 +226,43 @@ def test_corrupt_and_truncated_payloads_rejected(tiny_params):
             disagg.parse(b'NOTAKVMAGIC' + data[11:])
         # Well-formed but wrong replica: compat errors, not format.
         for kw in (dict(model='other'), dict(kv_cache='int8'),
-                   dict(kv_layout='slot'), dict(kv_block=999),
-                   dict(max_len=10)):
-            full = dict(model='tiny', kv_cache='bf16', kv_layout='paged',
+                   dict(kv_block=999), dict(max_len=10)):
+            full = dict(model='tiny', kv_cache='bf16',
                         kv_block=header['block'], max_len=96)
             full.update(kw)
             with pytest.raises(disagg.DisaggCompatError):
                 disagg.check_compat(header, **full)
+    finally:
+        pre.stop()
+
+
+@pytest.mark.parametrize('theirs', ['slot', 'dense', None])
+def test_a_header_of_another_layout_is_refused_by_name(theirs):
+    """The wire format keeps its ``layout`` field: a prefill replica of
+    a version that still had the slot layout (a rolling upgrade) is a
+    compat error that names the field and both values, and so a
+    colocated fallback at the LB, never an install."""
+    ours = dict(model='tiny', kv_cache='bf16', kv_block=16, max_len=96)
+    header = {'format': disagg.FORMAT, 'model': 'tiny', 'kv_cache': 'bf16',
+              'layout': 'paged', 'block': 16, 'row': [1, 2, 3],
+              'max_new': 4}
+    disagg.check_compat(header, **ours)
+    with pytest.raises(disagg.DisaggCompatError) as exc:
+        disagg.check_compat(dict(header, layout=theirs), **ours)
+    assert 'layout' in str(exc.value) and 'paged' in str(exc.value)
+    assert repr(theirs) in str(exc.value)
+
+
+def test_the_header_still_says_paged_and_the_import_takes_no_layout(
+        tiny_params):
+    pre = _engine(tiny_params, role='prefill')
+    try:
+        h = pre.submit_prefill(_row(21, 3), 4).result(timeout=300)
+        assert not hasattr(h, 'layout') and h.n_blocks == 2
+        header = disagg.build_header(h, model='tiny', kv_cache='bf16')
+        assert header['layout'] == 'paged' and header['block'] == 16
+        _, arrays = disagg.parse(disagg.serialize_bytes(h, header))
+        assert 'layout' not in disagg.import_kwargs(header, arrays)
     finally:
         pre.stop()
 
@@ -249,8 +278,7 @@ def test_registry_ttl_and_staging_roundtrip(tmp_path):
     assert reg.expired >= 1
 
     class _Fake:
-        layout = 'slot'
-        n_blocks = 0
+        n_blocks = 1
         k_s = None
 
     import numpy as np
@@ -258,8 +286,8 @@ def test_registry_ttl_and_staging_roundtrip(tmp_path):
     fake.k = np.arange(12, dtype=np.float32).reshape(1, 1, 1, 3, 4)
     fake.v = fake.k + 1
     header = {'format': disagg.FORMAT, 'planes': [
-        {'name': n, 'block': None, 'dtype': 'float32',
-         'shape': [1, 1, 1, 3, 4], 'nbytes': 48,
+        {'name': n, 'block': 0, 'dtype': 'float32',
+         'shape': [1, 1, 3, 4], 'nbytes': 48,
          'crc32': __import__('zlib').crc32(arr.tobytes()) & 0xFFFFFFFF}
         for n, arr in (('k', fake.k), ('v', fake.v))]}
     ref, nbytes = disagg.write_staging(str(tmp_path), fake, header)
@@ -283,12 +311,12 @@ def test_import_backpressure_on_kv_blocks(tiny_params):
     """An imported prompt whose block reservation does not fit QUEUES
     (visible as the queued_imports autoscaler signal) instead of
     crashing or stealing blocks, and admits once the pool frees."""
-    pre = _engine(tiny_params, role='prefill', kv_layout='paged')
+    pre = _engine(tiny_params, role='prefill')
     # 9 usable blocks (10 minus the junk sink): one 32+64 request needs
     # 6, so a second identical-footprint import must wait.
-    dec = _engine(tiny_params, role='decode', kv_layout='paged',
+    dec = _engine(tiny_params, role='decode',
                   kv_blocks=10, prefix_share=False)
-    colo = _engine(tiny_params, kv_layout='paged')
+    colo = _engine(tiny_params)
     try:
         row_a, row_b = _row(32, 8), _row(32, 9)
         want_a = colo.submit(row_a, 64).result(timeout=300)
@@ -356,7 +384,7 @@ def disagg_fleet():
     from skypilot_tpu.utils import common_utils
     os.environ.pop(disagg.STAGING_ENV, None)
     servers = {
-        role: llm_mod.LlmServer('tiny', max_len=96, kv_layout='paged',
+        role: llm_mod.LlmServer('tiny', max_len=96,
                                 role=role)
         for role in ('prefill', 'decode', 'colocated')}
     eps = {role: _start_http(s, 23900 + 20 * i)
@@ -417,7 +445,7 @@ def test_http_export_respects_qos_admission(tiny_params, monkeypatch):
     monkeypatch.setenv('SKYTPU_QOS', '1')
     # rate ~0, burst floor 1.0: exactly one export is admitted.
     monkeypatch.setenv('SKYTPU_QOS_TENANT_RPS', '0.001')
-    server = llm_mod.LlmServer('tiny', max_len=96, kv_layout='paged',
+    server = llm_mod.LlmServer('tiny', max_len=96,
                                role='prefill')
     ep = _start_http(server, 24300)
     try:
@@ -503,7 +531,7 @@ def _midstream_kill_attempt(salt: int, port_base: int):
     from skypilot_tpu.utils import common_utils
     os.environ.pop(disagg.STAGING_ENV, None)
     servers = {
-        role: llm_mod.LlmServer('tiny', max_len=160, kv_layout='paged',
+        role: llm_mod.LlmServer('tiny', max_len=160,
                                 role=role)
         for role in ('prefill', 'decode', 'colocated')}
     # Per-token emission lines: the more lines, the wider the window
@@ -615,7 +643,7 @@ def test_http_staging_fast_path(tiny_params, tmp_path, monkeypatch):
     from skypilot_tpu.utils import common_utils
     monkeypatch.setenv(disagg.STAGING_ENV, str(tmp_path))
     servers = {
-        role: llm_mod.LlmServer('tiny', max_len=96, kv_layout='paged',
+        role: llm_mod.LlmServer('tiny', max_len=96,
                                 role=role)
         for role in ('prefill', 'decode')}
     eps = {role: _start_http(s, 24500 + 20 * i)

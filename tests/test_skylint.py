@@ -206,7 +206,7 @@ def test_pr7_regression_reintroduced_is_caught(tmp_path):
     lines = src.splitlines(keepends=True)
     at = next(i for i, ln in enumerate(lines) if marker in ln)
     body = next(i for i in range(at + 1, len(lines))
-                if lines[i].strip().startswith('from skypilot_tpu'))
+                if lines[i].strip() == 'req = entry.req')
     lines.insert(body + 1, (
         '        if entry.k is not None and entry.k.shape[0] != '
         'self.cfg.n_layers:\n'
@@ -606,18 +606,18 @@ def test_profiled_jit_typo_gets_did_you_mean(tmp_path):
         def _impl(x):
             return x
 
-        _f = profiled_jit('engine.chunks', _impl)
+        _f = profiled_jit('engine.paged_chunks', _impl)
         ''')
     findings = jit_mod.JitPrograms().check_file(sf)
     assert _rules(findings) == ['jit-program']
-    assert "'engine.chunk'" in findings[0].message  # did-you-mean
+    assert "'engine.paged_chunk'" in findings[0].message  # did-you-mean
     ok = _sf(tmp_path, '''
         from skypilot_tpu.observability.profiler import profiled_jit
 
         def _impl(x):
             return x
 
-        _f = profiled_jit('engine.chunk', _impl)
+        _f = profiled_jit('engine.paged_chunk', _impl)
         ''', name='ok.py')
     assert jit_mod.JitPrograms().check_file(ok) == []
 
@@ -627,7 +627,7 @@ def test_profiled_jit_dynamic_name_flagged(tmp_path):
     sf = _sf(tmp_path, '''
         from skypilot_tpu.observability.profiler import profiled_jit
 
-        NAME = 'engine.chunk'
+        NAME = 'engine.paged_chunk'
 
         def _impl(x):
             return x

@@ -799,7 +799,7 @@ def _spawn_replica(role: str, port: int, workdir: str,
     proc = subprocess.Popen(
         [sys.executable, '-m', 'skypilot_tpu.serve.llm_server',
          '--model', 'tiny', '--max-len', str(max_len),
-         '--kv-layout', 'paged', '--role', role,
+         '--role', role,
          '--host', '127.0.0.1', '--port', str(port)]
         + list(extra_args or ()),
         cwd=_REPO_ROOT, env=env, stdout=log, stderr=log)
@@ -2277,7 +2277,7 @@ def profile_probe() -> dict:
         assert dbg['enabled'] is True
         assert dbg['compile']['generate.prefill']['compiles'] >= 1
         assert {p['name'] for p in dbg['programs']} >= {
-            'generate.prefill', 'engine.chunk', 'paged.insert'}
+            'generate.prefill', 'engine.paged_chunk', 'paged.insert'}
         return {
             'cold_start_wall_s': round(wall, 2),
             'cold_start_ledger_s': cold['total_s'],
