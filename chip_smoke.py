@@ -20,7 +20,8 @@ line; any phase not ``ok`` makes the exit code non-zero):
                 defaults, driven by python -m skypilot_tpu.serve.loadgen,
                 one greedy request three times (past the first the prefix
                 trie serves its prompt), plus a shared-prefix hit; decode
-                through paged_decode; SIGTERM -> drain -> exit 0.
+                through paged_decode (which writes the step's K/V row
+                too); SIGTERM -> drain -> exit 0.
   kernels       each Pallas kernel compiled (not interpreted) against its
                 jnp reference (paged_decode and mla_decode at the benchmark
                 cells' pool geometries) and the train step's HLO searched
@@ -681,8 +682,9 @@ def child_kernels(rehearse: bool, meshes) -> int:
     def pool_layout(slots, blocks, block, max_blocks):
         """(valid [slots], tables [slots, max_blocks]) of a pool as the
         engine leaves it: live rows of every length class, every other
-        slot empty, a first block shared by two rows, tables padded
-        with the junk sink."""
+        slot empty, a full first block shared by two rows (both append
+        elsewhere: the engine forks a shared tail), tables padded with
+        the junk sink."""
         max_len = max_blocks * block
         valid = np.zeros((slots,), np.int32)
         lens = [1, block - 1, block, block + 1, max_len // 2 + 3, max_len]
@@ -693,42 +695,58 @@ def child_kernels(rehearse: bool, meshes) -> int:
         for slot in range(slots):
             n = -(-int(valid[slot]) // block)
             tables[slot, :n] = [next(free) for _ in range(n)]
-        tables[2, 0] = tables[0, 0]
+        past_first = np.flatnonzero(valid > block)
+        if len(past_first) > 1:
+            tables[past_first[1], 0] = tables[past_first[0], 0]
         return valid, tables
 
     def paged_case(slots, blocks, block, max_blocks, hq, hkv, d, layers=2):
-        """``paged_decode`` over layer 1 of pools laid out as the engine
-        leaves them (live rows of every length class, empty slots, a
-        prefix shared by two rows, tables padded with the junk sink)
-        against the gather + einsum path on the same pools."""
+        """``paged_decode`` (the step's row written into layer 1 of the
+        pools, then attended) over pools laid out as the engine leaves
+        them (live rows of every length class, empty slots, a prefix
+        shared by two rows, tables padded with the junk sink) against
+        the row scatter + gather + einsum path on the same pools: the
+        output within ``tol``, the pools to the bit."""
         from skypilot_tpu.models import paged as paged_lib
         key = jax.random.PRNGKey(slots)
         q = jax.random.normal(key, (slots, hq, d), jnp.bfloat16)
         kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
                                     (layers, blocks, hkv, block, d),
                                     jnp.bfloat16) for i in (1, 2))
+        kn, vn = (jax.random.normal(jax.random.fold_in(key, i),
+                                    (slots, hkv, d), jnp.bfloat16)
+                  for i in (3, 4))
         valid, tables = pool_layout(slots, blocks, block, max_blocks)
-        args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(valid))
+        args = (q, kn, vn, kp, vp, jnp.asarray(tables), jnp.asarray(valid))
         assert interpret or decode_attention.paged_fits(
             slots, max_blocks, block, d, kp.dtype)
         # skylint: allow-jit(one-shot numerics check, not a program)
-        got = jax.jit(lambda q_, k_, v_, t_, n_:
-                      decode_attention.paged_decode(
-                          q_, k_, v_, jnp.int32(1), t_, n_,
-                          interpret=interpret))(*args)
+        got, k_got, v_got = jax.jit(
+            lambda q_, kn_, vn_, k_, v_, t_, n_:
+            decode_attention.paged_decode(
+                q_, kn_, vn_, k_, v_, jnp.int32(1), t_, n_,
+                interpret=interpret))(*args)
+
+        def reference(q_, kn_, vn_, k_, v_, t_, n_):
+            # the scatter's junk aside: the kernel writes no inactive row
+            k_, v_ = (paged_lib.pool_write(
+                pool, 1, t_, n_ - 1, new[:, :, None], n_ > 0).at[0, 0].set(
+                    pool[0, 0]) for pool, new in ((k_, kn_), (v_, vn_)))
+            return paged_lib._gather_attention(
+                q_[:, None], k_, v_, None, None, 1, t_, n_ - 1)[:, 0], k_, v_
+
         # skylint: allow-jit(one-shot numerics check, not a program)
-        want = jax.jit(lambda q_, k_, v_, t_, n_:
-                       paged_lib._gather_attention(
-                           q_[:, None], k_, v_, None, None, 1, t_,
-                           n_ - 1)[:, 0])(*args)
+        want, k_want, v_want = jax.jit(reference)(*args)
         live = valid > 0
         err = rel_err(np.asarray(got, np.float32)[live],
                       np.asarray(want, np.float32)[live])
+        pools_equal = bool(jnp.array_equal(k_got, k_want)
+                           and jnp.array_equal(v_got, v_want))
         emit(f'paged_decode B{slots} NB{blocks} P{block} MB{max_blocks} '
              f'Hq{hq} Hkv{hkv} D{d} bf16',
-             np.isfinite(err) and err <= tol
+             np.isfinite(err) and err <= tol and pools_equal
              and not np.asarray(got, np.float32)[~live].any(),
-             err=round(err, 5), tol=tol)
+             err=round(err, 5), tol=tol, pools_equal=pools_equal)
 
     def mla_case(slots, blocks, block, max_blocks, heads=32, rank=512,
                  rope=64, layers=2):

@@ -21,17 +21,20 @@ TPU-first shape discipline (vs the GPU original's per-block kernels):
   every write and read addresses it by layer index: a plane sliced out
   of ``xs`` and stacked back into ``ys`` was a copy of the plane a
   layer and of the whole pool a step (PR 29: 20.7 of a 28.5 ms step);
-* writes are row scatters at ``table[b, len // P]``, offset ``len % P``,
-  into the pool seen as ``L * NB`` blocks (``pool_write``). The decode
-  step (S = 1, float pool) then READS THROUGH THE TABLE, BY LENGTH:
-  ``ops/decode_attention.paged_decode`` is handed the whole pools and
-  the layer, and walks each slot's blocks by DMA only as far as the
-  slot's length, with the einsum path's precision (``_cache_step``;
+* the decode step (S = 1, float pool) WRITES AND READS THROUGH THE
+  TABLE, BY LENGTH: ``ops/decode_attention.paged_decode`` is handed the
+  whole pools, the layer and the step's new K/V rows, walks each slot's
+  blocks by DMA only as far as the slot's length, puts the new row into
+  the block of position ``len`` while it holds it and sends that one
+  block back, with the einsum path's precision (``_cache_step``;
   ``decode_path`` is the one rule for when). Measured on a v5e at 48
-  slots x 2,048 positions, 8 kv heads x 128 (PR 26, PERF.md §6): 66 us
-  a layer with 24 rows live at ~230 positions, 0.6 ms with every row
-  full, against 2.7 ms a layer whatever the slots hold for the dense
-  view below;
+  slots x 2,048 positions, 8 kv heads x 128 (my chip run, PR 31,
+  PERF.md §6): 36 us a layer with 3 rows live at ~230 positions, 195 us
+  with 20 at ~1,400, against 2.7 ms a layer whatever the slots hold for
+  the dense view below, and 63 us a layer for the row scatter alone
+  (PR 26 to PR 30 scattered the row first: 18% of the step);
+* every other write is a row scatter at ``table[b, len // P]``, offset
+  ``len % P``, into the pool seen as ``L * NB`` blocks (``pool_write``);
 * everything else — the speculative verify (S = k + 1), the
   shared-prefix prefill (S = W, one row), int8 pools, backends without
   the kernel — GATHERS each row's whole table out of the same flat view
@@ -41,8 +44,10 @@ TPU-first shape discipline (vs the GPU original's per-block kernels):
   slot, live or not, per layer per step: 70% of the decode step when
   S = 1 still took it (ledger, PR 25);
 * unallocated table entries point at block 0, a dedicated JUNK SINK no
-  request ever owns: freed slots keep decoding (static shapes forbid
-  shrinking the batch) and their overflow writes land harmlessly there.
+  request ever owns: a row that finished mid-chunk keeps decoding
+  (static shapes forbid shrinking the batch) and what it writes past
+  its reservation lands harmlessly there, as do the scatter's writes of
+  inactive rows (the kernel writes none).
 
 Accounting (free list, per-slot block lists) is host-side in the
 engine — the device never sees an allocation decision, only tables.
@@ -199,14 +204,13 @@ jit_insert = profiled_jit('paged.insert', _insert_impl,
 
 
 # ---------------------------------------------------------------------------
-# Decode forwards: scatter the step's K/V, then attend — through the
-# block table in a kernel (S=1, the chunked decode step) or over the
-# gathered dense view with the dense math (everything else). S=k+1 is
-# the speculative VERIFY window (writes
-# span up to two blocks per row; rollback afterwards is just a lengths
-# rewind — rolled-back block positions are never attended and get
-# overwritten on the next write, the same invariant as the dense
-# cache).
+# Decode forwards: write the step's K/V, then attend. S=1, the chunked
+# decode step: both in one kernel, through the block table. Everything
+# else: the row scatter, then the gathered dense view with the dense
+# math. S=k+1 is the speculative VERIFY window (writes span up to two
+# blocks per row; rollback afterwards is just a lengths rewind —
+# rolled-back block positions are never attended and get overwritten on
+# the next write, the same invariant as the dense cache).
 
 
 def _block_offsets(tables: jax.Array, lengths: jax.Array, s: int,
@@ -335,13 +339,16 @@ def _cache_step(q: jax.Array, kt: jax.Array, vt: jax.Array, pools, l,
     """One layer's cache write and read: q [B, S, Hq, D] and this
     step's kt/vt [B, Hkv, S, D] against layer ``l`` of the WHOLE pools
     ``(k, v, k_s, v_s)`` ([L, NB, Hkv, P, D]; the scale planes
-    [L, NB, Hkv, P] or None). Scatters kt/vt (an int8 pool: their codes
-    and scales) at [lengths, lengths + S), inactive rows into the junk
-    sink, as ever. Then attends: where ``decode_path`` says so,
-    ``paged_decode`` through the block table, positions <= ``lengths``
-    (inactive rows read nothing, valid 0: their output is never used and
-    their stale tables may name blocks that now belong to another
-    request); else over the gathered view.
+    [L, NB, Hkv, P] or None). Where ``decode_path`` says so (S = 1, a
+    float pool the kernel takes), ``paged_decode`` does both through
+    the block table: the row goes into the block of position
+    ``lengths`` that the kernel holds anyway, and the slot attends
+    positions <= ``lengths`` (inactive rows, valid 0, read and write
+    nothing: their output is never used and their stale tables may name
+    blocks that now belong to another request). Everything else
+    scatters kt/vt (an int8 pool: their codes and scales) at
+    [lengths, lengths + S), inactive rows into the junk sink, then
+    attends over the gathered view.
     -> (att [B, S, Hq, D], pools)."""
     kernel = q.shape[1] == 1 and decode_path(
         tables.shape, pools[0].shape, pools[0].dtype,
@@ -349,6 +356,14 @@ def _cache_step(q: jax.Array, kt: jax.Array, vt: jax.Array, pools, l,
 
     def step(q, kt, vt, pools, l, tables, lengths, active):
         k_pool, v_pool, k_s, v_s = pools
+        if kernel:
+            valid = lengths + 1
+            if active is not None:
+                valid = jnp.where(active, valid, 0)
+            att, k_pool, v_pool = decode_attention.paged_decode(
+                q[:, 0], kt[:, :, 0], vt[:, :, 0], k_pool, v_pool, l,
+                tables, valid, interpret=not attention_ops._use_pallas())
+            return att[:, None], (k_pool, v_pool, k_s, v_s)
         if k_s is not None:
             (kt, ks_new), (vt, vs_new) = (_quantize_block(kt),
                                           _quantize_block(vt))
@@ -356,16 +371,8 @@ def _cache_step(q: jax.Array, kt: jax.Array, vt: jax.Array, pools, l,
             v_s = pool_write(v_s, l, tables, lengths, vs_new, active)
         k_pool = pool_write(k_pool, l, tables, lengths, kt, active)
         v_pool = pool_write(v_pool, l, tables, lengths, vt, active)
-        if kernel:
-            valid = lengths + 1
-            if active is not None:
-                valid = jnp.where(active, valid, 0)
-            att = decode_attention.paged_decode(
-                q[:, 0], k_pool, v_pool, l, tables, valid,
-                interpret=not attention_ops._use_pallas())[:, None]
-        else:
-            att = _gather_attention(q, k_pool, v_pool, k_s, v_s, l,
-                                    tables, lengths)
+        att = _gather_attention(q, k_pool, v_pool, k_s, v_s, l, tables,
+                                lengths)
         return att, (k_pool, v_pool, k_s, v_s)
 
     args = (q, kt, vt, pools, l, tables, lengths, active_rows)
@@ -394,14 +401,16 @@ def _paged_layer(cfg: llama.LlamaConfig, x: jax.Array, layer, l,
     (S=1 decode step; S=k+1 speculative verify); ``pools`` the whole
     ``(k, v, k_s, v_s)`` of every layer, of which this is layer ``l``.
     The math is generate.py's (_qkv_proj/_cached_attention/_mlp_tail);
-    only the cache write (pool scatter) and read (through the table in
-    the kernel, or the block gather) differ from the dense layer:
-    ``_cache_step``. INACTIVE rows scatter to the junk sink (block 0)
+    only the cache write and read differ from the dense layer
+    (``_cache_step``): both through the table in the kernel, or the pool
+    scatter and the block gather. On the scatter path (S > 1, int8
+    pools, no kernel) INACTIVE rows scatter to the junk sink (block 0)
     unconditionally: a freed slot's stale table may point at blocks
     already reallocated to another request, and an unmasked junk write
-    there would corrupt the new owner's live KV. Within a chunk a
-    finishing row stays active and its blocks are only released after
-    the chunk returns, so active writes never race a reallocation."""
+    there would corrupt the new owner's live KV; the kernel writes
+    nothing for them. Within a chunk a finishing row stays active and
+    its blocks are only released after the chunk returns, so active
+    writes never race a reallocation."""
     b, s = x.shape[0], x.shape[1]
     positions = (lengths[:, None]
                  + jnp.arange(s, dtype=jnp.int32)[None])  # [B, S]

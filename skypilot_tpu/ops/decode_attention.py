@@ -9,23 +9,29 @@ Two kernels, one online softmax (the training kernel's recipe,
 ``ops/attention.py``; float32 logits, softmax and accumulation,
 probabilities cast to the query's dtype before P·V):
 
-``paged_decode`` — the paged layout's decode step, ON BY ITSELF
-wherever it fits (``models/paged.decode_path``: a TPU, S = 1, a float
-pool, ``paged_fits``). It reads each slot's blocks out of one layer of
-the WHOLE pool [L, NB, Hkv, P, D] through the block table, only as far
-as the slot's length: the layer, tables and lengths arrive by scalar
-prefetch, the pool stays in HBM (a plane sliced out for the call would
-be a copy of it a layer a step), a program per slot loops over groups
-of ``PAGED_GROUP`` blocks, each block one DMA ([Hkv, P, D]: 32 KB
-contiguous at 8 x 16 x 128 bf16) into double-buffered VMEM. No dense
-view is built and no logits tensor exists in HBM. Measured on a v5e
-(PR 26, PERF.md §6) at 48 slots, 2,049 blocks of 16, 16/8 heads x
-128: 66 us a call with 24
-rows live at ~230 positions (the DMAs alone 54, the arithmetic alone
-27), 303 us with 32 rows at ~1,500 (193 MB of K/V: 78% of the HBM
-peak), 586 us with all 48 at 2,048 (84%); the gather + einsum it
-replaced, 2.7 ms whatever the slots hold. An empty slot costs a grid
-step (~0.35 us) and reads nothing.
+``paged_decode`` — the paged layout's decode step, cache write AND
+read, ON BY ITSELF wherever it fits (``models/paged.decode_path``: a
+TPU, S = 1, a float pool, ``paged_fits``). It reads each slot's blocks
+out of one layer of the WHOLE pool [L, NB, Hkv, P, D] through the block
+table, only as far as the slot's length: the layer, tables and lengths
+arrive by scalar prefetch, the pool stays in HBM (a plane sliced out for
+the call would be a copy of it a layer a step), a program per slot loops
+over groups of ``PAGED_GROUP`` blocks, each block one DMA ([Hkv, P, D]:
+32 KB contiguous at 8 x 16 x 128 bf16) into double-buffered VMEM. No
+dense view is built and no logits tensor exists in HBM. The step's new
+K/V row is written by the same program (PR 31): the block of position
+``valid - 1`` is always in the last group fetched, so the row is put
+into it in VMEM before the group is multiplied (the token attends to
+itself in the values the pool will hold) and that one block is DMA'd
+back while it is; the pools are aliased input -> output, so under the
+layer scan and the step scan they stay the in-place carry. An empty slot
+costs a grid step (~0.35 us), reads nothing and writes nothing. Measured
+alone on a v5e (my chip run, PR 31, PERF.md §6) at 48 slots, 2,049
+blocks of 16, 16/8 heads x 128, a call with | without the write:
+35.9 | 33.7 us with 3 rows live at ~230 positions, 80.7 | 77.3 with 24
+at ~230, 195.0 | 192.0 with 20 at ~1,400 (+0.15 us a row); XLA's row
+scatter it replaced (K and V, ``paged._scatter_rows``) 62.6 us whatever
+the slots hold, and the gather + einsum before PR 26 2.7 ms.
 
 ``mla_decode`` — the same walk over a latent (MLA) pool in the absorbed
 form: one plane of ``c_kv | k_rope`` rows serves as keys and values
@@ -48,9 +54,10 @@ _NEG_INF = -1e30
 # block pool through the block table.
 
 # Blocks fetched and attended per loop turn (16 x P=16: 256 positions).
-# On a v5e at 48 slots x 8 kv heads x 128 (PR 26), 4 / 8 / 16 / 32 read
-# 76 / 66 / 70 / 81 us a call with 24 rows live at ~230 positions and
-# 450 / 328 / 303 / 314 us with 32 rows at ~1,500 (the DMAs alone: 284).
+# On a v5e at 48 slots x 8 kv heads x 128 (PR 26, the kernel before it
+# wrote), 4 / 8 / 16 / 32 read 76 / 66 / 70 / 81 us a call with 24 rows
+# live at ~230 positions and 450 / 328 / 303 / 314 us with 32 rows at
+# ~1,500 (the DMAs alone: 284).
 PAGED_GROUP = 16
 # Scalar-prefetched tables + lengths live in SMEM for the whole call. A
 # v5e has 1 MiB of it: 512 slots x 128 blocks (256 KiB) compiles, 1,024
@@ -86,23 +93,33 @@ def paged_fits(slots: int, max_blocks: int, block: int, head_dim: int,
             and (slots * lanes + slots) * 4 <= PAGED_SMEM_CAP_BYTES)
 
 
-def _paged_kernel(layer_ref, tables_ref, valid_ref, q_ref, k_hbm, v_hbm,
-                  o_ref, k_buf, v_buf, sem, *, block: int, group: int):
-    """One slot per program. q_ref/o_ref [Hkv, G, D]; k_hbm/v_hbm the
-    WHOLE pools [L, NB, Hkv, P, D], left in HBM, of which layer
-    ``layer_ref[0]`` is read; tables_ref [B, MB] and valid_ref [B]
+def _paged_kernel(layer_ref, tables_ref, valid_ref, q_ref, kn_ref, vn_ref,
+                  _k_in, _v_in, o_ref, k_hbm, v_hbm, k_buf, v_buf, sem, *,
+                  block: int, group: int):
+    """One slot per program. q_ref/o_ref [Hkv, G, D]; kn_ref/vn_ref
+    [Hkv, 1, D] the step's new row; k_hbm/v_hbm the WHOLE pools
+    [L, NB, Hkv, P, D], left in HBM, of which layer ``layer_ref[0]`` is
+    read and written (the OUTPUT refs: ``_k_in``/``_v_in`` are the same
+    buffers, aliased); tables_ref [B, MB] and valid_ref [B]
     scalar-prefetched too. The slot's blocks arrive
     ``group`` at a time by DMA into k_buf/v_buf [2, Hkv, group*P, D]
     (double-buffered: the next group is in flight while this one is
-    multiplied), only as far as ``valid`` reaches."""
+    multiplied), only as far as ``valid`` reaches. The last group holds
+    the block of position ``valid - 1``: the new row is put into it in
+    VMEM before the group is multiplied, and that one block goes back
+    to the pool while it is."""
     b = pl.program_id(0)
     q = q_ref[...]
     hkv, g, d = q.shape
     span = group * block
     scale = d ** -0.5
-    valid = valid_ref[b]
+    # The row is WRITTEN where the scatter wrote it (offset by the
+    # length as it is) and READ as far as its table reaches.
+    new_pos = valid_ref[b] - 1
+    valid = jnp.minimum(valid_ref[b], tables_ref.shape[1] * block)
     n_blocks = pl.cdiv(valid, block)
     n_groups = pl.cdiv(valid, span)
+    planes = ((k_hbm, k_buf, kn_ref, 0), (v_hbm, v_buf, vn_ref, 1))
 
     @pl.when(b == 0)
     def _():
@@ -120,10 +137,16 @@ def _paged_kernel(layer_ref, tables_ref, valid_ref, q_ref, k_hbm, v_hbm,
             def _(i=i, j=j):
                 blk = tables_ref[b, i]
                 dst = pl.ds(j * block, block)
-                for plane, buf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                for plane, buf, _, s in planes:
                     act(pltpu.make_async_copy(
                         plane.at[layer_ref[0], blk],
                         buf.at[slot, :, dst, :], sem.at[s, slot]))
+
+    def write_back(slot, rows, blk, s):
+        plane, buf = planes[s][:2]
+        return pltpu.make_async_copy(buf.at[slot, :, rows, :],
+                                     plane.at[layer_ref[0], blk],
+                                     sem.at[2, s])
 
     group_dma(0, 0, lambda c: c.start())
 
@@ -136,6 +159,19 @@ def _paged_kernel(layer_ref, tables_ref, valid_ref, q_ref, k_hbm, v_hbm,
             group_dma(gi + 1, 1 - slot, lambda c: c.start())
 
         group_dma(gi, slot, lambda c: c.wait())
+
+        @pl.when(gi + 1 == n_groups)
+        def _():
+            i = n_blocks - 1
+            rows = pl.ds(pl.multiple_of((i - gi * group) * block, block),
+                         block)
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, (hkv, block, d), 1) == new_pos % block
+            for _, buf, new, s in planes:
+                buf[slot, :, rows, :] = jnp.where(here, new[...],
+                                                  buf[slot, :, rows, :])
+                write_back(slot, rows, tables_ref[b, i], s).start()
+
         k = k_buf[slot].astype(q.dtype)  # [Hkv, span, D]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
@@ -158,16 +194,34 @@ def _paged_kernel(layer_ref, tables_ref, valid_ref, q_ref, k_hbm, v_hbm,
     acc, _, l = jax.lax.fori_loop(0, n_groups, body, (acc0, m0, l0))
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
+    @pl.when(valid > 0)
+    def _():
+        # The next program refills these buffers: the block is out
+        # first. (A wait counts bytes; any block's descriptor does.)
+        for s in (0, 1):
+            write_back(0, pl.ds(0, block), 0, s).wait()
 
-def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                 layer: jax.Array, tables: jax.Array, valid: jax.Array,
-                 interpret: bool = False) -> jax.Array:
-    """q [B, Hq, D] (the single decode position) against layer ``layer``
-    (int32 scalar) of the pools [L, NB, Hkv, P, D] under block tables
-    [B, MB] int32: row b attends positions < valid[b] of the blocks its
-    table names, in order; no other layer and no other block is read.
-    valid[b] == 0 reads nothing and returns zeros. -> [B, Hq, D].
-    Callers gate on ``paged_fits``."""
+
+def paged_decode(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                 k_pool: jax.Array, v_pool: jax.Array, layer: jax.Array,
+                 tables: jax.Array, valid: jax.Array,
+                 interpret: bool = False):
+    """One decode step's cache write and read of layer ``layer`` (int32
+    scalar) of the pools [L, NB, Hkv, P, D] under block tables [B, MB]
+    int32. Row b's new key and value ``k_new``/``v_new`` [B, Hkv, D]
+    (the pool's dtype) are written at position valid[b] - 1 of the
+    blocks its table names, and q [B, Hq, D] attends positions
+    < valid[b] of them in order, the new one included: the row goes
+    into the block the kernel holds in VMEM anyway, and that block alone
+    goes back. No other layer and no other block is read or written.
+    valid[b] == 0 reads nothing, WRITES nothing and returns zeros. A
+    live row appends only into a block it owns (the engine forks a
+    shared tail at admission), so no program reads a block another
+    writes; rows that decode on past their reservation all name the
+    junk sink there, and the programs run in order, each with its block
+    out before the next starts. -> (out [B, Hq, D], k_pool, v_pool):
+    the pools are aliased to their operands, in place under a scan that
+    carries them. Callers gate on ``paged_fits``."""
     b, hq, d = q.shape
     _, nb, hkv, block, _ = k_pool.shape
     mb = tables.shape[1]
@@ -175,28 +229,38 @@ def paged_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     group = _pick_group(mb)
     # What XLA's gather does for the dense view, a DMA does not: keep
     # every address inside the pool. A row that finished mid-chunk
-    # decodes on past max_len (its writes clip to its last block, its
-    # output is dropped); a table never names a block past the pool.
-    valid = jnp.clip(valid.astype(jnp.int32), 0, mb * block)
+    # decodes on past max_len (the kernel clips what it reads to the
+    # table, so the write lands in the row's last block as the
+    # scatter's did; its output is dropped); a table never names a
+    # block past the pool.
+    valid = jnp.maximum(valid.astype(jnp.int32), 0)
     tables = jnp.clip(tables.astype(jnp.int32), 0, nb - 1)
     qspec = pl.BlockSpec((None, hkv, g, d), lambda bi, *_: (bi, 0, 0, 0))
+    newspec = pl.BlockSpec((None, hkv, 1, d), lambda bi, *_: (bi, 0, 0, 0))
+    anyspec = pl.BlockSpec(memory_space=pl.ANY)
     buf = pltpu.VMEM((2, hkv, group * block, d), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3, grid=(b,),
-        in_specs=[qspec, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=qspec,
-        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))])
-    out = pl.pallas_call(
+        in_specs=[qspec, newspec, newspec, anyspec, anyspec],
+        out_specs=[qspec, anyspec, anyspec],
+        # reads [K | V, buffer]; write-backs [2, K | V]
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((3, 2))])
+    pool = jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype)
+    out, k_pool, v_pool = pl.pallas_call(
         functools.partial(_paged_kernel, block=block, group=group),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype), pool,
+                   pool],
+        # operands count the scalar-prefetched three
+        input_output_aliases={6: 1, 7: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         interpret=interpret, name='paged_decode',
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), tables, valid,
-      q.reshape(b, hkv, g, d), k_pool, v_pool)
-    return out.reshape(b, hq, d)
+      q.reshape(b, hkv, g, d),
+      k_new.astype(k_pool.dtype).reshape(b, hkv, 1, d),
+      v_new.astype(v_pool.dtype).reshape(b, hkv, 1, d), k_pool, v_pool)
+    return out.reshape(b, hq, d), k_pool, v_pool
 
 
 # ---------------------------------------------------------------------------

@@ -39,26 +39,65 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_kernel(one_chip, slots, max_blocks, block, blocks, hq, hkv, d):
+def _compile_kernel(one_chip, slots, max_blocks, block, blocks, hq, hkv, d,
+                    dtype=jnp.bfloat16):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((2, blocks, hkv, block, d), jnp.bfloat16)
+    pool = sds((2, blocks, hkv, block, d), dtype)
+    new = sds((slots, hkv, d), dtype)
     # skylint: allow-jit(test-only compile check)
-    return jax.jit(lambda *a: decode_attention.paged_decode(*a)).lower(
-        sds((slots, hq, d), jnp.bfloat16), pool, pool,
+    return jax.jit(lambda *a: decode_attention.paged_decode(*a),
+                   donate_argnums=(3, 4)).lower(
+        sds((slots, hq, d), dtype), new, new, pool, pool,
         sds((), jnp.int32), sds((slots, max_blocks), jnp.int32),
         sds((slots,), jnp.int32)).compile()
+
+
+def _kernel_call(hlo):
+    """Operand names of the ``paged_decode`` custom call, whose results
+    1 and 2 (the pools) must BE its operands 6 and 7."""
+    call = re.search(r'%paged_decode[.\d]* = [^\n]*custom-call\(([^)]*)\)'
+                     r'([^\n]*)', hlo)
+    assert call, 'no paged_decode call'
+    assert re.search(r'output_to_operand_aliasing=\{\{1\}: \(6, \{\}\), '
+                     r'\{2\}: \(7, \{\}\)\}', call.group(2)), call.group(2)
+    names = [n.split('*/')[-1].strip() for n in call.group(1).split(',')]
+    assert len(names) == 8, names
+    return names
 
 
 def test_paged_decode_compiles_at_the_cells_geometry(one_chip):
     assert decode_attention.paged_fits(
         CELL['slots'], CELL['max_blocks'], CELL['block'], CELL['d'],
         jnp.bfloat16)
-    hlo = _compile_kernel(one_chip, **CELL).as_text()
+    compiled = _compile_kernel(one_chip, **CELL)
+    hlo = compiled.as_text()
     # Mosaic's call, under the name the device trace shows.
     assert re.search(r'%paged_decode[.\d]* = .*custom-call\(', hlo)
     assert 'tpu_custom_call' in hlo
+    # It writes the pools it is handed: results 1 and 2 ARE operands 6
+    # and 7 (the donated arguments themselves), and nothing pool-sized
+    # is allocated beside them.
+    _kernel_call(hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize('dtype,block', [
+    (jnp.float32, 8), (jnp.float32, 16), (jnp.bfloat16, 32)],
+    ids=['f32-p8', 'f32-p16', 'bf16-p32'])
+def test_paged_decode_writes_a_block_of_any_tile_count(one_chip, dtype,
+                                                       block):
+    """The new row goes into a block-sized slice of the VMEM buffer at
+    an offset only the program knows, and that slice is what is DMA'd
+    back: one sublane tile (float32 x 8, bfloat16 x 16) or two, the
+    other geometries ``paged_fits`` lets through."""
+    geometry = dict(CELL, block=block, max_blocks=2048 // block,
+                    blocks=32768 // block + 1)
+    assert decode_attention.paged_fits(
+        CELL['slots'], geometry['max_blocks'], block, CELL['d'], dtype)
+    _kernel_call(_compile_kernel(one_chip, **geometry,
+                                 dtype=dtype).as_text())
 
 
 def test_paged_fits_is_inside_what_the_chip_takes(one_chip):
@@ -101,11 +140,14 @@ _MOVES = ('copy', 'copy-start', 'copy-done', 'dynamic-slice',
           'dynamic-update-slice', 'slice', 'transpose', 'concatenate')
 
 
-def _no_pool_is_taken_apart(hlo):
+def _no_pool_is_taken_apart(hlo, writer):
     """No instruction PRODUCES a pool or a plane by moving it, under any
     shape of the same size (the flat views included): the pool enters as
     a parameter, rides the loops' tuples, is seen through bitcasts, and
-    is written in place by the row scatter's fusion."""
+    is written in place by ``writer`` and by nothing else:
+    ``'fusion:scatter'`` (the row scatter's fusion: S > 1) or
+    ``'paged_decode'`` (the decode kernel, whose pool results alias its
+    operands: ``_kernel_call``)."""
     seen = set()
     for m in re.finditer(r'(%[\w.-]+) = bf16\[([\d,]+)\]\S* ([\w-]+)\('
                          r'([^\n]*)', hlo):
@@ -122,7 +164,10 @@ def _no_pool_is_taken_apart(hlo):
                                        body).group(1)
         seen.add(op)
         assert op.split(':')[-1] not in _MOVES, (name, dims, op)
-    assert 'fusion:scatter' in seen, seen  # the write, in place
+    # the write, in place
+    assert ('fusion:scatter' in seen) == (writer == 'fusion:scatter'), seen
+    if writer == 'paged_decode':
+        _kernel_call(hlo)
     # the pool keeps its row-major layout from the arguments on
     assert set(re.findall(_POOL + r'\{([\d,]+)', hlo)) == {'4,3,2,1,0'}
 
@@ -131,8 +176,10 @@ def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
                                                           monkeypatch):
     """The whole decode chunk as the engine builds it (two layers of the
     cells' width). The pools ride the step scan and the layer scan as a
-    carry; the kernel is handed BOTH WHOLE and a layer index, and the
-    row scatter writes them in place. As ``xs``/``ys`` of the layer
+    carry; the kernel is handed BOTH WHOLE and a layer index, writes the
+    step's row into them itself and hands them back as the SAME buffers
+    (PR 31: the row scatter in front of it was 18% of the step; no
+    scatter yields a pool any more). As ``xs``/``ys`` of the layer
     scan each layer's plane was sliced out, stacked back and the new
     pool copied into the step's carry (PR 28's ledger: 73% of the
     device's time in ``chat-steady``); and XLA lays a pool out for
@@ -152,17 +199,15 @@ def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
     hlo = engine_lib._jit_paged_chunk.lower(
         cfg, 2, params, pool, vec(jnp.int32), vec(jnp.float32), None, None,
         vec(jnp.bool_), vec(jnp.uint32, 2), None).compile().as_text()
-    call = re.search(r'%paged_decode[.\d]* = [^\n]*custom-call\(([^)]*)\)',
-                     hlo)
-    assert call, 'the decode step does not call the kernel'
+    names = _kernel_call(hlo)
     defs = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
         r'(%[\w.-]+) = (\S+) ([\w-]+)\(', hlo)}
-    for name in call.group(1).split(',')[-2:]:
-        shape, op = defs[name.split('*/')[-1].strip()]
+    for name in names[6:]:
+        shape, op = defs[name]
         assert re.match(_POOL, shape), (name, shape)
         assert op in ('bitcast', 'get-tuple-element', 'parameter'), (name,
                                                                      op)
-    _no_pool_is_taken_apart(hlo)
+    _no_pool_is_taken_apart(hlo, 'paged_decode')
     assert 'kernel-fallback' not in hlo
 
 
@@ -184,7 +229,7 @@ def test_shared_prefix_prefill_writes_the_pool_in_place(one_chip,
         vec(jnp.int32, 1, CELL['max_blocks']), vec(jnp.int32),
         vec(jnp.int32, 1), vec(jnp.int32, 1), None).compile().as_text()
     assert 'paged_decode' not in hlo
-    _no_pool_is_taken_apart(hlo)
+    _no_pool_is_taken_apart(hlo, 'fusion:scatter')
 
 
 # -- the latent (MLA) cell: xing-docs-sessions -------------------------------

@@ -356,6 +356,11 @@ def kernel_traces(monkeypatch):
 
 def test_paged_kernel_engine_matches_gather_engine(wide, kernel_traces,
                                                    monkeypatch):
+    """The same requests through the engine, chunks of two steps, on
+    the scatter + gather path and on the kernel that writes the step's
+    row itself: the same tokens, and the same pool behind them (every
+    block of every layer but the junk sinks, which only the scatter
+    feeds with inactive rows; float32: 1e-4)."""
     from skypilot_tpu.models import paged as paged_lib
     from skypilot_tpu.ops import decode_attention
     cfg, params = wide
@@ -363,6 +368,7 @@ def test_paged_kernel_engine_matches_gather_engine(wide, kernel_traces,
     try:
         assert eng.stats()['decode_attention'] == 'gather'  # CPU
         want = _run_reuse_pattern(eng)
+        want_cache = jax.tree.map(np.asarray, eng._cache)
     finally:
         eng.stop()
     assert not kernel_traces
@@ -376,18 +382,27 @@ def test_paged_kernel_engine_matches_gather_engine(wide, kernel_traces,
         assert eng.stats()['failures'] == 0
         assert kernel_traces and all(kw['interpret']
                                      for kw in kernel_traces)
-        # One more step over the pool the run left behind (tables of
-        # freed slots stale, lengths ragged), both ways: logits of order
-        # 1 agree to 1e-4 in float32.
         cache = jax.tree.map(jnp.copy, eng._cache)
     finally:
         eng.stop()
+    np.testing.assert_array_equal(cache.tables, want_cache.tables)
+    np.testing.assert_array_equal(cache.lengths, want_cache.lengths)
+    for got_plane, want_plane in ((cache.k, want_cache.k),
+                                  (cache.v, want_cache.v)):
+        assert np.asarray(got_plane[:, 1:]).any()
+        np.testing.assert_allclose(np.asarray(got_plane[:, 1:]),
+                                   want_plane[:, 1:], atol=1e-4)
+    # One more step over the pool the run left behind (tables of
+    # freed slots stale, lengths ragged), both ways: logits of order
+    # 1 agree to 1e-4 in float32, and so do the pools they leave.
     toks = jnp.asarray([[3], [4]], jnp.int32)
     got, new = paged_lib.forward_paged(params, toks, cache, cfg)
     monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', False)
     ref, ref_new = paged_lib.forward_paged(params, toks, cache, cfg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
     np.testing.assert_allclose(np.asarray(new.k), np.asarray(ref_new.k),
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(new.v), np.asarray(ref_new.v),
                                atol=1e-4)
 
 
@@ -473,5 +488,8 @@ def test_paged_kernel_leaves_int8_and_spec_on_the_gather(
     finally:
         eng.stop()
     assert not kernel_traces
+    # and a float pool without a draft takes it (stated when the engine
+    # builds its pool, before anything is traced)
     assert engine_lib.ContinuousEngine(
-        params, cfg, slots=2, max_len=64).stats()['decode_attention'] is None
+        params, cfg, slots=2,
+        max_len=64).stats()['decode_attention'] == 'paged_kernel'

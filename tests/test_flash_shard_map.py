@@ -113,9 +113,10 @@ def test_the_three_kernels_carry_their_names_into_the_program():
                          ids=['tensor4', 'data2-tensor2'])
 def test_sharded_paged_step_equals_unsharded(axes, s, monkeypatch):
     """Under a TP mesh the cache write and read of a layer run per
-    kv-head shard of the WHOLE pools (heads are independent): the row
-    scatter at every S, then the kernel (S = 1) or the gathered view
-    (S = 3: the verify, the shared-prefix prefill). The layer, tables
+    kv-head shard of the WHOLE pools (heads are independent): the
+    kernel, which writes the step's row itself (S = 1), or the row
+    scatter and the gathered view (S = 3: the verify, the shared-prefix
+    prefill). The layer, tables
     and lengths go to every shard whole, whatever the mesh does with
     the batch. Four virtual devices, interpret mode: the sharded step
     equals the unsharded one, keeps heads and pools sharded, and
@@ -151,10 +152,21 @@ def test_sharded_paged_step_equals_unsharded(axes, s, monkeypatch):
     if s == 1:  # the kernel: the inactive row read nothing
         assert not np.asarray(att[3]).any()
     # The write landed in layer 1 alone: row 1's 17th position is block
-    # 6, offset 0; the inactive row's went to layer 0's junk sink.
+    # 6, offset 0; the inactive row's went to layer 0's junk sink (the
+    # scatter's) or nowhere (the kernel's).
     np.testing.assert_array_equal(k_new[1, 6, :, 0], kt[1, :, 0])
     np.testing.assert_array_equal(k_new[2], kp[2])
     np.testing.assert_array_equal(k_new[0, 1:], kp[0, 1:])
+    if s == 1:
+        # Per shard the kernel left the pools the row scatter leaves, to
+        # the bit, less the junk it no longer writes: nothing in layer 0,
+        # and the inactive row's own block 8 as it was.
+        np.testing.assert_array_equal(k_new[0], kp[0])
+        np.testing.assert_array_equal(k_new[1, 8], kp[1, 8])
+        for pool, new, old in ((k_new, kt, kp), (got[1][1], vt, vp)):
+            np.testing.assert_array_equal(pool, paged.pool_write(
+                old, 1, tables, lengths, new, active).at[0, 0].set(
+                    old[0, 0]))
     spec = jax.sharding.PartitionSpec
     assert att.sharding.is_equivalent_to(jax.sharding.NamedSharding(
         mesh, spec(None, None, ctx[1][1], None)), att.ndim)

@@ -104,10 +104,12 @@ def test_flash_gate_falls_back_on_unaligned_seq():
 
 # -- pallas paged decode (ops/decode_attention.paged_decode) ----------------
 
-_P, _MB, _NB = 16, 4, 12  # blocks of 16, max_len 64, 11 usable blocks
+_P, _MB, _NB = 16, 4, 13  # blocks of 16, max_len 64, 12 usable blocks
 
 # name -> (valid lengths, block tables). 0 in a table is the junk sink:
-# what the engine pads a row's unreserved tail with.
+# what the engine pads a row's unreserved tail with. The block of a live
+# row's position valid - 1 (the one the step appends to) is in no other
+# row's table below that row's length: the engine forks a shared tail.
 _PAGED_CASES = {
     'len0-inactive': ([0, 33], [[0, 0, 0, 0], [3, 1, 2, 0]]),
     'len1': ([1, 1], [[5, 0, 0, 0], [6, 0, 0, 0]]),
@@ -117,55 +119,144 @@ _PAGED_CASES = {
     'full-max-len': ([64, 64], [[1, 2, 3, 4], [8, 7, 6, 5]]),
     'ragged': ([5, 64, 0, 31, 48, 17],
                [[9, 0, 0, 0], [1, 2, 3, 4], [4, 4, 4, 4], [5, 6, 0, 0],
-                [7, 8, 10, 0], [11, 3, 0, 0]]),
+                [7, 8, 10, 0], [11, 12, 0, 0]]),
     # A row that finished mid-chunk decodes on past max_len until the
     # chunk ends: it attends its 64 positions, like the dense view.
     'past-max-len': ([64 + 3, 20], [[1, 2, 3, 4], [5, 6, 0, 0]]),
-    # The prefix trie's case: two rows' tables name the same blocks.
+    # The prefix trie's case: two rows' tables name the same blocks
+    # (whole ones: each row appends into a block of its own).
     'shared-blocks': ([40, 37, 33], [[2, 3, 4, 0], [2, 3, 5, 0],
                                      [2, 3, 6, 0]]),
+    # Where the step's row lands (position valid - 1; groups are 2
+    # blocks = 32 positions in these tests): the last row of a block
+    # that ends a group, the first row of a fresh block that opens the
+    # last group, and the second block of that group.
+    'write-block-end': ([32, 48], [[3, 9, 0, 0], [1, 2, 4, 0]]),
+    'write-group-start': ([33], [[7, 2, 5, 0]]),
+    'write-mid-group': ([49, 50], [[1, 2, 3, 4], [8, 7, 6, 5]]),
+    # An inactive row's stale table names a live row's blocks.
+    'stale-inactive': ([0, 22, 0], [[5, 6, 0, 0], [5, 6, 0, 0],
+                                    [6, 0, 0, 0]]),
+    # Rows that finished mid-chunk decode on past their reservation:
+    # both name the junk sink there and both write it, in slot order.
+    'past-reservation': ([20, 37], [[5, 0, 0, 0], [6, 7, 0, 0]]),
 }
 
 
 def _paged_inputs(group, valid, tables, dtype, layer=1):
-    """(q, k_pool, v_pool, layer, tables, valid): pools of three layers,
-    of which ``layer`` is the one attended."""
+    """(q, k_new, v_new, k_pool, v_pool, layer, tables, valid): pools of
+    three layers, of which ``layer`` is the one written and attended."""
     hkv, d = 2, 128
     key = jax.random.PRNGKey(len(valid) + group)
     q = jax.random.normal(key, (len(valid), hkv * group, d), dtype)
     kp, vp = (jax.random.normal(jax.random.fold_in(key, i),
                                 (3, _NB, hkv, _P, d), dtype) for i in (1, 2))
-    return (q, kp, vp, jnp.int32(layer), jnp.asarray(tables, jnp.int32),
-            jnp.asarray(valid, jnp.int32))
+    kn, vn = (jax.random.normal(jax.random.fold_in(key, i),
+                                (len(valid), hkv, d), dtype) for i in (3, 4))
+    return (q, kn, vn, kp, vp, jnp.int32(layer),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(valid, jnp.int32))
 
 
-def _paged_reference(q, kp, vp, layer, tables, valid):
-    """What the layer did before the kernel and still does off the
-    TPU: every row's whole table gathered into a dense view, then the
-    einsum path."""
+def _scattered(kn, vn, kp, vp, layer, tables, valid):
+    """The pools as ``pool_write`` leaves them (what the step did before
+    the kernel wrote, and S > 1 still does), less its junk: the scatter
+    sends inactive rows to block 0 of layer 0, the kernel writes nothing
+    for them."""
     from skypilot_tpu.models import paged
-    return paged._gather_attention(  # noqa: SLF001 — oracle
-        q[:, None], kp, vp, None, None, layer, tables, valid - 1)[:, 0]
+    return tuple(
+        paged.pool_write(pool, layer, tables, valid - 1, new[:, :, None],
+                         valid > 0).at[0, 0].set(pool[0, 0])
+        for pool, new in ((kp, kn), (vp, vn)))
+
+
+def _paged_reference(q, kn, vn, kp, vp, layer, tables, valid):
+    """What the layer did before the kernel and still does off the
+    TPU: the row scatter, then every row's whole table gathered into a
+    dense view and the einsum path. -> (out, k_pool, v_pool)."""
+    from skypilot_tpu.models import paged
+    kp, vp = _scattered(kn, vn, kp, vp, layer, tables, valid)
+    return (paged._gather_attention(  # noqa: SLF001 — oracle
+        q[:, None], kp, vp, None, None, layer, tables, valid - 1)[:, 0],
+            kp, vp)
+
+
+def _bits(x):
+    """An array's bit pattern: NaN compares equal to itself."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
 @pytest.mark.parametrize('group', [1, 2], ids=['mha', 'gqa2'])
 @pytest.mark.parametrize('case', list(_PAGED_CASES))
 def test_paged_decode_matches_gather_path(monkeypatch, case, group):
     """float32 end to end, so the tolerance is the accumulation order's
-    alone: 2e-5 on outputs of order 1. Groups of 2 blocks make a full
-    row loop twice and a 17-long row end mid-group."""
+    alone: 2e-5 on outputs of order 1; the pools come back as the row
+    scatter leaves them, to the bit. Groups of 2 blocks make a full row
+    loop twice and a 17-long row end mid-group."""
     from skypilot_tpu.ops import decode_attention
 
     monkeypatch.setattr(decode_attention, 'PAGED_GROUP', 2)
     valid, tables = _PAGED_CASES[case]
     args = _paged_inputs(group, valid, tables, jnp.float32)
-    got = np.asarray(decode_attention.paged_decode(*args, interpret=True))
-    want = np.asarray(_paged_reference(*args))
-    live = np.asarray(valid) > 0
-    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    got, k_got, v_got = decode_attention.paged_decode(*args, interpret=True)
+    want, k_want, v_want = _paged_reference(*args)
+    got, live = np.asarray(got), np.asarray(valid) > 0
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=2e-5)
     # A row with nothing valid reads nothing and returns zeros (the
     # gather path attends uniformly over junk there; neither is used).
     assert not got[~live].any()
+    np.testing.assert_array_equal(_bits(k_got), _bits(k_want))
+    np.testing.assert_array_equal(_bits(v_got), _bits(v_want))
+
+
+# What the write must get right, by name -> (case, layer).
+_WRITES = {
+    'off-0-fresh-block': ('len17', 1),
+    'off-last': ('write-block-end', 1),
+    'clipped-past-max-len': ('past-max-len', 1),
+    'inactive-writes-nothing': ('stale-inactive', 1),
+    'layer-0': ('ragged', 0),
+    'layer-2': ('ragged', 2),
+    'group-boundary': ('write-group-start', 1),
+    'second-block-of-last-group': ('write-mid-group', 1),
+    'junk-sink-shared': ('past-reservation', 2),
+}
+
+
+@pytest.mark.parametrize('name', list(_WRITES))
+def test_paged_decode_writes_what_the_scatter_wrote_bf16(monkeypatch, name):
+    """bfloat16, the serving dtype, to the BIT: both pools equal the row
+    scatter's (so every block no live row appends to, every other
+    layer, block 0 and an inactive row's stale table's blocks hold what
+    they held), and the output equals the same kernel's over pools the
+    scatter wrote first (writing the same row again changes nothing):
+    the token attends to itself in the values the pool now holds."""
+    from skypilot_tpu.ops import decode_attention
+
+    monkeypatch.setattr(decode_attention, 'PAGED_GROUP', 2)
+    case, layer = _WRITES[name]
+    valid, tables = _PAGED_CASES[case]
+    q, kn, vn, kp, vp, l, t, n = _paged_inputs(2, valid, tables,
+                                               jnp.bfloat16, layer)
+    got, k_got, v_got = decode_attention.paged_decode(
+        q, kn, vn, kp, vp, l, t, n, interpret=True)
+    k_want, v_want = _scattered(kn, vn, kp, vp, l, t, n)
+    want, k_again, v_again = decode_attention.paged_decode(
+        q, kn, vn, k_want, v_want, l, t, n, interpret=True)
+    for a, b in ((got, want), (k_got, k_want), (v_got, v_want),
+                 (k_again, k_want), (v_again, v_want)):
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    # and it did write: each live row's position holds the new row
+    for row, length in enumerate(valid):
+        if length:
+            at = min(length - 1, _MB * _P - 1) // _P, (length - 1) % _P
+            np.testing.assert_array_equal(
+                _bits(k_got[layer, tables[row][at[0]], :, at[1]]),
+                _bits(kn[row]))
+    others = [i for i in range(3) if i != layer]
+    np.testing.assert_array_equal(_bits(k_got[others, ...]),
+                                  _bits(kp[others, ...]))
 
 
 @pytest.mark.parametrize('layer', [0, 2])
@@ -173,23 +264,30 @@ def test_paged_decode_reads_its_layers_named_blocks_only(layer):
     """The kernel is handed the WHOLE pools and a layer index. Every
     other layer, and every block of this one that no live row's table
     names below its length, holds NaN: one DMA off its address and the
-    output says so (0 x NaN is NaN). The gather path on the clean pools
-    is the oracle."""
+    output says so (0 x NaN is NaN), and one write off its address and
+    the pools say so. The gather path on the clean pools is the
+    oracle."""
     from skypilot_tpu.ops import decode_attention
 
     valid, tables = _PAGED_CASES['ragged']
-    q, kp, vp, l, t, n = _paged_inputs(2, valid, tables, jnp.float32, layer)
-    want = np.asarray(_paged_reference(q, kp, vp, l, t, n))
+    q, kn, vn, kp, vp, l, t, n = _paged_inputs(2, valid, tables,
+                                               jnp.float32, layer)
+    want, k_want, v_want = _paged_reference(q, kn, vn, kp, vp, l, t, n)
     named = np.zeros((3, _NB), bool)
     for row, length in zip(tables, valid):
         named[layer, row[:-(-length // _P)]] = True
     poison = jnp.asarray(~named)[:, :, None, None, None]
-    got = np.asarray(decode_attention.paged_decode(
-        q, jnp.where(poison, jnp.nan, kp), jnp.where(poison, jnp.nan, vp),
-        l, t, n, interpret=True))
+    got, k_got, v_got = decode_attention.paged_decode(
+        q, kn, vn, jnp.where(poison, jnp.nan, kp),
+        jnp.where(poison, jnp.nan, vp), l, t, n, interpret=True)
+    got = np.asarray(got)
     assert np.isfinite(got).all()
     live = np.asarray(valid) > 0
-    np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=2e-5)
+    for pool, clean in ((k_got, k_want), (v_got, v_want)):
+        np.testing.assert_array_equal(
+            _bits(pool), _bits(jnp.where(poison, jnp.nan, clean)))
 
 
 def test_paged_decode_bf16_tolerance():
@@ -201,12 +299,12 @@ def test_paged_decode_bf16_tolerance():
 
     valid, tables = _PAGED_CASES['ragged']
     args = _paged_inputs(2, valid, tables, jnp.bfloat16)
-    got = decode_attention.paged_decode(*args, interpret=True)
+    got, _, _ = decode_attention.paged_decode(*args, interpret=True)
     assert got.dtype == jnp.bfloat16
     live = np.asarray(valid) > 0
     np.testing.assert_allclose(
         np.asarray(got, np.float32)[live],
-        np.asarray(_paged_reference(*args), np.float32)[live], atol=1e-2)
+        np.asarray(_paged_reference(*args)[0], np.float32)[live], atol=1e-2)
 
 
 def test_paged_decode_geometry_gate():
