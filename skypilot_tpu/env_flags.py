@@ -176,7 +176,10 @@ FLAGS: Tuple[Flag, ...] = (
     Flag('SKYTPU_LLM_PREFILL_BATCH', 'int', '8',
          'Max prompts prefilled per admission group.'),
     Flag('SKYTPU_LLM_PREFILL_CHUNK', 'int', '0',
-         'Chunked-prefill chunk length (0 = whole prompt).'),
+         'Chunked-prefill chunk length (0 = whole prompt). Unset, a '
+         'model family that names a piece of its own takes that '
+         '(models/model_ops.py: 512 for a model that carries a '
+         'recurrent state between the pieces).'),
     Flag('SKYTPU_LLM_PREFIX_SHARE', 'bool', '1',
          'Copy-on-write block-level prefix sharing in the paged KV '
          'pool.'),

@@ -459,6 +459,7 @@ class ContinuousEngine:
         'spec_proposals': '_lock', 'spec_accepted': '_lock',
         'exports': '_lock', 'imports': '_lock',
         'import_errors': '_lock', 'dispatches': '_lock',
+        'decode_steps': '_lock',
         'host_overlap_ms': '_lock', 'bubble_ms': '_lock',
         '_gap_ms_total': '_lock', '_gap_count': '_lock',
         '_moe_load': '_lock',
@@ -578,14 +579,17 @@ class ContinuousEngine:
             # acceptance decides the rollback that shapes the next
             # round's inputs, so there is nothing to keep in flight.
             self.pipeline_depth = 0
-        # Chunked prefill (opt-in): prompts longer than this advance in
+        # Chunked prefill: prompts longer than this advance in
         # prefill_chunk-token pieces interleaved with decode chunks, so
         # long admissions don't stall every active slot's stream. Each
         # in-flight long prefill holds one scratch max_len cache row
-        # (capped at 2 concurrent).
+        # (capped at 2 concurrent). Opt-in, except where the family
+        # names a piece of its own (model_ops: a model that carries a
+        # state between the pieces); 0 turns it off.
         if prefill_chunk is None:
-            prefill_chunk = int(os.environ.get('SKYTPU_LLM_PREFILL_CHUNK',
-                                               '0'))
+            prefill_chunk = int(
+                os.environ.get('SKYTPU_LLM_PREFILL_CHUNK')
+                or self._ops.prefill_chunk(cfg))
         self.prefill_chunk = max(int(prefill_chunk), 0)
         if self.prefill_chunk:
             self._ops.refuse('prefill_chunk')
@@ -595,6 +599,18 @@ class ContinuousEngine:
             # the monolithic prefill the greedy-exactness oracle uses —
             # same reason block sharing is disabled for MoE below.
             self.prefill_chunk = 0
+        # Where pieces go between the chunks (and the family has the
+        # program for it), a chunk ENDS WITH ITS FIRST ROW TO FINISH
+        # and a piece follows at least chunk_steps decode steps: a row
+        # gets its last tokens at the step that makes them instead of
+        # at the end of a chunk of junk steps plus a piece, and no row
+        # sees more than one piece per chunk_steps of its own tokens.
+        # Without pieces a chunk's tail costs a row only junk steps,
+        # and the chunk stays whole (one dispatch per chunk_steps).
+        self._trim_chunks = bool(self.prefill_chunk
+                                 and self._ops.paged_chunk_n is not None
+                                 and draft_cfg is None)
+        self._steps_since_piece = self.chunk_steps
         # COPY-ON-WRITE BLOCK SHARING, the prefix cache (default ON):
         # committed full prompt blocks are indexed in a host-side trie
         # (models/paged.py BlockTrie) with per-block refcounts; a
@@ -607,9 +623,13 @@ class ContinuousEngine:
         # prefix token's expert routing), so shared prefix KV would
         # replay its commit-time batchmates' contention. Spec mode
         # keeps its own dense draft-cache prefill path and opts out.
+        if prefix_share:
+            self._ops.refuse('prefix sharing')   # asked for by name
         if prefix_share is None:
-            prefix_share = os.environ.get('SKYTPU_LLM_PREFIX_SHARE',
-                                          '1') != '0'
+            # default ON only where the family has it
+            prefix_share = (os.environ.get('SKYTPU_LLM_PREFIX_SHARE',
+                                           '1') != '0'
+                            and 'prefix sharing' not in self._ops.refuses)
         self.prefix_share = (bool(prefix_share)
                              and not rows_couple
                              and draft_cfg is None)
@@ -732,6 +752,7 @@ class ContinuousEngine:
         # done while a chunk computes vs host time the device provably
         # idled with work waiting (the serial-mode bubble).
         self.dispatches = 0
+        self.decode_steps = 0  # chunk_steps a dispatch, fewer if trimmed
         self.host_overlap_ms = 0.0
         self.bubble_ms = 0.0
         self._gap_ms_total = 0.0
@@ -1018,6 +1039,10 @@ class ContinuousEngine:
                 # the family's own count (a latent cache: c_kv | k_rope).
                 'kv_bytes_per_token': self._ops.kv_bytes_per_token(
                     self.cfg),
+                # What one SEQUENCE costs beside its tokens, whatever
+                # its length: a recurrent state a slot (0: none).
+                'state_bytes_per_slot': self._ops.state_bytes_per_slot(
+                    self.cfg),
                 # Drop-free expert models: (token, choice) pairs the
                 # decode chunks routed, and the busiest and the mean
                 # expert's share of them (None: no such experts).
@@ -1084,6 +1109,7 @@ class ContinuousEngine:
                 'pipeline': {
                     'pipeline_depth': self.pipeline_depth,
                     'dispatches': self.dispatches,
+                    'decode_steps': self.decode_steps,
                     'dispatch_gap_ms': round(
                         self._gap_ms_total / max(self._gap_count, 1),
                         3),
@@ -1852,7 +1878,7 @@ class ContinuousEngine:
         chunk = row[consumed:consumed + w]
         padded = np.zeros((1, w), np.int32)
         padded[0, :len(chunk)] = chunk
-        logits, cache1 = gen_lib._jit_prefill(  # noqa: SLF001 — same pkg
+        logits, cache1 = model_ops.ops_for(cfg).prefill(
             params, padded, cache1, cfg,
             np.asarray([len(chunk)], np.int32))
         if params is self.params:  # draft-model chunks don't count
@@ -1865,8 +1891,12 @@ class ContinuousEngine:
     def _advance_prefill(self) -> None:
         if not self._prefilling:
             return
-        t0 = time.perf_counter()
         had_active = any(r is not None for r in self._slot_req)
+        if (self._trim_chunks and had_active
+                and not self._prefilling[0].parked
+                and self._steps_since_piece < self.chunk_steps):
+            return  # the live rows' chunk_steps come first
+        t0 = time.perf_counter()
         with profiler.span('engine.advance_prefill'):
             try:
                 self._advance_prefill_impl()
@@ -1930,8 +1960,8 @@ class ContinuousEngine:
                     with self._lock:
                         self.share_misses += 1
             if cache1 is None:
-                cache1 = gen_lib.init_cache(self.cfg, 1, self.max_len,
-                                            quantize=self.kv_quantize)
+                cache1 = self._ops.init_cache(self.cfg, 1, self.max_len,
+                                              quantize=self.kv_quantize)
             entry.cache, entry.consumed = cache1, p_hit
             req.timeline.saved_tokens = p_hit
             if spec:
@@ -1940,6 +1970,7 @@ class ContinuousEngine:
                     quantize=self.kv_quantize)
         logits, entry.cache, entry.consumed = self._prefill_one_chunk(
             self.params, self.cfg, entry.cache, req.row, entry.consumed)
+        self._steps_since_piece = 0
         with self._lock:
             self.prefill_chunks += 1
         if entry.consumed >= n:
@@ -1973,11 +2004,16 @@ class ContinuousEngine:
                 or gen_lib.truncate_at_stop([entry.first_host],
                                             req.eos)[1])
         slot = None
-        table_row = None
+        table_row = np.zeros((self.max_len // self.kv_block,), np.int32)
         with self._lock:
+            free = [i for i, r in enumerate(self._slot_req) if r is None]
+            if done and free:
+                # As the group prefill does: a request that ends at its
+                # first token still goes in, as junk through the junk
+                # sink in a still-free slot, so the shape's insert has
+                # run (or compiled) once a one-token request has.
+                slot = free[0]
             if not done:
-                free = [i for i, r in enumerate(self._slot_req)
-                        if r is None]
                 nb = self._blocks_needed(req)
                 if not free or self._blocks_avail() < nb:
                     return  # park until a completion frees a slot/blocks
@@ -1985,8 +2021,6 @@ class ContinuousEngine:
                 # reaches _fail_everything, which rebuilds the device
                 # state and the whole block pool)
                 blocks = self._alloc_blocks(nb)
-                table_row = np.zeros(
-                    (self.max_len // self.kv_block,), np.int32)
                 table_row[:nb] = blocks
                 slot = free[0]
                 self._slot_req[slot] = req
@@ -1998,11 +2032,13 @@ class ContinuousEngine:
             req.timeline.first = time.perf_counter()
             self.tokens_emitted += 1
         self._emit([(req, [entry.first_host])], [req] if done else [])
-        if done:
+        if done and slot is None:
             return
-        self._cache = paged_lib.jit_insert(
+        self._cache = self._ops.insert_paged(
             self._cache, entry.cache, np.asarray(table_row[None]),
             np.asarray([slot], np.int32))
+        if done:
+            return
         self._last = self._last.at[
             jnp.asarray([slot], jnp.int32)].set(entry.first)
         if self._trie is not None:
@@ -2039,7 +2075,7 @@ class ContinuousEngine:
             self._slot_req[slot] = req
             self._slot_blocks[slot] = list(blocks)
             self._slot_table[slot] = table_row.copy()
-        self._cache = paged_lib.jit_insert(
+        self._cache = self._ops.insert_paged(
             self._cache, entry.cache, np.asarray(table_row[None]),
             np.asarray([slot], np.int32))
         if self._trie is not None:
@@ -2600,12 +2636,39 @@ class ContinuousEngine:
                             ms=round(bubble_closed_ms, 3),
                             edge='dispatch')
         tk, tp = _filters_or_none(top_ks, top_ps)
-        self._cache, self._last, toks, counts = self._ops.paged_chunk(
+        if self._trim_chunks:
+            steps = self._steps_to_first_finish(reqs)
+            self._steps_since_piece += steps
+            chunk, tail = self._ops.paged_chunk_n, np.int32(steps)
+        else:
+            steps = self.chunk_steps
+            chunk, tail = self._ops.paged_chunk, self._shard_ctx
+        with self._lock:
+            self.decode_steps += steps
+        self._cache, self._last, toks, counts = chunk(
             self.cfg, self.chunk_steps, self.params, self._cache,
             self._last, np.asarray(temps), tk, tp,
-            np.asarray(active), self._next_key(), self._shard_ctx)
-        return _Inflight(reqs=reqs, toks=toks, steps=self.chunk_steps,
-                         counts=counts)
+            np.asarray(active), self._next_key(), tail)
+        return _Inflight(reqs=reqs, toks=toks, steps=steps, counts=counts)
+
+    # skylint: engine-thread
+    def _steps_to_first_finish(self, reqs: List[Optional[_Request]]) -> int:
+        """Steps until the first of ``reqs`` reaches its ``max_new``,
+        counting what the chunk in flight will have given it; at most
+        ``chunk_steps``. A row that ends in the chunk in flight decodes
+        junk here and does not count; with no other row, one junk step
+        (dispatch and retirement alternate). A stop id can only end a
+        row sooner: its slot then frees at the retirement, as ever."""
+        flight = self._inflight
+        with self._lock:
+            # a first token sampled at the prefill and not yet fetched
+            unfetched = {id(r) for rs, _ in self._unfetched for r in rs}
+            owed = [r.max_new - len(r.tokens) - (id(r) in unfetched)
+                    - (flight.steps if flight is not None
+                       and flight.reqs[i] is r else 0)
+                    for i, r in enumerate(reqs) if r is not None]
+        owed = [n for n in owed if n > 0]
+        return min(min(owed), self.chunk_steps) if owed else 1
 
     # skylint: engine-thread
     def _note_decode_quiet(self) -> None:

@@ -31,6 +31,19 @@ What differs from ``models/llama.py``'s block, and where it lives:
 The pool rides the layer scans as a CARRY and the kernel takes the
 whole pool plus a layer index: nothing slices a plane out of it, so the
 step makes no copy of the pool.
+
+**A per-layer kind** (``KdaMlaMoeConfig``; Kimi-Linear-48B-A3B is its
+numbers): a layer's mixer is latent attention (``'mla'``) or Kimi Delta
+Attention (``'kda'``, ``models/kda.py``), by the published list. The
+parameters are stacked by RUN of equal layers, one ``lax.scan`` a run,
+and two kinds of cache ride the scans side by side: the latent pool,
+with a plane for each MLA layer only, and a float32 state
+``[L_kda, rows, H, dk, dv]`` with the convolutions' tails
+``[L_kda, rows, cw - 1, 3 H dk]``, a row a SLOT whatever its length
+(``LatentStatePool`` / ``StateKVCache``). Both are written in place at
+the layer's index among the layers of its kind. Such a model's MLA may
+have no query down-projection (``q_lora_rank`` None) and no rotary
+(``rope`` False).
 """
 from __future__ import annotations
 
@@ -42,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from skypilot_tpu.models import moe, sampling
+from skypilot_tpu.models import kda, moe, paged, sampling
 from skypilot_tpu.models.generate import KVCache
 from skypilot_tpu.models.llama import rms_norm
 from skypilot_tpu.models.paged import PagedKVCache, pool_view, pool_write
@@ -64,7 +77,7 @@ class MlaMoeConfig:
     n_layers: int = 40
     n_dense_layers: int = 2         # leading layers with a dense SwiGLU
     n_heads: int = 32
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768    # None: q straight from x
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -83,6 +96,7 @@ class MlaMoeConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    rope: bool = True               # False: nothing is rotated (NoPE)
     rope_theta: float = 10_000.0
     # YaRN (factor 1 = plain rotary): (factor, original length,
     # beta_fast, beta_slow, mscale, mscale_all_dim)
@@ -95,6 +109,14 @@ class MlaMoeConfig:
     @property
     def n_moe_layers(self) -> int:
         return self.n_layers - self.n_dense_layers if self.num_experts else 0
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        """Each layer's mixer: ``'mla'`` here, every one."""
+        return ('mla',) * self.n_layers
+
+    def n_kind(self, kind: str) -> int:
+        return self.kinds.count(kind)
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -111,8 +133,9 @@ class MlaMoeConfig:
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """What the cache must hold: ``latent_dim`` numbers a layer."""
-        return (self.n_layers * self.latent_dim
+        """What the cache must hold a token: ``latent_dim`` numbers a
+        latent-attention layer."""
+        return (self.n_kind('mla') * self.latent_dim
                 * jnp.dtype(self.dtype).itemsize)
 
     def __post_init__(self):
@@ -120,6 +143,36 @@ class MlaMoeConfig:
             raise ValueError('without experts every layer is dense: '
                              f'n_dense_layers {self.n_dense_layers} != '
                              f'n_layers {self.n_layers}')
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaMlaMoeConfig(MlaMoeConfig):
+    """``MlaMoeConfig`` with a per-layer kind: the layers in
+    ``kda_layers`` (0-based) mix by Kimi Delta Attention, the others by
+    latent attention. Its own TYPE because its cache is two caches
+    (``model_ops``)."""
+    kda_layers: Tuple[int, ...] = ()
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_gate_rank: int = 128        # of the decay's and the output gate's
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return tuple('kda' if i in self.kda_layers else 'mla'
+                     for i in range(self.n_layers))
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """What the cache must hold a SEQUENCE, whatever its length: a
+        state and the convolutions' tails a KDA layer."""
+        return self.n_kind('kda') * kda.state_bytes_per_row(self)
+
+    def __post_init__(self):
+        super().__post_init__()
+        if not all(0 <= i < self.n_layers for i in self.kda_layers):
+            raise ValueError(f'kda_layers {self.kda_layers} outside the '
+                             f'{self.n_layers} layers')
 
 
 TINY = MlaMoeConfig(
@@ -132,7 +185,7 @@ TINY = MlaMoeConfig(
 # -- params -----------------------------------------------------------------
 
 
-def _layer_shapes(cfg: MlaMoeConfig, moe_layer: bool
+def _layer_shapes(cfg: MlaMoeConfig, moe_layer: bool, kind: str = 'mla'
                   ) -> Dict[str, Tuple[tuple, Any, float]]:
     """``name -> (shape, logical axes, fan_in)`` of one layer's leaves
     (fan_in 0 = a norm's weight; the stacked trees add a leading
@@ -147,17 +200,25 @@ def _layer_shapes(cfg: MlaMoeConfig, moe_layer: bool
             out[f'hc_{sub}_alpha'] = ((3,), (None,), 1.0)
             out[f'hc_{sub}_bias'] = ((m,), (None,), 1.0)
         out[f'{sub}_norm'] = ((d,), (None,), 0)
-    out.update({
-        'wq_a': ((d, cfg.q_lora_rank), ('embed', None), d),
-        'q_norm': ((cfg.q_lora_rank,), (None,), 0),
-        'wq_b': ((cfg.q_lora_rank, h, qk), (None, 'heads', 'head_dim'),
-                 cfg.q_lora_rank),
-        'wkv_a': ((d, cfg.latent_dim), ('embed', None), d),
-        'kv_norm': ((cfg.kv_lora_rank,), (None,), 0),
-        'wkv_b': ((cfg.kv_lora_rank, h, cfg.qk_nope_dim + cfg.v_head_dim),
-                  (None, 'heads', 'head_dim'), cfg.kv_lora_rank),
-        'wo': ((h, cfg.v_head_dim, d), ('heads', 'head_dim', 'embed'),
-               h * cfg.v_head_dim)})
+    if kind == 'kda':
+        out.update(kda.layer_shapes(cfg))
+    else:
+        if cfg.q_lora_rank:
+            out.update({
+                'wq_a': ((d, cfg.q_lora_rank), ('embed', None), d),
+                'q_norm': ((cfg.q_lora_rank,), (None,), 0),
+                'wq_b': ((cfg.q_lora_rank, h, qk),
+                         (None, 'heads', 'head_dim'), cfg.q_lora_rank)})
+        else:
+            out['wq'] = ((d, h, qk), ('embed', 'heads', 'head_dim'), d)
+        out.update({
+            'wkv_a': ((d, cfg.latent_dim), ('embed', None), d),
+            'kv_norm': ((cfg.kv_lora_rank,), (None,), 0),
+            'wkv_b': ((cfg.kv_lora_rank, h,
+                       cfg.qk_nope_dim + cfg.v_head_dim),
+                      (None, 'heads', 'head_dim'), cfg.kv_lora_rank),
+            'wo': ((h, cfg.v_head_dim, d), ('heads', 'head_dim', 'embed'),
+                   h * cfg.v_head_dim)})
     if not moe_layer:
         f = cfg.d_ff
         out.update({'w_gate': ((d, f), ('embed', 'mlp'), d),
@@ -181,13 +242,24 @@ def _layer_shapes(cfg: MlaMoeConfig, moe_layer: bool
 
 
 def _stacks(cfg: MlaMoeConfig):
-    """(name, layers, is-expert-stack) of the stacks the model has."""
-    out = []
-    if cfg.n_dense_layers:
-        out.append(('dense', cfg.n_dense_layers, False))
-    if cfg.n_moe_layers:
-        out.append(('moe', cfg.n_moe_layers, True))
-    return out
+    """(name, layers, is-expert-stack, kind) of the stacks the model
+    has, in layer order: one for each RUN of layers with the same
+    mixer and the same feed-forward, so that each run is one scan. A
+    model of one kind has the two it always had (``dense``, ``moe``);
+    otherwise a run is named ``<first layer>_<kind>_<dense|moe>``."""
+    is_moe = [bool(cfg.num_experts) and i >= cfg.n_dense_layers
+              for i in range(cfg.n_layers)]
+    runs = []
+    for i, (kind, m) in enumerate(zip(cfg.kinds, is_moe)):
+        if runs and runs[-1][2:] == [m, kind]:
+            runs[-1][1] += 1
+        else:
+            runs.append([i, 1, m, kind])
+    if set(cfg.kinds) == {'mla'}:
+        return [('moe' if m else 'dense', n, m, kind)
+                for _, n, m, kind in runs]
+    return [(f'{i}_{kind}_{"moe" if m else "dense"}', n, m, kind)
+            for i, n, m, kind in runs]
 
 
 def init_params(key: jax.Array, cfg: MlaMoeConfig) -> Params:
@@ -204,8 +276,8 @@ def init_params(key: jax.Array, cfg: MlaMoeConfig) -> Params:
         'embed': draw(jax.random.fold_in(key, 0), (cfg.vocab_size, d), 1.0),
         'final_norm': jnp.ones((d,), cfg.dtype),
         'lm_head': draw(jax.random.fold_in(key, 1), (d, cfg.vocab_size), d)}
-    for si, (name, n_l, is_moe) in enumerate(_stacks(cfg)):
-        shapes = _layer_shapes(cfg, is_moe)
+    for si, (name, n_l, is_moe, kind) in enumerate(_stacks(cfg)):
+        shapes = _layer_shapes(cfg, is_moe, kind)
         ks = jax.random.split(jax.random.fold_in(key, 2 + si), len(shapes))
         out[name] = {leaf: draw(k, (n_l,) + shape, fan_in)
                      for k, (leaf, (shape, _, fan_in))
@@ -217,9 +289,9 @@ def param_logical_axes(cfg: MlaMoeConfig) -> Params:
     """Logical sharding axes matching ``init_params``' tree."""
     out: Params = {'embed': ('vocab', 'embed'), 'final_norm': (None,),
                    'lm_head': ('embed', 'vocab')}
-    for name, _, is_moe in _stacks(cfg):
+    for name, _, is_moe, kind in _stacks(cfg):
         out[name] = {leaf: ('layers',) + axes for leaf, (_, axes, _)
-                     in _layer_shapes(cfg, is_moe).items()}
+                     in _layer_shapes(cfg, is_moe, kind).items()}
     return out
 
 
@@ -252,7 +324,7 @@ def softmax_scale(cfg: MlaMoeConfig) -> float:
     stay unscaled when ``mscale == mscale_all_dim``, which is asserted)."""
     factor, _, _, _, mscale, all_dim = cfg.rope_yarn
     scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-    if factor == 1.0:
+    if factor == 1.0 or not cfg.rope:
         return scale
     if mscale != all_dim:
         raise NotImplementedError('YaRN with mscale != mscale_all_dim '
@@ -262,7 +334,10 @@ def softmax_scale(cfg: MlaMoeConfig) -> float:
 
 
 def _rope(x: jax.Array, positions: jax.Array, cfg: MlaMoeConfig):
-    """x [B, S, ..., Dr], positions [B, S]; rotate-half."""
+    """x [B, S, ..., Dr], positions [B, S]; rotate-half (``cfg.rope``
+    False: x as it is)."""
+    if not cfg.rope:
+        return x
     half = x.shape[-1] // 2
     ang = positions[..., None].astype(jnp.float32) * _inv_freq(cfg)
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (half,))
@@ -340,9 +415,12 @@ def _q_and_latent(cfg: MlaMoeConfig, h: jax.Array, layer: Params,
                   positions: jax.Array):
     """h [B, S, d] -> (q [B, S, H, nope + rope] with rotary applied,
     latent rows [B, S, W]: ``RMSNorm(c_kv) | rope(k_rope) | 0``)."""
-    c_q = rms_norm(jnp.einsum('bsd,dr->bsr', h, layer['wq_a']),
-                   layer['q_norm'], cfg.norm_eps)
-    q = jnp.einsum('bsr,rhk->bshk', c_q, layer['wq_b'])
+    if 'wq' in layer:       # no query down-projection
+        q = jnp.einsum('bsd,dhk->bshk', h, layer['wq'])
+    else:
+        c_q = rms_norm(jnp.einsum('bsd,dr->bsr', h, layer['wq_a']),
+                       layer['q_norm'], cfg.norm_eps)
+        q = jnp.einsum('bsr,rhk->bshk', c_q, layer['wq_b'])
     nope = cfg.qk_nope_dim
     q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], positions,
                                               cfg)], -1)
@@ -486,16 +564,20 @@ def _wo(att: jax.Array, layer: Params) -> jax.Array:
 
 
 def _layers(cfg: MlaMoeConfig, params: Params, xs: jax.Array, cache_arr,
-            attend, token_mask):
-    """Both stacks over the residual ``xs`` [B, S, n, d]. ``cache_arr``
-    ([L, ...], every layer of the model) rides as a carry;
-    ``attend(h, layer, cache_arr, l) -> (att [B, S, d], cache_arr)`` is
-    the caller's cache strategy. -> (xs, cache_arr, expert counts [E]).
+            mixers, token_mask):
+    """Every stack over the residual ``xs`` [B, S, n, d]. ``cache_arr``
+    (any pytree: the latent planes [L_mla, ...], and the KDA state
+    beside them where there are such layers) rides as a carry;
+    ``mixers[kind](h, layer, cache_arr, l) -> (y [B, S, d], cache_arr)``
+    is the caller's cache strategy for a layer of that kind, ``l`` the
+    layer's index among the layers of its KIND (which is where its
+    part of the cache lies). -> (xs, cache_arr, expert counts [E]).
     The routed experts' weights are NOT scanned: every layer is handed
     the whole stack and its index in it (``moe.dropfree_mlp``)."""
     load0 = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
-    l0 = 0
-    for name, n_l, _ in _stacks(cfg):
+    seen = {kind: 0 for kind in mixers}
+    for name, n_l, _, kind in _stacks(cfg):
+        l0, mix = seen[kind], mixers[kind]
         whole = {k: v for k, v in params[name].items()
                  if k in ('we_gate', 'we_up', 'we_down')}
         scanned = {k: v for k, v in params[name].items() if k not in whole}
@@ -507,7 +589,7 @@ def _layers(cfg: MlaMoeConfig, params: Params, xs: jax.Array, cache_arr,
             if whole:
                 layer = dict(layer, stack_layer=i, **whole)
             xs, arr = _sublayer(cfg, xs, layer, 'attn',
-                                lambda h: attend(h, layer, arr, l))
+                                lambda h: mix(h, layer, arr, l))
             xs, cnt = _sublayer(cfg, xs, layer, 'mlp',
                                 lambda h: _ffn(cfg, h, layer, token_mask))
             return (xs, arr, load if cnt is None else load + cnt), None
@@ -515,7 +597,7 @@ def _layers(cfg: MlaMoeConfig, params: Params, xs: jax.Array, cache_arr,
         (xs, cache_arr, load0), _ = jax.lax.scan(
             body, (xs, cache_arr, load0),
             (scanned, jnp.arange(n_l, dtype=jnp.int32)))
-        l0 += n_l
+        seen[kind] += n_l
     return xs, cache_arr, load0
 
 
@@ -546,18 +628,82 @@ def _head(cfg: MlaMoeConfig, params: Params, xs: jax.Array,
 # -- dense cache: prefill (and the window path's decode) ---------------------
 
 
+@dataclasses.dataclass
+class StateKVCache(KVCache):
+    """The dense latent cache with, beside it, what the KDA layers keep
+    a row: ``state`` [L_kda, B, H, dk, dv] float32 and ``conv``
+    [L_kda, B, cw - 1, 3 H dk] (``kda.py``)."""
+    state: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
+
+
+@dataclasses.dataclass
+class LatentStatePool(PagedKVCache):
+    """The latent pool with the KDA layers' state beside it, a row a
+    SLOT: what the block table pages is the MLA layers' alone."""
+    state: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
+
+
+for _cls in (StateKVCache, LatentStatePool):
+    jax.tree_util.register_dataclass(
+        _cls, data_fields=[f.name for f in dataclasses.fields(_cls)],
+        meta_fields=[])
+
+
+def _state_fields(cfg: MlaMoeConfig, rows: int) -> Dict[str, jax.Array]:
+    n = cfg.n_kind('kda')
+    return {'state': jnp.zeros((n,) + kda.state_shape(cfg, rows),
+                               jnp.float32),
+            'conv': jnp.zeros((n,) + kda.tail_shape(cfg, rows), cfg.dtype)}
+
+
+def _carry(cache):
+    """What of a cache rides the layer scans: (latent planes, KDA
+    state, convolution tails), the last two None where the model has
+    no such layer (empty pytrees: the scans carry what they did)."""
+    return cache.k, getattr(cache, 'state', None), getattr(cache, 'conv',
+                                                           None)
+
+
+def _kda_mixer(cfg: MlaMoeConfig, row_lens: Optional[jax.Array],
+               live: Optional[jax.Array]):
+    """``_layers``' mixer for a KDA layer over the carry of ``_carry``:
+    the one-token recurrence for S == 1, else the chunked form
+    continuing the row's state; the row's first ``row_lens`` positions
+    are real, rows not ``live`` leave their state alone."""
+    def mix(h, layer, carry, l):
+        arr, state, conv = carry
+        if h.shape[1] == 1:
+            alive = jnp.ones(h.shape[:1], bool) if live is None else live
+            y, s_new, c_new = kda.step(cfg, h[:, 0], layer, state[l],
+                                       conv[l], alive)
+            y = y[:, None]
+        else:
+            if row_lens is None:
+                raise ValueError('a KDA layer over more than one position '
+                                 'needs each row\'s length (row_lens): its '
+                                 'state is taken there')
+            y, s_new, c_new = kda.forward(cfg, h, layer, state[l], conv[l],
+                                          row_lens, live)
+        return y, (arr, state.at[l].set(s_new), conv.at[l].set(c_new))
+    return mix
+
+
 def init_cache(cfg: MlaMoeConfig, batch: int, max_len: int, dtype=None,
                kv_sharding=None, lengths_sharding=None,
                quantize: bool = False, kv_scale_sharding=None) -> KVCache:
-    """The dense latent cache [L, B, 1, max_len, W]: ``KVCache`` with
-    ONE plane (``v`` None)."""
+    """The dense latent cache [L_mla, B, 1, max_len, W]: ``KVCache``
+    with ONE plane (``v`` None); with KDA layers, their state too."""
     if quantize:
         raise ValueError('the latent (MLA) cache has no int8 mode')
-    shape = (cfg.n_layers, batch, 1, max_len, cfg.latent_width)
-    return KVCache(k=jnp.zeros(shape, dtype or cfg.dtype,
-                               device=kv_sharding), v=None,
-                   lengths=jnp.zeros((batch,), jnp.int32,
-                                     device=lengths_sharding))
+    shape = (cfg.n_kind('mla'), batch, 1, max_len, cfg.latent_width)
+    k = jnp.zeros(shape, dtype or cfg.dtype, device=kv_sharding)
+    lengths = jnp.zeros((batch,), jnp.int32, device=lengths_sharding)
+    if cfg.n_kind('kda'):
+        return StateKVCache(k=k, v=None, lengths=lengths,
+                            **_state_fields(cfg, batch))
+    return KVCache(k=k, v=None, lengths=lengths)
 
 
 def forward_cached(params: Params, tokens: jax.Array, cache: KVCache,
@@ -581,7 +727,8 @@ def forward_cached(params: Params, tokens: jax.Array, cache: KVCache,
     if active_rows is not None:
         token_mask = token_mask & active_rows[:, None]
 
-    def attend(h, layer, arr, l):
+    def attend(h, layer, carry, l):
+        arr = carry[0]
         q, latent = _q_and_latent(cfg, h, layer, positions)
         rows = jax.vmap(lambda c, n, st: jax.lax.dynamic_update_slice(
             c, n, (st, 0)))(arr[l, :, 0], latent.astype(arr.dtype), start)
@@ -594,12 +741,17 @@ def forward_cached(params: Params, tokens: jax.Array, cache: KVCache,
                 layer)[:, None]
         else:
             att = _attend_view(cfg, q, rows, layer, positions, valid)
-        return _wo(att, layer), arr
+        return _wo(att, layer), (arr,) + carry[1:]
 
-    xs, arr, _ = _layers(cfg, params, _embed(cfg, params, tokens), cache.k,
-                         attend, token_mask)
+    mixers = {'mla': attend, 'kda': _kda_mixer(cfg, row_lens, active_rows)}
+    xs, (arr, state, conv), _ = _layers(
+        cfg, params, _embed(cfg, params, tokens), _carry(cache), mixers,
+        token_mask)
     logits = _head(cfg, params, xs, row_lens - 1, all_logits)
-    return logits, KVCache(k=arr, v=None, lengths=valid)
+    if state is None:
+        return logits, KVCache(k=arr, v=None, lengths=valid)
+    return logits, StateKVCache(k=arr, v=None, lengths=valid, state=state,
+                                conv=conv)
 
 
 jit_prefill = profiled_jit('mla_moe.prefill', forward_cached,
@@ -612,18 +764,39 @@ jit_prefill = profiled_jit('mla_moe.prefill', forward_cached,
 def init_pool(cfg: MlaMoeConfig, slots: int, max_len: int, n_blocks: int,
               block: int, quantize: bool = False, kv_sharding=None,
               scale_sharding=None, lengths_sharding=None) -> PagedKVCache:
-    """``paged.init_pool`` with ONE latent plane [L, NB, 1, P, W]
-    (``v`` None). Block 0 is the junk sink, as ever."""
+    """``paged.init_pool`` with ONE latent plane [L_mla, NB, 1, P, W]
+    (``v`` None). Block 0 is the junk sink, as ever. With KDA layers,
+    their state a slot beside it (``LatentStatePool``)."""
     if quantize:
         raise ValueError('the latent (MLA) pool has no int8 mode')
     if block < 1 or block & (block - 1) or max_len % block:
         raise ValueError(f'block size {block} must be a power of two that '
                          f'divides max_len {max_len}')
-    shape = (cfg.n_layers, n_blocks, 1, block, cfg.latent_width)
-    return PagedKVCache(
+    shape = (cfg.n_kind('mla'), n_blocks, 1, block, cfg.latent_width)
+    fields = dict(
         k=jnp.zeros(shape, cfg.dtype, device=kv_sharding), v=None,
         tables=jnp.zeros((slots, max_len // block), jnp.int32),
         lengths=jnp.zeros((slots,), jnp.int32, device=lengths_sharding))
+    if cfg.n_kind('kda'):
+        return LatentStatePool(**fields, **_state_fields(cfg, slots))
+    return PagedKVCache(**fields)
+
+
+def _insert_impl(pool: LatentStatePool, cache_n: StateKVCache,
+                 tables_new: jax.Array, slots: jax.Array) -> LatentStatePool:
+    """``paged._insert_impl`` for a pool with state: the rows' latent
+    blocks through their tables, and each row's FINAL state and tails
+    (taken at the row's own length by the prefill) into its slot,
+    whatever the slot held."""
+    kv = paged._insert_impl(pool, cache_n, tables_new, slots)
+    return LatentStatePool(
+        k=kv.k, v=None, tables=kv.tables, lengths=kv.lengths,
+        state=pool.state.at[:, slots].set(cache_n.state),
+        conv=pool.conv.at[:, slots].set(cache_n.conv))
+
+
+jit_insert = profiled_jit('mla_moe.insert', _insert_impl,
+                          donate_argnums=(0,))
 
 
 def _pool_view(pool: jax.Array, l, tables: jax.Array) -> jax.Array:
@@ -652,14 +825,15 @@ def forward_paged(params: Params, tokens: jax.Array, cache: PagedKVCache,
     path = decode_path(tables.shape, cache.k.shape, cache.k.dtype)
     live = jnp.ones((b,), bool) if active_rows is None else active_rows
 
-    def attend(h, layer, pool, l):
+    def attend(h, layer, carry, l):
+        pool, rest = carry[0], carry[1:]
         q, latent = _q_and_latent(cfg, h, layer, positions)
         pool = pool_write(pool, l, tables, lengths, latent[:, None],
                           active_rows)
         if s > 1:
             att = _attend_view(cfg, q, _pool_view(pool, l, tables), layer,
                                positions, lengths + s)
-            return _wo(att, layer), pool
+            return _wo(att, layer), (pool,) + rest
         q_abs = _absorb(cfg, q[:, 0], layer)
         # inactive rows read nothing: their stale tables may name
         # blocks that now belong to another request
@@ -672,13 +846,21 @@ def forward_paged(params: Params, tokens: jax.Array, cache: PagedKVCache,
         else:
             o_lat = _absorbed_view(cfg, q_abs, _pool_view(pool, l, tables),
                                    valid)
-        return _wo(_unabsorb(cfg, o_lat, layer)[:, None], layer), pool
+        return (_wo(_unabsorb(cfg, o_lat, layer)[:, None], layer),
+                (pool,) + rest)
 
-    xs, pool, load = _layers(cfg, params, _embed(cfg, params, tokens),
-                             cache.k, attend, token_mask)
+    real = None if logit_index is None else logit_index + 1
+    mixers = {'mla': attend, 'kda': _kda_mixer(cfg, real, active_rows)}
+    xs, (pool, state, conv), load = _layers(
+        cfg, params, _embed(cfg, params, tokens), _carry(cache), mixers,
+        token_mask)
     logits = _head(cfg, params, xs, logit_index)
-    return logits, PagedKVCache(k=pool, v=None, tables=tables,
-                                lengths=lengths + s), load
+    if state is None:
+        return logits, PagedKVCache(k=pool, v=None, tables=tables,
+                                    lengths=lengths + s), load
+    return logits, LatentStatePool(k=pool, v=None, tables=tables,
+                                   lengths=lengths + s, state=state,
+                                   conv=conv), load
 
 
 def _prefill_shared_impl(cfg: MlaMoeConfig, params, cache: PagedKVCache,
@@ -728,3 +910,34 @@ def _paged_chunk_impl(cfg: MlaMoeConfig, k_steps: int, params, cache,
 jit_paged_chunk = profiled_jit('mla_moe.paged_chunk', _paged_chunk_impl,
                                static_argnums=(0, 1, 10),
                                donate_argnums=(3, 4))
+
+
+def _paged_chunk_n_impl(cfg: MlaMoeConfig, k_steps: int, params, cache,
+                        last: jax.Array, temps: jax.Array, top_ks, top_ps,
+                        active: jax.Array, key: jax.Array,
+                        n_steps: jax.Array):
+    """``_paged_chunk_impl`` that stops after ``n_steps`` <= K steps (a
+    device scalar: ONE program for every length). Step ``i`` draws with
+    the key the full chunk gives it, so the first ``n_steps`` rows of
+    ``toks[K, B]`` are the full chunk's; the rest stay zero. Pool and
+    state ride the loop's carry in place, as they ride the scan's."""
+    keys = jax.random.split(key, k_steps)
+
+    def step(i, carry):
+        cache, last, load, toks = carry
+        logits, cache, cnt = forward_paged(params, last[:, None], cache,
+                                           cfg, active)
+        nxt = sampling.sample(logits, temps, keys[i], top_ks, top_ps)
+        return cache, nxt, load + cnt, toks.at[i].set(nxt)
+
+    load0 = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
+    toks0 = jnp.zeros((k_steps,) + last.shape, last.dtype)
+    cache, last, load, toks = jax.lax.fori_loop(
+        0, n_steps, step, (cache, last, load0, toks0))
+    return cache, last, toks, load
+
+
+jit_paged_chunk_n = profiled_jit('mla_moe.paged_chunk_n',
+                                 _paged_chunk_n_impl,
+                                 static_argnums=(0, 1),
+                                 donate_argnums=(3, 4))

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,11 +32,17 @@ class ModelOps:
     ``paged_chunk(cfg, k, params, pool, last, temps, top_ks, top_ps,
     active, key, shard_ctx) -> (pool, last, toks, counts)`` where
     ``counts`` is None or the experts' token counts [E] of the chunk;
+    ``paged_chunk_n``: None, or ``paged_chunk`` with ``n_steps`` (a
+    scalar <= k) in place of ``shard_ctx``: the chunk stops there;
     ``forward_cached``: the un-jitted dense forward (``generate``'s
     window path scans it).
 
     ``decode_attention(pool, quantized) -> str``: how the S = 1 step
     reads the pool (``stats()['decode_attention']``).
+    ``state_bytes_per_slot(cfg)``: what a sequence keeps beside its
+    paged rows, whatever its length (a recurrent state a slot).
+    ``prefill_chunk(cfg)``: the family's default piece of a chunked
+    long prefill (0: none).
     ``rows_couple(cfg)``: True where co-batched rows influence each
     other (capacity-dropping experts): pipelining, chunked prefill,
     block sharing, speculation and KV handoff all need independent
@@ -54,6 +60,16 @@ class ModelOps:
     decode_attention: Callable
     kv_bytes_per_token: Callable[[Any], int]
     rows_couple: Callable[[Any], bool]
+    # Bytes a SEQUENCE costs the cache whatever its length (a recurrent
+    # state a slot, beside what a token costs): 0 where a sequence is
+    # its keys and values alone.
+    state_bytes_per_slot: Callable[[Any], int] = lambda cfg: 0
+    # The piece (tokens) a long prompt's prefill advances by between
+    # decode chunks where neither the caller nor the environment says
+    # (``ContinuousEngine(prefill_chunk=)``): 0, the whole prompt in
+    # one group prefill, unless the family names a piece of its own.
+    prefill_chunk: Callable[[Any], int] = lambda cfg: 0
+    paged_chunk_n: Optional[Callable] = None
     refuses: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     def refuse(self, feature: str) -> None:
@@ -118,7 +134,47 @@ def _mla_moe() -> ModelOps:
                                   'head-sharded pool or kernel'})
 
 
-_BUILDERS = {'LlamaConfig': _llama, 'MlaMoeConfig': _mla_moe}
+# Eight of ``kda.CHUNK``. On a v5e at Kimi-Linear's widths a piece is
+# 28 ms beside a decode chunk of 41 ms (8 steps): a live slot's token
+# gap is bounded near 2 x the undisturbed one, where 1024 and 2048
+# left the 90th percentile swinging with the arrivals (PERF.md, PR 33).
+KDA_PREFILL_CHUNK = 512
+
+
+def _kda_mla_moe() -> ModelOps:
+    """``mla_moe``'s programs over two caches (the layers are of two
+    kinds, ``mla_moe.KdaMlaMoeConfig``): the insert carries a row's
+    state into its slot, and everything that takes a prefix to BE its
+    blocks is refused: a prefix of this model is blocks and a state.
+    A long prompt is prefilled in PIECES by default: a piece continues
+    the scratch row's state and tails (a KDA layer re-reads nothing of
+    what went before), the group prefill runs its rows one at a time
+    anyway, and an unchunked prefill of thousands of tokens stalls
+    every live slot (PERF.md, PR 33: ``tpot_p90_ms`` swung by 20%
+    with the arrival order). With pieces, a chunk ends with its first
+    row to finish (``paged_chunk_n``): a short answer's last tokens do
+    not wait out the chunk's junk steps and the piece behind it."""
+    from skypilot_tpu.models import mla_moe
+    state = ('a sequence is its latent blocks AND a recurrent state a '
+             'KDA layer; no state is kept at block boundaries, so a '
+             'prefix cannot be rebuilt from blocks')
+    base = _mla_moe()
+    return dataclasses.replace(
+        base, name='kda_mla_moe', insert_paged=mla_moe.jit_insert,
+        state_bytes_per_slot=lambda cfg: cfg.state_bytes_per_slot,
+        prefill_chunk=lambda cfg: KDA_PREFILL_CHUNK,
+        paged_chunk_n=mla_moe.jit_paged_chunk_n,
+        # the chunked long prefill seeds its scratch row from shared
+        # blocks only where there is a trie: not here
+        refuses=dict({k: v for k, v in base.refuses.items()
+                      if k != 'prefill_chunk'}, **{
+            'prefix sharing': state,
+            'kv_tiers': state,
+            'KV handoff': state}))
+
+
+_BUILDERS = {'LlamaConfig': _llama, 'MlaMoeConfig': _mla_moe,
+             'KdaMlaMoeConfig': _kda_mla_moe}
 
 
 @functools.lru_cache(maxsize=None)

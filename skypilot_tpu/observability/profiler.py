@@ -152,10 +152,20 @@ PROGRAMS: Tuple[Program, ...] = (
             'Suffix prefill directly over the latent pool (the '
             'block-share hit path): one shape per tail bucket.',
             budget=12),
+    Program('mla_moe.insert',
+            'paged.insert for a pool with a recurrent state beside it '
+            '(KDA layers): the rows\' latent blocks and each row\'s final '
+            'state into its slot; one shape per prompt bucket x '
+            'admission-group size.', budget=24),
     Program('mla_moe.paged_chunk',
             'The K-step decode chunk over the latent pool (absorbed '
             'attention, drop-free experts); also returns the experts\' '
             'token counts.', budget=4),
+    Program('mla_moe.paged_chunk_n',
+            'mla_moe.paged_chunk that stops after n <= K steps (n a '
+            'device scalar, so one shape): where a long prompt goes in '
+            'pieces between the chunks, a chunk ends with its first '
+            'row to finish.', budget=4),
     # -- models/speculative.py ----------------------------------------
     Program('spec.propose',
             'k+1 greedy draft proposal steps (solo speculative '

@@ -75,6 +75,10 @@ def test_dump_bundle_contents(tmp_path, monkeypatch):
     blackbox.record('engine.retire', emitted=4, max_new=4)
 
     from skypilot_tpu.observability import trace as trace_lib
+    # Roots this test does not own: another file's server thread can
+    # hold a live one in the same xdist worker (``trace_lib.reset()``
+    # cannot close it). They are left out of the comparison below.
+    foreign = {t['trace_id'] for t in trace_lib.open_spans()}
     with trace_lib.start_trace('unit.open_span'):
         path = blackbox.dump('manual', reason='unit test')
     assert path is not None and os.path.basename(path).startswith(
@@ -88,7 +92,8 @@ def test_dump_bundle_contents(tmp_path, monkeypatch):
     # The last /health snapshot rides along.
     assert b['health'] == {'status': 'ok', 'queue': {'depth_total': 3}}
     # Open (unfinished) trace spans are frozen in.
-    assert [t['name'] for t in b['traces']['open']] == ['unit.open_span']
+    assert [t['name'] for t in b['traces']['open']
+            if t['trace_id'] not in foreign] == ['unit.open_span']
     # faulthandler all-thread stacks.
     assert 'Current thread' in b['stacks'] or 'Thread 0x' in b['stacks']
     # Declared env flags present, secrets masked to presence.

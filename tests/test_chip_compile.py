@@ -352,3 +352,160 @@ def test_mla_prefill_takes_the_flash_kernel_at_the_cells_widest(one_chip,
     hlo = compiled.as_text()
     assert re.search(r'%flash_fwd[.\d]* = .*custom-call\(', hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+# -- the hybrid (KDA + MLA) cell: kimi-linear-docs-steady --------------------
+
+# 48 slots, max_len 4096 in blocks of 16 out of a pool of 12,289 for ONE
+# latent layer; beside it a float32 state [32, 128, 128] a slot a KDA layer.
+KDA_CELL = dict(slots=48, max_blocks=256, block=16, blocks=12289)
+
+
+def _kda_cfg():
+    """Four layers at Kimi-Linear-48B-A3B's widths: KDA + dense, KDA +
+    experts, MLA + experts, KDA + experts; 128 of 256 experts held."""
+    from skypilot_tpu.models import mla_moe
+    return mla_moe.KdaMlaMoeConfig(
+        vocab_size=1024, d_model=2304, n_layers=4, n_dense_layers=1,
+        n_heads=32, q_lora_rank=None, d_ff=9216, num_experts=256,
+        expert_top_k=8, routed_scale=2.446, experts_held=(0, 128),
+        hc_mult=1, rope=False, rope_yarn=(1.0, 0, 0.0, 0.0, 1.0, 1.0),
+        norm_eps=1e-5, max_seq_len=32768, kda_layers=(0, 1, 3))
+
+
+def _top_level(hlo):
+    """(name, result type, op) of every instruction that is not inside
+    a fused computation: what has a buffer of its own."""
+    out = []
+    for block in re.split(r'\n(?=(?:ENTRY )?%[\w.-]+ \([^\n]*\) -> [^\n]*\{\n)',
+                          hlo):
+        if 'fused_computation' in block.split('\n', 1)[0]:
+            continue
+        out += re.findall(r'^\s*(?:ROOT )?(%[\w.-]+) = (\S+) ([\w-]+)\(',
+                          block, re.M)
+    return out
+
+
+@pytest.mark.parametrize('trimmed', [False, True])
+def test_kda_decode_step_carries_state_and_pool_in_place(one_chip,
+                                                         monkeypatch,
+                                                         trimmed):
+    """The decode chunk of the hybrid model as the engine builds it: the
+    state [L_kda, 48, 32, 128, 128] float32 and the tails ride the step
+    scan and the layer scans as carries and are updated at the layer's
+    index in place (a state that is ``xs``/``ys`` of a scan is copied
+    whole every step: PR 29's lesson); no layer's state [48, 32, 128,
+    128] has a buffer of its own; the latent pool has ONE layer and
+    reaches ``mla_decode`` whole; results alias the donated cache.
+    ``trimmed``: the same of the chunk that stops after ``n_steps`` (a
+    loop whose trip count is a device scalar, not a scan)."""
+    from skypilot_tpu.models import mla_moe
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg = _kda_cfg()
+    assert cfg.kinds == ('kda', 'kda', 'mla', 'kda')
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    c = KDA_CELL
+    slots = c['slots']
+    params = on_chip(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: mla_moe.init_pool(
+        cfg, slots, c['max_blocks'] * c['block'], c['blocks'], c['block'])))
+    assert pool.k.shape == (1, 12289, 1, 16, 640)
+    assert pool.state.shape == (3, 48, 32, 128, 128)
+    assert pool.conv.shape == (3, 48, 3, 12288)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (slots,), dtype,
+                                    sharding=one_chip)
+
+    if trimmed:
+        compiled = mla_moe.jit_paged_chunk_n.lower(
+            cfg, 8, params, pool, vec(jnp.int32), vec(jnp.float32), None,
+            None, vec(jnp.bool_), vec(jnp.uint32, 2),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    else:
+        compiled = mla_moe.jit_paged_chunk.lower(
+            cfg, 2, params, pool, vec(jnp.int32), vec(jnp.float32), None,
+            None, vec(jnp.bool_), vec(jnp.uint32, 2), None).compile()
+    hlo = compiled.as_text()
+    whole, layer = r'f32\[3,48,32,128,128\]', r'f32\[48,32,128,128\]'
+    for name, shape, op in _top_level(hlo):
+        assert not re.match(layer, shape), (name, shape, op)
+        if re.match(whole, shape):
+            # a carry handed on, or a fusion that updates it in place
+            assert op in ('parameter', 'get-tuple-element', 'fusion',
+                          'bitcast'), (name, shape, op)
+            if op == 'fusion':
+                assert 'dynamic-update-slice' in name or re.search(
+                    re.escape(name) + r' = [^\n]*kind=kLoop', hlo), name
+    stats = compiled.memory_analysis()
+    kept = sum(x.size * x.dtype.itemsize for x in (pool.k, pool.state,
+                                                   pool.conv))
+    assert stats.alias_size_in_bytes >= kept
+    assert stats.temp_size_in_bytes < 0.2e9      # no second state (0.3 GB)
+    assert re.search(r'%mla_decode[.\d]* = [^\n]*custom-call\(', hlo)
+    assert set(re.findall(r'bf16\[1,12289,1,16,640\]\{([\d,]+)', hlo)) == {
+        '4,3,2,1,0'}
+    assert 'kernel-fallback' not in hlo
+
+
+def test_kda_group_prefill_fits_beside_the_weights(one_chip, monkeypatch):
+    """A group of 4 rows padded to 4,096: the MLA layer takes the flash
+    kernel, and the KDA layers go a row at a time, so the chunked form's
+    float32 temporaries are one row's (4.5 GB for the group at once,
+    which 9.3 GB of weights leave no room for)."""
+    from skypilot_tpu.models import mla_moe
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg = _kda_cfg()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: mla_moe.init_cache(cfg, 4, 4096)))
+    compiled = mla_moe.jit_prefill.lower(
+        params, jax.ShapeDtypeStruct((4, 4096), jnp.int32, sharding=one_chip),
+        cache, cfg, jax.ShapeDtypeStruct((4,), jnp.int32,
+                                         sharding=one_chip)).compile()
+    assert re.search(r'%flash_fwd[.\d]* = .*custom-call\(', compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_kda_prefill_piece_continues_a_scratch_row(one_chip, monkeypatch):
+    """One piece of the chunked long prefill as the engine runs it: 512
+    tokens of ONE row continuing a scratch row 4,096 wide (state, tails
+    and the latent rows so far). The MLA layer attends over the row's
+    view (no flash kernel: that is the fresh prefill's), the map over
+    rows is gone for the one row (the only loops left are the layer scan
+    and the KDA layers' chunk scans), and the temporaries are a fraction
+    of the group prefill's."""
+    from skypilot_tpu.models import mla_moe, model_ops
+    monkeypatch.setattr(attention, '_use_pallas', lambda: True)
+    cfg = _kda_cfg()
+    piece = model_ops.ops_for(cfg).prefill_chunk(cfg)
+    assert piece == 512
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: mla_moe.init_cache(cfg, 1, 4096)))
+    compiled = mla_moe.jit_prefill.lower(
+        params, jax.ShapeDtypeStruct((1, piece), jnp.int32,
+                                     sharding=one_chip),
+        cache, cfg, jax.ShapeDtypeStruct((1,), jnp.int32,
+                                         sharding=one_chip)).compile()
+    hlo = compiled.as_text()
+    assert 'flash_fwd' not in hlo
+    # layers 0 | 1 | 2 | 3 are runs of their own here (KDA + dense, KDA +
+    # experts, MLA, KDA + experts): three chunk scans, no scan of rows
+    assert len(re.findall(r' while\(', hlo)) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
