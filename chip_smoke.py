@@ -23,8 +23,8 @@ line; any phase not ``ok`` makes the exit code non-zero):
                 through paged_decode (which writes the step's K/V row
                 too); SIGTERM -> drain -> exit 0.
   kernels       each Pallas kernel compiled (not interpreted) against its
-                jnp reference (paged_decode and mla_decode at the benchmark
-                cells' pool geometries) and the train step's HLO searched
+                jnp reference (paged_decode, mla_decode and kda_step at the
+                benchmark cells' geometries) and the train step's HLO searched
                 for the Mosaic call.
   launch-local  execution.launch(Task(run='python -m ...train.run'),
                 cloud='local'): the orchestrator's own path.
@@ -71,7 +71,10 @@ REAL = dict(model='bench-1b', tp_model='bench-1b', vocab=32768,
                        hq=16, hkv=8, d=128),
             # xing-docs-sessions' latent pool: 8,193 blocks of 16,
             # max_len 4096, 32 heads over one 512 + 64 row, bf16.
-            mla=dict(slots=48, blocks=8193, block=16, max_blocks=256))
+            mla=dict(slots=48, blocks=8193, block=16, max_blocks=256),
+            # kimi-linear-docs-steady's state: four KDA layers x 48
+            # slots x 32 heads of [128, 128] float32.
+            kda=dict(layers=4, slots=48, heads=32, d=128))
 # tiny-mh: 8 kv heads, so --tp 4 divides them. The interpreter cannot
 # afford the kernels' real VMEM caps, so the rehearsal names small ones.
 REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
@@ -81,7 +84,8 @@ REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
                  paged=dict(slots=4, blocks=33, block=16, max_blocks=8,
                             hq=4, hkv=2, d=128),
                  mla=dict(slots=4, blocks=33, block=16, max_blocks=8,
-                          heads=4, rank=96, rope=16))
+                          heads=4, rank=96, rope=16),
+                 kda=dict(layers=2, slots=4, heads=2, d=128))
 
 
 def remaining() -> float:
@@ -784,9 +788,43 @@ def child_kernels(rehearse: bool, meshes) -> int:
              and not np.asarray(got, np.float32)[~live].any(),
              err=round(err, 5), tol=tol)
 
+    def kda_case(layers, slots, heads, d):
+        """``kda_step`` on the last layer of a state, every other slot
+        live, against the plain XLA recurrence (``kda.recur``): float32
+        on both sides, so to rounding; slots not live and the other
+        layers to the bit."""
+        from skypilot_tpu.models import kda
+        ks = jax.random.split(jax.random.PRNGKey(slots), 6)
+        state = jax.random.normal(ks[0], (layers, slots, heads, d, d))
+        q, k = (kda._l2(jax.random.normal(ks[i], (slots, heads, d)))
+                for i in (1, 2))
+        v = jax.random.normal(ks[3], (slots, heads, d))
+        g = -jnp.exp(jax.random.normal(ks[4], (slots, heads, d)) - 1.0)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[5], (slots, heads)))
+        live = np.arange(slots) % 2 == 0
+        assert interpret or decode_attention.kda_fits(state.shape,
+                                                      state.dtype)
+        # skylint: allow-jit(one-shot numerics check, not a program)
+        got_o, got_s = jax.jit(lambda *a: decode_attention.kda_step(
+            a[0], jnp.int32(layers - 1), *a[1:], interpret=interpret))(
+            state, q, k, v, g, beta, jnp.asarray(live))
+        # skylint: allow-jit(one-shot numerics check, not a program)
+        want_o, want_s = jax.jit(kda.recur)(state[-1], q, k, v, g, beta)
+        err = max(rel_err(np.asarray(got_o)[live], np.asarray(want_o)[live]),
+                  rel_err(np.asarray(got_s[-1])[live],
+                          np.asarray(want_s)[live]))
+        untouched = bool(
+            jnp.array_equal(got_s[:-1], state[:-1])
+            and jnp.array_equal(got_s[-1][~live], state[-1][~live]))
+        emit(f'kda_step L{layers} B{slots} H{heads} D{d} f32',
+             np.isfinite(err) and err <= 1e-5 and untouched
+             and not np.asarray(got_o)[~live].any(),
+             err=float(f'{err:.3g}'), tol=1e-5, untouched=untouched)
+
     hq, hkv, d = c['hq'], c['hkv'], c['d']
     guarded('paged_decode', lambda: paged_case(**c['paged']))
     guarded('mla_decode', lambda: mla_case(**c['mla']))
+    guarded('kda_step', lambda: kda_case(**c['kda']))
     for s in c['flash_seqs']:
         guarded(f'flash S{s}', lambda s=s: flash_case(2, hq, hkv, s, d))
     # The VMEM cap itself, as the code has it: one group.

@@ -1055,6 +1055,10 @@ class ContinuousEngine:
                 # (through the block table, by length) or 'gather'
                 # (every slot's whole max_len into a dense view).
                 'decode_attention': self.decode_attention,
+                # The family's further rules for its decode program,
+                # each under its own key (a recurrent state's one-token
+                # step: 'kernel' or 'xla'); none for most.
+                **self._step_paths,
                 # Handoff accounting (serve/disagg.py): exports are
                 # prefill-role retirements, imports are decode-role
                 # admissions of transferred tables; queued_imports is
@@ -1309,6 +1313,7 @@ class ContinuousEngine:
             'gather' if self.draft_cfg is not None
             else self._ops.decode_attention(self._cache,
                                             self.kv_quantize))
+        self._step_paths = self._ops.step_paths(self._cache)
         self._last = jnp.zeros((self.slots,), jnp.int32, device=vec)
         self._d_cache = None
         if self.draft_cfg is not None:

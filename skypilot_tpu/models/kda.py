@@ -17,9 +17,12 @@ Per token ``t`` of one sequence, per head (``dk = dv = kda_head_dim``):
   ``o = S^T q``.
 * ``y = (RMSNorm_head(o) * sigmoid((x W_g_a) W_g_b)) W_o``.
 
-TWO FORMS of the same recurrence. ``step``: one token a row (decode);
-two passes over the state (the decayed state's two products, then the
-rank-one update), float32 on the VPU. ``forward``: a whole block of
+TWO FORMS of the same recurrence. ``step_layer``: one token a row
+(decode), float32 on the VPU: on a TPU ``decode_attention.kda_step``,
+which reads and writes the LIVE rows' state once, in place in the
+carried [L, B, H, dk, dv] (``step_path`` is the rule); elsewhere
+``recur``, plain XLA over the layer's slice (the decayed state's two
+products, then the rank-one update). ``forward``: a whole block of
 tokens (prefill) in chunks of ``CHUNK``: with ``G`` the decay's running
 sum inside a chunk, ``A_ij = sum_c k_ic k_jc exp(G_ic - G_jc)`` (j < i)
 makes the chunk's updates the solution of a unit lower-triangular
@@ -44,6 +47,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from skypilot_tpu.ops import attention as attention_ops
+from skypilot_tpu.ops import decode_attention
 
 Params = Dict[str, Any]
 CHUNK = 64      # positions a chunk of the prefill form
@@ -142,10 +148,32 @@ def _out(cfg, o: jax.Array, h: jax.Array, layer: Params) -> jax.Array:
 # -- decode: one token a row --------------------------------------------------
 
 
-def step(cfg, h: jax.Array, layer: Params, state: jax.Array,
-         tail: jax.Array, live: jax.Array):
-    """h [B, d] -> (y [B, d], state [B, H, dk, dv], tail [B, cw-1, C]).
-    Rows that are not ``live`` leave state and tail as they were."""
+def step_path(state_shape, dtype) -> str:
+    """How the one-token recurrence runs over a state [..., H, dk, dv]:
+    ``'kernel'`` (``ops/decode_attention.kda_step``: the live rows'
+    state once, in place) or ``'xla'`` (``recur`` over the layer's slice
+    of every row). ``paged.decode_path``'s rule: a TPU, or the
+    interpreter where a test asks for it by name, and a state the
+    kernel takes (float32, whole tiles). The ONE definition:
+    ``step_layer`` branches on it and the engine reports it
+    (``stats()['kda_step']``)."""
+    if not (attention_ops._use_pallas()
+            or decode_attention.PAGED_INTERPRET):
+        return 'xla'
+    if decode_attention.kda_fits(state_shape, dtype):
+        return 'kernel'
+    attention_ops.log_fallback_once(
+        'kda_step', state_shape[-3:],
+        f'a {jnp.dtype(dtype).name} state of heads {state_shape[-2:]} '
+        f'outside kda_fits()')
+    return 'xla'
+
+
+def step_inputs(cfg, h: jax.Array, layer: Params, tail: jax.Array,
+                live: jax.Array):
+    """h [B, d] after ``tail`` [B, cw-1, C] -> (q, k, v, g [B, H, dk],
+    beta [B, H], all float32, g and beta 0 where not ``live``; the
+    tail, moved on for live rows)."""
     with jax.named_scope('kda.proj'):
         x = jnp.einsum('bd,dc->bc', h, layer['kda_wqkv'])
     with jax.named_scope('kda.conv'):
@@ -156,6 +184,13 @@ def step(cfg, h: jax.Array, layer: Params, state: jax.Array,
         tail = jnp.where(live[:, None, None], window[:, 1:], tail)
     q, k, v = _qkv(cfg, y)
     g, beta = _gates(cfg, h, layer, live)
+    return q, k, v, g, beta, tail
+
+
+def recur(state: jax.Array, q, k, v, g, beta):
+    """The recurrence's one step in plain XLA: state [B, H, dk, dv] ->
+    (o [B, H, dv], state). ``g = beta = 0`` leaves a row's state bit
+    for bit."""
     with jax.named_scope('kda.step'):
         s = state * jnp.exp(g)[..., None]
         sk = jnp.sum(s * k[..., None], axis=-2)              # S^T k
@@ -163,7 +198,36 @@ def step(cfg, h: jax.Array, layer: Params, state: jax.Array,
         u = beta[..., None] * (v - sk)
         state = s + k[..., None] * u[..., None, :]
         o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * u
-    return _out(cfg, o, h, layer), state, tail
+    return o, state
+
+
+def step_layer(cfg, h: jax.Array, layer: Params, states: jax.Array,
+               tails: jax.Array, l, live: jax.Array):
+    """One token a row through KDA layer ``l`` (its index among the KDA
+    layers; may be traced) of the WHOLE carried ``states`` [L, B, H, dk,
+    dv] and ``tails`` [L, B, cw-1, C]: h [B, d] -> (y [B, d], states,
+    tails). Rows that are not ``live`` leave state and tail as they
+    were. Where ``step_path`` says so the kernel updates the live rows'
+    state inside ``states`` (no layer is sliced out or put back)."""
+    q, k, v, g, beta, tail = step_inputs(cfg, h, layer, tails[l], live)
+    if step_path(states.shape, states.dtype) == 'kernel':
+        with jax.named_scope('kda.step'):
+            o, states = decode_attention.kda_step(
+                states, l, q, k, v, g, beta, live,
+                interpret=not attention_ops._use_pallas())
+    else:
+        o, state = recur(states[l], q, k, v, g, beta)
+        states = states.at[l].set(state)
+    return _out(cfg, o, h, layer), states, tails.at[l].set(tail)
+
+
+def step(cfg, h: jax.Array, layer: Params, state: jax.Array,
+         tail: jax.Array, live: jax.Array):
+    """``step_layer`` over one layer's own state: h [B, d] -> (y [B, d],
+    state [B, H, dk, dv], tail [B, cw-1, C])."""
+    y, states, tails = step_layer(cfg, h, layer, state[None], tail[None], 0,
+                                  live)
+    return y, states[0], tails[0]
 
 
 # -- prefill: chunks ----------------------------------------------------------

@@ -676,16 +676,15 @@ def _kda_mixer(cfg: MlaMoeConfig, row_lens: Optional[jax.Array],
         arr, state, conv = carry
         if h.shape[1] == 1:
             alive = jnp.ones(h.shape[:1], bool) if live is None else live
-            y, s_new, c_new = kda.step(cfg, h[:, 0], layer, state[l],
-                                       conv[l], alive)
-            y = y[:, None]
-        else:
-            if row_lens is None:
-                raise ValueError('a KDA layer over more than one position '
-                                 'needs each row\'s length (row_lens): its '
-                                 'state is taken there')
-            y, s_new, c_new = kda.forward(cfg, h, layer, state[l], conv[l],
-                                          row_lens, live)
+            y, state, conv = kda.step_layer(cfg, h[:, 0], layer, state, conv,
+                                            l, alive)
+            return y[:, None], (arr, state, conv)
+        if row_lens is None:
+            raise ValueError('a KDA layer over more than one position '
+                             'needs each row\'s length (row_lens): its '
+                             'state is taken there')
+        y, s_new, c_new = kda.forward(cfg, h, layer, state[l], conv[l],
+                                      row_lens, live)
         return y, (arr, state.at[l].set(s_new), conv.at[l].set(c_new))
     return mix
 

@@ -39,6 +39,9 @@ class ModelOps:
 
     ``decode_attention(pool, quantized) -> str``: how the S = 1 step
     reads the pool (``stats()['decode_attention']``).
+    ``step_paths(pool) -> {stats key: answer}``: the family's further
+    rules for its decode program, each reported under its own key (a
+    recurrent state: how its one-token step runs).
     ``state_bytes_per_slot(cfg)``: what a sequence keeps beside its
     paged rows, whatever its length (a recurrent state a slot).
     ``prefill_chunk(cfg)``: the family's default piece of a chunked
@@ -64,6 +67,7 @@ class ModelOps:
     # state a slot, beside what a token costs): 0 where a sequence is
     # its keys and values alone.
     state_bytes_per_slot: Callable[[Any], int] = lambda cfg: 0
+    step_paths: Callable[[Any], Dict[str, str]] = lambda pool: {}
     # The piece (tokens) a long prompt's prefill advances by between
     # decode chunks where neither the caller nor the environment says
     # (``ContinuousEngine(prefill_chunk=)``): 0, the whole prompt in
@@ -154,7 +158,7 @@ def _kda_mla_moe() -> ModelOps:
     with the arrival order). With pieces, a chunk ends with its first
     row to finish (``paged_chunk_n``): a short answer's last tokens do
     not wait out the chunk's junk steps and the piece behind it."""
-    from skypilot_tpu.models import mla_moe
+    from skypilot_tpu.models import kda, mla_moe
     state = ('a sequence is its latent blocks AND a recurrent state a '
              'KDA layer; no state is kept at block boundaries, so a '
              'prefix cannot be rebuilt from blocks')
@@ -162,6 +166,8 @@ def _kda_mla_moe() -> ModelOps:
     return dataclasses.replace(
         base, name='kda_mla_moe', insert_paged=mla_moe.jit_insert,
         state_bytes_per_slot=lambda cfg: cfg.state_bytes_per_slot,
+        step_paths=lambda pool: {'kda_step': kda.step_path(
+            pool.state.shape, pool.state.dtype)},
         prefill_chunk=lambda cfg: KDA_PREFILL_CHUNK,
         paged_chunk_n=mla_moe.jit_paged_chunk_n,
         # the chunked long prefill seeds its scratch row from shared

@@ -1,4 +1,4 @@
-"""Pallas decode attention: one new token against the KV cache.
+"""Pallas decode kernels: one new token against what a slot has cached.
 
 Reference analog: the reference's serving engines carry fused decode
 attention kernels (JetStream's pallas kernels, vLLM's paged attention);
@@ -36,6 +36,14 @@ the slots hold, and the gather + einsum before PR 26 2.7 ms.
 ``mla_decode`` — the same walk over a latent (MLA) pool in the absorbed
 form: one plane of ``c_kv | k_rope`` rows serves as keys and values
 (``models/mla_moe.decode_path`` is its rule).
+
+``kda_step`` — no attention: the one-token recurrence of a Kimi Delta
+Attention layer (``models/kda.py``), whose cache is a float32 STATE
+[H, dk, dv] a slot, not keys and values. The WHOLE carried state
+[L, slots, H, dk, dv] stays in HBM, aliased input -> output like the
+pools; a live slot's state comes in once, is decayed and updated on the
+VPU in float32, and goes back once; a slot that is not live is neither
+read nor written (``models/kda.step_path`` is its rule).
 """
 from __future__ import annotations
 
@@ -68,11 +76,11 @@ PAGED_SMEM_CAP_BYTES = 256 * 1024
 PAGED_INTERPRET = False
 
 
-def _pick_group(max_blocks: int) -> int:
-    """Largest divisor of ``max_blocks`` that is <= PAGED_GROUP, so a
-    group never reads past the end of a table row."""
-    g = min(PAGED_GROUP, max_blocks)
-    while max_blocks % g:
+def _pick_group(n: int, cap: int = PAGED_GROUP) -> int:
+    """Largest divisor of ``n`` that is <= ``cap``: a group of blocks
+    never reads past the end of a table row of ``n``."""
+    g = min(cap, n)
+    while n % g:
         g -= 1
     return g
 
@@ -388,3 +396,154 @@ def mla_decode(q: jax.Array, pool: jax.Array, layer: jax.Array,
             dimension_semantics=('arbitrary',)),
         interpret=interpret, name='mla_decode',
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), tables, valid, q, pool)
+
+
+# ---------------------------------------------------------------------------
+# KDA decode step: the one-token delta-rule recurrence over the state of
+# the LIVE rows, in place.
+
+# Heads a DMA: 8 x [128, 128] float32 = 512 KB. A live row's state comes
+# in as H / 8 copies started together and goes back group by group while
+# the next group is computed; a group's heads are unrolled together. On a
+# v5e at 48 slots x 32 heads (PR 34), 4 / 8 / 16 / 32 read 16.1 / 17.4 /
+# 17.7 / 19.9 us a call with one row live and 383 / 385 / 435 / 540 with
+# all 48 (XLA's three passes: 477 whatever is live).
+KDA_HEADS_PER_DMA = 8
+# Rows a program (the grid is B / this): a row that is not live costs a
+# loop turn, not a grid step with its block copies.
+KDA_ROWS = 8
+
+
+def kda_fits(state_shape, dtype) -> bool:
+    """True when ``kda_step`` can take a state [..., H, dk, dv]: float32
+    (the recurrence's precision IS the state's), and a head's [dk, dv]
+    a whole number of (8, 128) tiles with ``dk`` a whole number of lanes
+    too (the step's k, q and decay arrive with dk on the lanes and are
+    turned onto the sublanes a head at a time)."""
+    dk, dv = state_shape[-2:]
+    return (jnp.dtype(dtype) == jnp.float32 and dk % 128 == 0
+            and dv % 128 == 0)
+
+
+def _kda_kernel(layer_ref, live_ref, cols_ref, rows_ref, _s_in, o_ref,
+                s_hbm, buf, sem, *, group: int):
+    """``KDA_ROWS`` rows a program, one after the other. cols_ref
+    [R, H, 3, dk]: a row's decay ``exp g``, k and q a head; rows_ref
+    [R, H, 3, dv]: v, beta and k . q, the last two repeated along dv;
+    o_ref [R, H, dv]. s_hbm the WHOLE state [L, B, H, dk, dv], left in
+    HBM (the OUTPUT ref: ``_s_in`` is the same buffer, aliased);
+    layer_ref [1] and live_ref [B] scalar-prefetched. A live row's
+    [H, dk, dv] comes into ``buf`` by DMA in groups of ``group`` heads,
+    is updated there head by head and goes back group by group; a row
+    that is not live starts no copy. The heads are LOOPS, not Python
+    unrolling: 32 bodies to trace and lower in every program that holds
+    the kernel cost every start-up seconds (PERF.md, PR 34)."""
+    i = pl.program_id(0)
+    n_rows = cols_ref.shape[0]
+    h = buf.shape[0]
+    n_groups = h // group
+
+    def copy(b, g, back):
+        heads = pl.ds(g * group, group)
+        hbm = s_hbm.at[layer_ref[0], b, heads]
+        if back:
+            return pltpu.make_async_copy(buf.at[heads], hbm, sem.at[1, g])
+        return pltpu.make_async_copy(hbm, buf.at[heads], sem.at[0, g])
+
+    def row(r, carry):
+        b = i * n_rows + r
+
+        def heads(g, carry):
+            def head(j, carry):
+                hh = g * group + j
+                # a head's vectors lie along the state's dk, the
+                # sublanes: [3, dk] -> [dk, 3], a column each
+                cols = cols_ref[r, hh].T
+                a, k, q = (cols[:, n:n + 1] for n in range(3))
+                v, beta, kq = (rows_ref[r, hh, n:n + 1, :] for n in range(3))
+                # models/kda.recur, in its order: decay, the decayed
+                # state's two products, the rank-one update
+                s = buf[hh] * a
+                sk = jnp.sum(s * k, axis=0, keepdims=True)  # S^T k [1, dv]
+                sq = jnp.sum(s * q, axis=0, keepdims=True)
+                u = beta * (v - sk)
+                buf[hh] = s + k * u
+                o_ref[r, pl.ds(hh, 1), :] = sq + kq * u
+                return carry
+
+            copy(b, g, False).wait()
+            # traced once, unrolled where Mosaic lowers the loop: the
+            # group's heads overlap in the schedule
+            jax.lax.fori_loop(0, group, head, 0, unroll=True)
+            copy(b, g, True).start()
+            return carry
+
+        @pl.when(live_ref[b] == 0)
+        def _():
+            o_ref[r] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+        @pl.when(live_ref[b] != 0)
+        def _():
+            for g in range(n_groups):
+                copy(b, g, False).start()
+            jax.lax.fori_loop(0, n_groups, heads, 0)
+            # the next live row refills the buffer: this one is out first
+            for g in range(n_groups):
+                copy(b, g, True).wait()
+        return carry
+
+    jax.lax.fori_loop(0, n_rows, row, 0)
+
+
+def kda_step(state: jax.Array, layer: jax.Array, q: jax.Array,
+             k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+             live: jax.Array, interpret: bool = False):
+    """One token a row of the KDA recurrence (``models/kda.recur``) over
+    layer ``layer`` (int32 scalar: the index among the KDA layers) of
+    the WHOLE state [L, B, H, dk, dv] float32. q, k, g [B, H, dk],
+    v [B, H, dv], beta [B, H] float32 as ``kda.step_inputs`` makes them;
+    live [B] bool. For a live row and head: ``S <- Diag(exp g) S``,
+    ``u = beta (v - S^T k)``, ``S <- S + k u^T``, ``o = S^T q``, every
+    product and sum float32 on the VPU; its [dk, dv] is read once and
+    written once. A row that is not live is NEITHER READ NOR WRITTEN
+    (its state stays bit for bit, whatever g and beta say) and its ``o``
+    is ZEROS (the XLA form gives ``S^T q`` of the untouched state there;
+    the engine drops either). -> (o [B, H, dv], state): the state is
+    aliased to its operand, in place under a scan that carries it.
+    Callers gate on ``kda_fits``."""
+    b, h, dk = q.shape
+    dv = v.shape[-1]
+    if state.shape[1:] != (b, h, dk, dv):
+        raise ValueError(f'state {state.shape} is not [L, {b}, {h}, {dk}, '
+                         f'{dv}]: a row of q, k, v a row of the state')
+    n_rows = _pick_group(b, KDA_ROWS)
+    group = _pick_group(h, KDA_HEADS_PER_DMA)
+    f32 = jnp.float32
+    cols = jnp.stack([jnp.exp(g.astype(f32)), k, q], 2).astype(f32)
+    wide = lambda x: jnp.broadcast_to(x[..., None], (b, h, dv))  # noqa: E731
+    rows = jnp.stack([v, wide(beta), wide(jnp.sum(k * q, axis=-1))],
+                     2).astype(f32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b // n_rows,),
+        in_specs=[
+            pl.BlockSpec((n_rows, h, 3, dk), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((n_rows, h, 3, dv), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec((n_rows, h, dv), lambda i, *_: (i, 0, 0)),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        # a row's whole state; [reads | write-backs, group]
+        scratch_shapes=[pltpu.VMEM((h, dk, dv), f32),
+                        pltpu.SemaphoreType.DMA((2, h // group))])
+    o, state = pl.pallas_call(
+        functools.partial(_kda_kernel, group=group),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), f32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operands count the scalar-prefetched two
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        interpret=interpret, name='kda_step',
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), live.astype(jnp.int32),
+      cols, rows, state)
+    return o, state

@@ -394,8 +394,11 @@ def test_kda_decode_step_carries_state_and_pool_in_place(one_chip,
     state [L_kda, 48, 32, 128, 128] float32 and the tails ride the step
     scan and the layer scans as carries and are updated at the layer's
     index in place (a state that is ``xs``/``ys`` of a scan is copied
-    whole every step: PR 29's lesson); no layer's state [48, 32, 128,
-    128] has a buffer of its own; the latent pool has ONE layer and
+    whole every step: PR 29's lesson): ``decode_attention.kda_step``
+    takes the whole state and a layer index and returns it aliased; no
+    layer's state [48, 32, 128, 128] has a buffer of its own, none is
+    sliced out in front of the call or put back behind it (PR 34: three
+    XLA passes over all 48 slots went); the latent pool has ONE layer and
     reaches ``mla_decode`` whole; results alias the donated cache.
     ``trimmed``: the same of the chunk that stops after ``n_steps`` (a
     loop whose trip count is a device scalar, not a scan)."""
@@ -435,13 +438,27 @@ def test_kda_decode_step_carries_state_and_pool_in_place(one_chip,
     whole, layer = r'f32\[3,48,32,128,128\]', r'f32\[48,32,128,128\]'
     for name, shape, op in _top_level(hlo):
         assert not re.match(layer, shape), (name, shape, op)
+        # the whole state is a carry handed on and nothing else: no
+        # fusion reads or updates it, nothing copies it
         if re.match(whole, shape):
-            # a carry handed on, or a fusion that updates it in place
-            assert op in ('parameter', 'get-tuple-element', 'fusion',
-                          'bitcast'), (name, shape, op)
-            if op == 'fusion':
-                assert 'dynamic-update-slice' in name or re.search(
-                    re.escape(name) + r' = [^\n]*kind=kLoop', hlo), name
+            assert op in ('parameter', 'get-tuple-element', 'bitcast'), (
+                name, shape, op)
+    # ... but for ``kda_step`` (PR 34), one call a KDA layer, which
+    # takes it as it lies and hands it back aliased
+    defs = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r'(%[\w.-]+) = (\S+) ([\w-]+)\(', hlo)}
+    calls = re.findall(r'%kda_step[.\d]* = (\([^\n]*?\)) custom-call\('
+                       r'([^)]*)\)([^\n]*)', hlo)
+    assert len(calls) == 3, len(calls)
+    for result, operands, rest in calls:
+        assert re.fullmatch(r'\(f32\[48,32,128\]\S*, ' + whole + r'\S*\)',
+                            result), result
+        assert re.search(r'output_to_operand_aliasing=\{\{1\}: \(4, \{\}\)\}',
+                         rest), rest[:300]
+        shape, op = defs[operands.split(',')[-1].strip()]
+        assert re.match(whole, shape), shape
+        assert op in ('parameter', 'get-tuple-element', 'bitcast'), op
+    assert set(re.findall(whole + r'\{([\d,]+)', hlo)) == {'4,3,2,1,0'}
     stats = compiled.memory_analysis()
     kept = sum(x.size * x.dtype.itemsize for x in (pool.k, pool.state,
                                                    pool.conv))
@@ -451,6 +468,35 @@ def test_kda_decode_step_carries_state_and_pool_in_place(one_chip,
     assert set(re.findall(r'bf16\[1,12289,1,16,640\]\{([\d,]+)', hlo)) == {
         '4,3,2,1,0'}
     assert 'kernel-fallback' not in hlo
+
+
+def test_kda_step_compiles_at_the_cells_geometry(one_chip):
+    """48 slots x 32 heads of [128, 128] float32, four layers: the
+    row's [32, 128, 128] buffer (2 MB) and the blocks of eight rows fit
+    VMEM, a head's decay, k and q turn from lanes onto sublanes by a
+    [3, 128] transpose Mosaic takes, and the state is operand 4 and
+    result 1 of one call."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, h, d = KDA_CELL['slots'], 32, 128
+    assert decode_attention.kda_fits((4, b, h, d, d), jnp.float32)
+    vec = sds((b, h, d))
+    # skylint: allow-jit(test-only compile check)
+    compiled = jax.jit(lambda *a: decode_attention.kda_step(*a),
+                       donate_argnums=(0,)).lower(
+        sds((4, b, h, d, d)), sds((), jnp.int32), vec, vec, vec, vec,
+        sds((b, h)), sds((b,), jnp.bool_)).compile()
+    hlo = compiled.as_text()
+    call = re.search(r'%kda_step[.\d]* = [^\n]*custom-call\(([^)]*)\)'
+                     r'([^\n]*)', hlo)
+    assert call, 'no kda_step call'
+    assert re.search(r'output_to_operand_aliasing=\{\{1\}: \(4, \{\}\)\}',
+                     call.group(2))
+    assert not re.search(r'= f32\[4,48,32,128,128\]\S* (copy|fusion)\(', hlo)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 4 * b * h * d * d * 4
+    assert stats.temp_size_in_bytes < 16e6
 
 
 def test_kda_group_prefill_fits_beside_the_weights(one_chip, monkeypatch):

@@ -44,7 +44,9 @@ def test_rehearsal_passes_end_to_end():
     assert phases['launch-local']['framework_processes_left'] == []
     assert phases['launch-local']['gang_runner'] in ('native gangd',
                                                      'python')
-    assert len(phases['kernels']['cases']) == 8
+    assert len(phases['kernels']['cases']) == 9
+    assert sum('kda_step' in c['case']
+               for c in phases['kernels']['cases']) == 1
     assert not any('flash_decode' in c['case']
                    for c in phases['kernels']['cases'])
     assert lines[-1] == {'ok': True, 'rehearsal': True, 'device': {

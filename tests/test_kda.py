@@ -17,6 +17,7 @@ import pytest
 from benchmarks import manifest, weights
 from skypilot_tpu.models import engine as engine_lib
 from skypilot_tpu.models import generate, kda, llama, mla_moe, model_ops
+from skypilot_tpu.ops import decode_attention
 
 DATA = os.path.join(os.path.dirname(__file__), 'benchmarks', 'data')
 TOL = 2e-4
@@ -149,6 +150,36 @@ def test_prefill_then_decode_through_state_and_pool_gives_the_references_logits(
                                    np.arange(n_prompt - 1, total), cfg)
     assert float(jnp.max(jnp.abs(got - want))) < TOL
     # one live row, four expert layers, top-2: eight (token, choice) pairs
+    assert int(load.sum()) == 8
+
+
+def _wide_heads():
+    """The tiny model with KDA heads of 128: whole lane tiles, which
+    ``decode_attention.kda_step`` needs (``kda.step_path``)."""
+    with open(os.path.join(DATA, 'tiny_kda_mla_moe_config.json')) as f:
+        linear = json.load(f)['linear_attn_config']
+    return setup(linear_attn_config=dict(linear, head_dim=128))
+
+
+@pytest.mark.parametrize('kernel', [False, True], ids=['xla', 'kernel'])
+def test_the_decode_step_gives_the_references_logits_under_both_paths(
+        kernel, monkeypatch):
+    """``test_prefill_then_decode_through_state_and_pool...`` at heads
+    of 128, where the rule has a choice: the plain XLA recurrence, and
+    the kernels (``kda_step`` and ``mla_decode``) in the interpreter,
+    asked for by name. Either way the inactive slot's junk state stays
+    bit for bit (``_paged_run``)."""
+    cfg, fam, params, pcfg = _wide_heads()
+    if kernel:
+        monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    state = (4, 2) + kda.state_shape(pcfg, 2)[1:]
+    assert state[2:] == (2, 128, 128)
+    assert kda.step_path(state, jnp.float32) == (
+        'kernel' if kernel else 'xla')
+    toks = tokens(22, seed=3)
+    got, load = _paged_run(params, pcfg, toks, 11)
+    want = fam.reference.logits_at(params, toks, np.arange(10, 22), cfg)
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
     assert int(load.sum()) == 8
 
 
@@ -338,9 +369,44 @@ def test_a_slot_freed_and_readmitted_sees_none_of_its_predecessors_state():
         assert st['state_bytes_per_slot'] == 4 * (2 * 16 * 16 * 4
                                                   + 3 * 96 * 4)
         assert st['decode_attention'] == 'gather'
+        # heads of 16 on a CPU: the plain recurrence
+        assert st['kda_step'] == 'xla'
         assert st['moe_tokens_routed'] % 8 == 0 and st['moe_tokens_routed']
     finally:
         eng.stop()
+
+
+@pytest.mark.parametrize('piece', [0, 32], ids=['paged_chunk',
+                                                'paged_chunk_n'])
+def test_the_engine_serves_through_the_kernel_and_says_so(piece,
+                                                          monkeypatch):
+    """Both decode programs (the whole chunk, and with pieces on the
+    chunk that stops at its first row to finish) with ``kda_step`` in
+    them (the interpreter, asked for by name; heads of 128): slots are
+    freed and re-used, one stays empty at times, and every served token
+    is the reference's pick; ``stats()`` names the path."""
+    cfg, fam, params, pcfg = _wide_heads()
+    monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    programs = (mla_moe.jit_paged_chunk, mla_moe.jit_paged_chunk_n)
+    # the path is chosen when a program is traced
+    for p in programs:
+        p.clear_cache()
+    eng = _engine(params, pcfg, prefill_chunk=piece)
+    try:
+        assert bool(eng._trim_chunks) == bool(piece)
+        rows = [tokens(n, seed=40 + i) for i, n in enumerate([40, 13, 29])]
+        futs = [eng.submit(list(map(int, r)), k)
+                for r, k in zip(rows, [6, 9, 5])]
+        outs = [f.result(timeout=600) for f in futs]
+        for row, out in zip(rows, outs):
+            _picks_are_the_references(fam, params, cfg, row, out)
+        st = eng.stats()
+        assert st['kda_step'] == 'kernel'
+        assert st['decode_attention'] == 'mla_kernel'
+    finally:
+        eng.stop()
+        for p in programs:
+            p.clear_cache()
 
 
 @pytest.mark.parametrize('piece', [16, 24, 64])
@@ -519,6 +585,12 @@ def test_the_table_has_the_familys_row_and_its_reasons():
     assert ops.prefill_chunk(pcfg) == 512 == 8 * kda.CHUNK
     assert model_ops.ops_for(mla_moe.TINY).prefill_chunk(mla_moe.TINY) == 0
     assert model_ops.ops_for(llama.TINY).prefill_chunk(llama.TINY) == 0
+    # the family's own rule, under the family's own stats() key; the
+    # other families report none
+    shapes = jax.eval_shape(lambda: mla_moe.init_pool(pcfg, 2, 64, 9, 16))
+    assert ops.step_paths(shapes) == {'kda_step': 'xla'}
+    assert model_ops.ops_for(mla_moe.TINY).step_paths(None) == {}
+    assert model_ops.ops_for(llama.TINY).step_paths(None) == {}
     with open(engine_lib.__file__) as f:
         src = f.read()
     assert 'kda' not in src.lower() and 'Kimi' not in src
