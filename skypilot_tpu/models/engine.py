@@ -32,7 +32,10 @@ TPU-first shape discipline (everything compiles exactly once per shape):
   ``lengths`` at insert exactly as speculative rollback does. Depth is
   capped at ONE so a paged slot's stale-active writes always precede
   (in device program order) any insert that re-populates its released
-  blocks — see ``_dispatch_chunk``;
+  blocks — see ``_dispatch_chunk``. N+1 is dispatched LATE, when N is
+  on its last step (``_hold_chunk``): the loop sleeps before the
+  dispatch, a submit() ends the sleep, and the arrival's prefill runs
+  behind N alone instead of behind an N+1 queued a chunk too soon;
 * inserts scatter a group's rows into the blocks it reserved and the
   pool is donated, so steady state allocates nothing.
 
@@ -286,6 +289,18 @@ class _Inflight:
     # The experts' token counts [E] of the chunk (drop-free expert
     # models; None otherwise): fetched with ``toks``, no sync of its own.
     counts: Optional[jax.Array] = None
+    # What the hold of the NEXT chunk reads (``_hold_chunk``). ``start``:
+    # when the host saw what precedes this chunk on the device finish
+    # (the retirement before it blocked until then), or its own dispatch
+    # if nothing preceded it; None when the host was behind the device
+    # and cannot know. ``exact``: ``start`` is of the first kind, so the
+    # chunk's length may teach the step time. ``followed``: a prefill,
+    # piece or import was queued behind it, so its retirement ends later
+    # than the chunk. ``held``: its successor's dispatch was held back.
+    start: Optional[float] = None
+    exact: bool = False
+    followed: bool = False
+    held: bool = False
 
 
 class KVImportError(RuntimeError):
@@ -299,6 +314,37 @@ class KVImportError(RuntimeError):
 # slot is active — submit() sets the event, so the wait length only
 # bounds how often an IDLE replica spins, not admission latency.
 _IDLE_WAIT_S = 1.0
+
+# The hold of the pipelined chunk (``ContinuousEngine._hold_chunk``).
+# The chunk after the one in flight is dispatched ``lead`` before the
+# one in flight should end: its last decode step's time, at least
+# _HOLD_LEAD_S (the host time of ``_dispatch_chunk`` plus a late timer).
+# An admission is not STARTED inside a hold with less than
+# _HOLD_ADMIT_S left before the chunk's end (an admission's host time:
+# its prefill has to be on the device's queue when the chunk ends, and
+# it then covers the dispatch of the held chunk). A retirement's waits
+# count as having blocked past _BLOCKED_S: less is the fetch of a result
+# that was ready, and the host is behind the device.
+_HOLD_LEAD_S = 0.004
+_HOLD_ADMIT_S = 0.006
+_BLOCKED_S = 0.0005
+
+
+def hold_plan(start: Optional[float], step_s: Optional[float],
+              steps: int) -> Optional[tuple]:
+    """``(dispatch_at, admit_until)`` for the successor of a chunk of
+    ``steps`` decode steps that began at ``start``, each step taking
+    ``step_s``: dispatch it one ``lead`` before the chunk's estimated
+    end, and start no admission later than ``admit_until``. None (no
+    hold) while either is unknown: the host has to have SEEN the chunk
+    begin, and a chunk end. Errs early by construction (work queued
+    behind the chunk is ignored): dispatching too soon is the loop
+    without the hold."""
+    if start is None or step_s is None:
+        return None
+    end = start + steps * step_s
+    dispatch_at = end - max(_HOLD_LEAD_S, step_s)
+    return dispatch_at, min(dispatch_at, end - _HOLD_ADMIT_S)
 
 
 def prompt_bucket(n: int, lo: int = 16) -> int:
@@ -460,6 +506,8 @@ class ContinuousEngine:
         'exports': '_lock', 'imports': '_lock',
         'import_errors': '_lock', 'dispatches': '_lock',
         'decode_steps': '_lock',
+        'holds': '_lock', 'hold_ms': '_lock', 'hold_overruns': '_lock',
+        'admits': '_lock', 'early_admits': '_lock',
         'host_overlap_ms': '_lock', 'bubble_ms': '_lock',
         '_gap_ms_total': '_lock', '_gap_count': '_lock',
         '_moe_load': '_lock',
@@ -720,6 +768,12 @@ class ContinuousEngine:
         self._inflight: Optional[_Inflight] = None
         self._last_dispatch_t: Optional[float] = None
         self._no_flight_since: Optional[float] = None
+        # The hold of the next chunk (_hold_chunk): what a decode step
+        # took in the last chunks whose start and end the host both saw
+        # (the estimate is the least of them: early, never late), and
+        # whether the loop is running its top again from inside a hold.
+        self._step_s: collections.deque = collections.deque(maxlen=3)
+        self._early = False
         # Stats (read by /health).
         self.prefills = 0
         self.failures = 0  # _fail_everything trips
@@ -753,6 +807,15 @@ class ContinuousEngine:
         # idled with work waiting (the serial-mode bubble).
         self.dispatches = 0
         self.decode_steps = 0  # chunk_steps a dispatch, fewer if trimmed
+        # The hold (stats()['pipeline']): chunks whose successor was
+        # held back, the time slept so, the holds after which the chunk
+        # had already ended (the device may have idled); requests
+        # admitted, and those admitted from inside a hold.
+        self.holds = 0
+        self.hold_ms = 0.0
+        self.hold_overruns = 0
+        self.admits = 0
+        self.early_admits = 0
         self.host_overlap_ms = 0.0
         self.bubble_ms = 0.0
         self._gap_ms_total = 0.0
@@ -1118,7 +1181,21 @@ class ContinuousEngine:
                         self._gap_ms_total / max(self._gap_count, 1),
                         3),
                     'host_overlap_ms': round(self.host_overlap_ms, 3),
-                    'bubble_ms': round(self.bubble_ms, 3)},
+                    'bubble_ms': round(self.bubble_ms, 3),
+                    # The hold of the next chunk (_hold_chunk): chunks
+                    # whose successor's dispatch was held back, the
+                    # time slept so (cumulative), admissions made from
+                    # inside a hold (their prefill runs before the
+                    # held chunk), and holds that ended after their
+                    # chunk had (the device may have idled).
+                    'holds': self.holds,
+                    'hold_ms': round(self.hold_ms, 3),
+                    'early_admits': self.early_admits,
+                    'hold_overruns': self.hold_overruns},
+                # Of all admissions since start, the share made from
+                # inside a hold, in %.
+                'early_admit_share': round(
+                    100.0 * self.early_admits / max(self.admits, 1), 2),
                 'speculative': None if self.draft_cfg is None else {
                     'rounds': self.spec_rounds,
                     'proposals': self.spec_proposals,
@@ -1204,7 +1281,14 @@ class ContinuousEngine:
                     continue
                 if self.draft_cfg is not None:
                     self._run_spec_round()
-                else:
+                    continue
+                # With a chunk in flight, sleep HERE until it is nearly
+                # done, not in its retirement after the next one is
+                # already queued behind it: a submit() that ends the
+                # sleep is admitted by the top of the loop now, and its
+                # prefill runs before the chunk still held back.
+                self._early = self._hold_chunk()
+                if not self._early:
                     self._run_chunk()
             except Exception as exc:  # noqa: BLE001 — fail all waiters
                 # Fail in-flight work, rebuild device state, KEEP LOOPING:
@@ -1217,11 +1301,66 @@ class ContinuousEngine:
                 self._wake.wait(0.1)
                 self._wake.clear()
 
-    def _idle_wait(self, timeout: float) -> None:
-        """Nothing to do until a submit() or ``timeout``."""
+    def _idle_wait(self, timeout: float) -> bool:
+        """Nothing to do until a submit() or ``timeout``; whether it was
+        a submit()."""
         with profiler.span('engine.idle'):
-            self._wake.wait(timeout)
+            woke = self._wake.wait(timeout)
         self._wake.clear()
+        return woke
+
+    # skylint: engine-thread
+    def _hold_chunk(self) -> bool:
+        """Hold the next decode chunk back until the one in flight (k)
+        is nearly done. True: a submit() ended the sleep with time left
+        (or a long prompt just admitted has its first piece due), and
+        the caller runs the top of the loop again with k+1 still held
+        back, so what it admits is dispatched behind k alone (device
+        order k, prefill, k+1). False: dispatch k+1 now.
+
+        Admissions always happened in this interval (after k-1's
+        retirement, before k+1's dispatch, with k dispatched): the hold
+        only stretches it in host time, so ``_dispatch_chunk``'s safety
+        argument stands as written and the programs and their order per
+        request are the loop's without it. No hold where it could not
+        help or the loop cannot tell when k ends: nothing in flight
+        (always so at depth 0 and with a draft), a retirement before k
+        that did not block (``flight.start`` None: the host is behind
+        the device), no chunk timed yet, no free slot or no block to
+        allocate (nothing could be admitted). The SPMD lockstep loop
+        (serve/spmd.py) drives ``_run_chunk`` itself and never holds."""
+        flight = self._inflight
+        if flight is None:
+            return False
+        plan = hold_plan(flight.start, min(self._step_s, default=None),
+                         flight.steps)
+        if plan is None:
+            return False
+        deadline, admit_until = plan
+        with self._lock:
+            parked = sum(1 for e in self._prefilling if e.parked)
+            free = sum(1 for r in self._slot_req if r is None)
+            room = free > parked and self._blocks_avail() > 0
+        if not room:
+            return False
+        while True:
+            left = deadline - time.perf_counter()
+            if left <= 0:
+                return False
+            if time.perf_counter() < admit_until and self._piece_due():
+                return True  # a long prompt admitted just now
+            if not flight.held:
+                flight.held = True
+                with self._lock:
+                    self.holds += 1
+            t0 = time.perf_counter()
+            woke = self._idle_wait(left)
+            ms = (time.perf_counter() - t0) * 1e3
+            with self._lock:
+                self.hold_ms += ms
+            blackbox.record('engine.hold', ms=round(ms, 3), woke=woke)
+            if woke and time.perf_counter() < admit_until:
+                return True
 
     # skylint: engine-thread
     def _fail_everything(self, exc: Exception) -> None:
@@ -1246,6 +1385,7 @@ class ContinuousEngine:
             self._inflight = None
             self._last_dispatch_t = None
             self._no_flight_since = None
+            self._early = False
             self.failures += 1
         for req in doomed:  # dupes are safe: first set_exception wins
             if not req.future.done():
@@ -1504,6 +1644,19 @@ class ContinuousEngine:
                 if not req.future.done():
                     req.future.set_result(req.tokens)
 
+    # skylint: locked(every caller holds _lock: the request leaves its
+    # queue in the same critical section)
+    def _note_admitted(self, reqs: List[_Request], path: str) -> None:
+        """The ``admit`` stamp of requests that leave the queue together,
+        and the count of them: ``early`` when the loop admits from
+        inside a hold (``_hold_chunk``), the next chunk still held back."""
+        now = time.perf_counter()
+        for r in reqs:
+            r.timeline.admitted_at(now, path, len(reqs))
+        self.admits += len(reqs)
+        if self._early:
+            self.early_admits += len(reqs)
+
     def _next_key(self) -> jax.Array:
         self._key, sub = jax.random.split(self._key)
         return sub
@@ -1532,7 +1685,7 @@ class ContinuousEngine:
                        and len(self._prefilling) < 2
                        and len(self._pending[0].row) > self.prefill_chunk):
                     long = self._pending.popleft()
-                    long.timeline.admitted_at(time.perf_counter(), 'long')
+                    self._note_admitted([long], 'long')
                     self._prefilling.append(_Prefilling(long))
                 if (self.prefill_chunk and self._pending
                         and len(self._pending[0].row) > self.prefill_chunk):
@@ -1627,8 +1780,7 @@ class ContinuousEngine:
                         owned = self._alloc_blocks(need)
                         slot = free_s[0]
                         self._pending.popleft()
-                        head.timeline.admitted_at(time.perf_counter(),
-                                                'shared')
+                        self._note_admitted([head], 'shared')
                         self._slot_req[slot] = head
                         self._slot_blocks[slot] = list(owned)
                         self._slot_shared[slot] = list(nodes)
@@ -1691,9 +1843,7 @@ class ContinuousEngine:
                     while g * 2 <= n:
                         g *= 2
                     reqs = [self._pending.popleft() for _ in range(g)]
-                    now = time.perf_counter()
-                    for r in reqs:
-                        r.timeline.admitted_at(now, 'group', g)
+                    self._note_admitted(reqs, 'group')
                     # Mid-prefill requests live in NO other structure —
                     # a device failure here must still fail their
                     # futures.
@@ -1866,10 +2016,18 @@ class ContinuousEngine:
         waited on (active slots, nothing in flight) — the prefill
         bubble sharing and chunking shrink."""
         dt_ms = (time.perf_counter() - t0) * 1e3
+        self._note_queued_behind()
         with self._lock:
             self.prefill_ms += dt_ms
             if had_active and self._inflight is None:
                 self.prefill_bubble_ms += dt_ms
+
+    # skylint: engine-thread
+    def _note_queued_behind(self) -> None:
+        """Work was dispatched behind the chunk in flight: its
+        retirement will end later than the chunk, and does not time it."""
+        if self._inflight is not None:
+            self._inflight.followed = True
 
     # skylint: engine-thread
     def _prefill_one_chunk(self, params, cfg, cache1, row, consumed):
@@ -1896,17 +2054,33 @@ class ContinuousEngine:
     def _advance_prefill(self) -> None:
         if not self._prefilling:
             return
+        if not (self._prefilling[0].parked or self._piece_due()):
+            return  # the live rows' decode steps come first
         had_active = any(r is not None for r in self._slot_req)
-        if (self._trim_chunks and had_active
-                and not self._prefilling[0].parked
-                and self._steps_since_piece < self.chunk_steps):
-            return  # the live rows' chunk_steps come first
         t0 = time.perf_counter()
         with profiler.span('engine.advance_prefill'):
             try:
                 self._advance_prefill_impl()
             finally:
                 self._note_prefill_time(t0, had_active)
+
+    # skylint: locked(as _advance_prefill: loop-pacing reads of state
+    # the engine thread alone mutates)
+    def _piece_due(self) -> bool:
+        """Whether the oldest long prefill's next piece may go out now.
+        With nothing decoding: always. Between trimmed chunks: after at
+        least ``chunk_steps`` decode steps since the last piece. Else
+        one piece per dispatched chunk (``_steps_since_piece`` is 0
+        from a piece to the next dispatch): the hold runs the top of
+        the loop more than once a chunk, and the pieces of a long
+        prompt must not go out back to back in front of the live rows."""
+        if not self._prefilling or self._prefilling[0].parked:
+            return False
+        if not any(r is not None for r in self._slot_req):
+            return True
+        if self._trim_chunks:
+            return self._steps_since_piece >= self.chunk_steps
+        return self._inflight is None or self._steps_since_piece > 0
 
     # skylint: engine-thread
     def _advance_prefill_impl(self) -> None:
@@ -2190,23 +2364,27 @@ class ContinuousEngine:
 
     # skylint: engine-thread
     @profiler.spanned('engine.drain_firsts')
-    def _drain_firsts(self) -> None:
+    def _drain_firsts(self) -> float:
         """Materialize deferred first tokens. MUST run before a chunk's
         emission so every admitted request's token list starts with its
-        prefill token; also completes single-token requests."""
+        prefill token; also completes single-token requests. Returns
+        the seconds it waited for the device."""
         with self._lock:
             batches = self._unfetched
             self._unfetched = []
         done: List[_Request] = []
         emitted: List[tuple] = []
         exports: List[tuple] = []
+        waited = 0.0
         for reqs, firsts in batches:
+            t0 = time.perf_counter()
             with profiler.span('engine.wait_firsts'):
                 # skylint: allow-host-sync(designed deferred fetch point
                 # — first tokens batched per prefill group and fetched
                 # while the next chunk runs on-device, per the pipeline
                 # contract)
                 firsts_host = np.asarray(jax.device_get(firsts))
+            waited += time.perf_counter() - t0
             with self._lock:
                 for i, req in enumerate(reqs):
                     first = int(firsts_host[i])
@@ -2244,6 +2422,7 @@ class ContinuousEngine:
         self._emit(emitted, done)
         for req, first in exports:
             self._export_and_retire(req, first)
+        return waited
 
     # -- disaggregated prefill/decode handoff (serve/disagg.py) -----------
 
@@ -2383,7 +2562,7 @@ class ContinuousEngine:
                     self._pending_imports.popleft()
                 if doomed is None:
                     # The prefill was another engine's: nothing to prep.
-                    req.timeline.admitted_at(time.perf_counter(), 'import')
+                    self._note_admitted([req], 'import')
                     req.timeline.prefill = req.timeline.admit
                     req.timeline.saved_tokens = len(nodes) * self.kv_block
             if doomed is not None:
@@ -2459,6 +2638,7 @@ class ContinuousEngine:
             if len(ids):
                 ks_pad[:, :len(ids)] = entry.k_s[:, lo:hi]
                 vs_pad[:, :len(ids)] = entry.v_s[:, lo:hi]
+        self._note_queued_behind()
         self._cache = paged_lib.jit_import_blocks(
             self._cache, k_pad, v_pad, ks_pad, vs_pad, blocks,
             table_row, np.int32(slot), np.int32(n))
@@ -2579,7 +2759,15 @@ class ContinuousEngine:
         slot that finished in N just decodes one discardable chunk more
         (the retirement guard drops it; the reuse insert overwrites
         ``lengths``). Serial (depth 0): dispatch, fetch, bookkeep — the
-        device idles through all host work (the measured bubble)."""
+        device idles through all host work (the measured bubble).
+
+        Where the loop sleeps: the plain loop calls this only when N is
+        nearly done (``_hold_chunk`` sleeps before it, under
+        ``engine.idle``, and admits what arrives meanwhile), so the
+        fetch of N's tokens below blocks for about a decode step, not
+        for a chunk. A caller that does not hold (the SPMD lockstep
+        loop) dispatches N+1 a whole chunk early and sleeps in that
+        fetch: the loop as it was, same programs, same order."""
         prev, self._inflight = self._inflight, self._dispatch_chunk()
         if prev is not None:
             self._retire_chunk(prev)
@@ -2602,7 +2790,11 @@ class ContinuousEngine:
         the insert that overwrites them. A deeper pipeline would let a
         chunk dispatched with a stale snapshot land AFTER such an
         insert and corrupt the new owner's KV — do not raise the depth
-        without revisiting this argument."""
+        without revisiting this argument. The hold (``_hold_chunk``)
+        leaves it as it stands: admissions always ran in the interval
+        after N's retirement and before N+2's dispatch with N+1 on the
+        device; dispatching N+2 later only stretches that interval in
+        host time, and dispatch and retirement still alternate."""
         with self._lock:
             reqs = list(self._slot_req)
         temps = np.zeros((self.slots,), np.float32)
@@ -2643,18 +2835,21 @@ class ContinuousEngine:
         tk, tp = _filters_or_none(top_ks, top_ps)
         if self._trim_chunks:
             steps = self._steps_to_first_finish(reqs)
-            self._steps_since_piece += steps
             chunk, tail = self._ops.paged_chunk_n, np.int32(steps)
         else:
             steps = self.chunk_steps
             chunk, tail = self._ops.paged_chunk, self._shard_ctx
+        self._steps_since_piece += steps
         with self._lock:
             self.decode_steps += steps
         self._cache, self._last, toks, counts = chunk(
             self.cfg, self.chunk_steps, self.params, self._cache,
             self._last, np.asarray(temps), tk, tp,
             np.asarray(active), self._next_key(), tail)
-        return _Inflight(reqs=reqs, toks=toks, steps=steps, counts=counts)
+        # With a predecessor in flight this chunk begins when that one's
+        # retirement sees it end (_note_flight_end); with none, now.
+        return _Inflight(reqs=reqs, toks=toks, steps=steps, counts=counts,
+                         start=now if self._inflight is None else None)
 
     # skylint: engine-thread
     def _steps_to_first_finish(self, reqs: List[Optional[_Request]]) -> int:
@@ -2674,6 +2869,35 @@ class ContinuousEngine:
                     for i, r in enumerate(reqs) if r is not None]
         owed = [n for n in owed if n > 0]
         return min(min(owed), self.chunk_steps) if owed else 1
+
+    # skylint: engine-thread
+    def _note_flight_end(self, flight: _Inflight, now: float,
+                         blocked: bool) -> None:
+        """The host has ``flight``'s tokens, and the first tokens of
+        what was queued behind it. ``blocked``: it had to wait for them,
+        so the device came to the end of that work just now and is
+        starting the chunk dispatched behind it (``self._inflight``),
+        whose ``start`` this is; if not, the host is behind the device
+        and that chunk's start is not known. A whole chunk seen at both
+        ends with nothing behind it teaches the step time; one that had
+        ended before the hold of its successor did is an overrun (the
+        device may have idled from its end to the next dispatch)."""
+        nxt = self._inflight
+        if nxt is not None and nxt is not flight:
+            nxt.start = now if blocked else None
+            nxt.exact = blocked
+        if (blocked and flight.exact and not flight.followed
+                and flight.steps == self.chunk_steps):
+            # (a whole chunk: a trimmed one's fixed cost would read as
+            # step time, and a whole chunk after it would be held late)
+            self._step_s.append((now - flight.start) / flight.steps)
+        if flight.held and not blocked:
+            # The estimate was late, and a late one keeps itself: no
+            # chunk after an overrun is seen at both ends. Forget it;
+            # the next chunks run unheld and teach a fresh one.
+            self._step_s.clear()
+            with self._lock:
+                self.hold_overruns += 1
 
     # skylint: engine-thread
     def _note_decode_quiet(self) -> None:
@@ -2711,14 +2935,17 @@ class ContinuousEngine:
         # admitted request's token list already holding its prefill
         # token (and a first-token-eos resolved here frees its slot
         # before this chunk's junk for it could be appended).
-        self._drain_firsts()
+        waited = self._drain_firsts()
+        t0 = time.perf_counter()
         with profiler.span('engine.wait_chunk'):
             # skylint: allow-host-sync(designed fetch point — THE chunk
             # result transfer; under pipelining it lands while the next
             # chunk computes, which is the whole overlap design)
             toks_host, counts = jax.device_get((flight.toks, flight.counts))
             toks_host = np.asarray(toks_host)  # [K, B]
-        t0 = time.perf_counter()
+        now = time.perf_counter()
+        self._note_flight_end(flight, now, waited + now - t0 > _BLOCKED_S)
+        t0 = now
         with self._lock:
             self.chunks_run += 1
             if counts is not None:

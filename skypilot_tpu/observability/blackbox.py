@@ -88,6 +88,10 @@ EVENTS: Tuple[Event, ...] = (
           'A decode chunk was dispatched over the active slots.'),
     Event('engine.bubble',
           'The device provably sat idle waiting on host work (ms).'),
+    Event('engine.hold',
+          'The loop slept (ms) with a chunk in flight, its successor '
+          'held back until that one is nearly done; woke: a submit() '
+          'ended the sleep.'),
     Event('engine.fail',
           '_fail_everything: the cause and blast radius of an engine '
           'loop failure.'),
