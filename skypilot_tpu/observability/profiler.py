@@ -1,5 +1,5 @@
-"""Runtime profiler: compile ledger, device-memory accounting, and a
-cold-start phase ledger.
+"""Runtime profiler: compile ledger, set-up ledger, device-memory
+accounting, and a cold-start phase ledger.
 
 The engine's whole TPU design rests on a compile-once-per-shape
 contract ("everything compiles exactly once per shape",
@@ -7,7 +7,7 @@ contract ("everything compiles exactly once per shape",
 provision→first-token a first-class budget — yet until this module the
 tree had zero visibility into compiles, HBM occupancy, or warm-up
 phases: a recompile storm, a leaked device buffer, or a minutes-long
-jit warm-up was invisible until it surfaced as tail latency. Three
+jit warm-up was invisible until it surfaced as tail latency. Four
 coupled ledgers close that gap:
 
 * **Compile ledger** — every ``jax.jit`` program in the serving stack
@@ -17,14 +17,24 @@ coupled ledgers close that gap:
   entry declares its SHAPE BUDGET — the number of distinct compiled
   shapes the program is designed to cost (e.g. ~log2(max_len) prompt
   buckets for prefill, a couple of filter-pytree variants for the
-  decode chunk). Compiles are detected via ``jax.monitoring``
-  lowering/compile duration events attributed to the dispatching
-  program through a thread-local (zero per-dispatch cost beyond two
-  attribute writes; the shape signature is computed only when a
-  compile actually happened — compiles are rare by contract). A count
-  past the budget is a **recompile storm**: storm counter +
-  ``profiler.storm`` black-box event + the ``serve.recompile_storm``
-  SLO warn rule (observability/slo.py).
+  decode chunk). Compiles are detected by the set-up ledger's listener
+  (below), attributed to the dispatching program through a
+  thread-local (zero per-dispatch cost beyond a few attribute writes;
+  the shape signature is computed only when a compile actually
+  happened — compiles are rare by contract). A count past the budget
+  is a **recompile storm**: storm counter + ``profiler.storm``
+  black-box event + the ``serve.recompile_storm`` SLO warn rule
+  (observability/slo.py).
+* **Set-up ledger** — ONE ``jax.monitoring`` listener per process
+  (:func:`listen`, registered first thing by
+  ``utils/jax_env.enable_compile_cache``), always on: every program
+  JAX traces, lowers and builds (compiles, or loads from the
+  persistent cache), eager operations included, as wall-clock
+  intervals per stage, a table by function name, and the persistent
+  cache's hits and misses. :func:`setup_totals` reads it; the
+  benchmark's ``setup.*`` metrics and ``/debug/profile``'s ``setup``
+  block are its readers. The events fire only while JAX traces,
+  lowers or compiles, never on a cached dispatch.
 * **Device-memory accounting** — :func:`sample_device_memory` snapshots
   ``device.memory_stats()`` (bytes_in_use / peak / limit → headroom)
   and reconciles it against the engine's LOGICAL accounting
@@ -67,6 +77,7 @@ and the warm-up budget workflow.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import dataclasses
 import functools
@@ -254,11 +265,10 @@ _LAST_MEM: Optional[Dict[str, Any]] = None
 _LAST_MEM_MONO: float = 0.0
 
 # Thread-local compile attribution: the profiled_jit wrapper names the
-# dispatching program; the jax.monitoring listener accumulates compile
-# milliseconds onto it. Reading/writing two attributes per dispatch is
-# the whole hot-path cost.
+# dispatching program; the set-up listener accumulates each stage's
+# milliseconds onto it. A few attribute reads and writes per dispatch
+# are the whole hot-path cost.
 _TLS = threading.local()
-_MON_STATE = {'registered': False, 'ok': False}
 
 
 def _process_birth_mono() -> float:
@@ -291,37 +301,194 @@ def _entry(name: str) -> Dict[str, Any]:
         st = {'compiles': 0, 'compile_ms': 0.0, 'storms': 0,
               'last_compile_ts': None,
               'shapes': collections.deque(maxlen=_SHAPES_KEPT)}
+        st.update(dict.fromkeys(_STAGE_MS, 0.0))
         _LEDGER[name] = st
     return st
 
 
-def _on_monitoring_event(key: str, duration_s: float, **_kw: Any) -> None:
-    """jax.monitoring duration listener: attribute lowering/compile
-    time to the program currently dispatching on this thread. Fires
-    only while jax is actually tracing/compiling — never on the cached
-    steady-state dispatch."""
-    if '/compile/' not in key and not key.endswith('compile_time'):
-        return
-    if getattr(_TLS, 'program', None) is None:
-        return
-    _TLS.compile_ms = getattr(_TLS, 'compile_ms', 0.0) \
-        + duration_s * 1000.0
+# -- set-up ledger -----------------------------------------------------------
+
+#: The stages of a program's set-up, each timed by one ``jax.monitoring``
+#: time-span event (JAX 0.9.0, ``jax/_src/dispatch.py``). A ``build`` is
+#: an XLA compile on a persistent-cache miss, or a retrieve-and-load on
+#: a hit; the cache is keyed on the lowered module, so a warm cache
+#: saves builds and never a trace or a lowering.
+SETUP_STAGES: Tuple[Tuple[str, str], ...] = (
+    ('trace', '/jax/core/compile/jaxpr_trace_duration'),
+    ('lower', '/jax/core/compile/jaxpr_to_mlir_module_duration'),
+    ('build', '/jax/core/compile/backend_compile_duration'),
+)
+_STAGE_OF = {event: i for i, (_, event) in enumerate(SETUP_STAGES)}
+_STAGE_MS = tuple(f'{stage}_ms' for stage, _ in SETUP_STAGES)
+_BUILD = 2      # SETUP_STAGES' index of 'build'
+# a by-name row: each stage's seconds, then each stage's count
+_ROW_KEYS = tuple(f'{stage}_s' for stage, _ in SETUP_STAGES) + (
+    'traces', 'lowerings', 'builds')
+_CACHE_EVENTS = {'/jax/compilation_cache/cache_hits': 'cache_hits',
+                 '/jax/compilation_cache/cache_misses': 'cache_misses'}
+#: Function names the by-name table keeps; the rest add up under
+#: ``_OTHER_NAMES``.
+_NAMES_KEPT = 256
+_OTHER_NAMES = '(other)'
+#: Intervals (or stamps) a stage (or counter) keeps for cuts at an
+#: instant; past it the oldest fold into a sum that every cut counts.
+_KEPT = 1 << 16
 
 
-def _ensure_listener() -> bool:
-    if _MON_STATE['registered']:
-        return _MON_STATE['ok']
-    with _LOCK:
-        if not _MON_STATE['registered']:
-            _MON_STATE['registered'] = True
-            try:
-                from jax import monitoring as jax_monitoring
-                jax_monitoring.register_event_duration_secs_listener(
-                    _on_monitoring_event)
-                _MON_STATE['ok'] = True
-            except Exception:  # noqa: BLE001 — degrade to cache-size
-                _MON_STATE['ok'] = False
-    return _MON_STATE['ok']
+class _Union:
+    """The union of one stage's wall-clock intervals: sorted, disjoint,
+    merged as they come, so a jit traced inside another's trace, or two
+    threads compiling at once, count once."""
+    __slots__ = ('starts', 'ends', 'total', 'folded')
+
+    def __init__(self) -> None:
+        self.starts: list = []
+        self.ends: list = []
+        self.total = 0.0        # seconds of the intervals kept
+        self.folded = 0.0       # seconds of those folded away
+
+    def add(self, start: float, end: float) -> None:
+        starts, ends = self.starts, self.ends
+        lo = bisect.bisect_left(ends, start)
+        hi = bisect.bisect_right(starts, end)
+        if lo < hi:     # [lo, hi) overlap the new interval: merge them
+            self.total -= sum(ends[k] - starts[k] for k in range(lo, hi))
+            start, end = min(start, starts[lo]), max(end, ends[hi - 1])
+            del starts[lo:hi], ends[lo:hi]
+        starts.insert(lo, start)
+        ends.insert(lo, end)
+        self.total += end - start
+        if len(starts) > _KEPT:
+            oldest = ends.pop(0) - starts.pop(0)
+            self.total -= oldest
+            self.folded += oldest
+
+    def seconds(self, until: Optional[float] = None) -> float:
+        if until is None:
+            return self.folded + self.total
+        return self.folded + sum(min(e, until) - s for s, e in
+                                 zip(self.starts, self.ends) if s < until)
+
+
+class _Stamps:
+    """When a counted event happened, for counts cut at an instant."""
+    __slots__ = ('at', 'folded')
+
+    def __init__(self) -> None:
+        self.at: list = []
+        self.folded = 0
+
+    def add(self, t: float) -> None:
+        bisect.insort(self.at, t)
+        if len(self.at) > _KEPT:
+            self.at.pop(0)
+            self.folded += 1
+
+    def count(self, until: Optional[float] = None) -> int:
+        return self.folded + (len(self.at) if until is None else
+                              bisect.bisect_right(self.at, until))
+
+
+_SETUP_LOCK = threading.Lock()
+_UNIONS = tuple(_Union() for _ in SETUP_STAGES)
+# builds (ends of build intervals), cache hits, cache misses
+_COUNTS = {'builds': _Stamps(), 'cache_hits': _Stamps(),
+           'cache_misses': _Stamps()}
+# function name -> its row, _ROW_KEYS (bounded)
+_NAMES: Dict[str, list] = {}
+# the listener's own cost: events taken, seconds spent taking them
+_SELF = {'events': 0, 'listener_s': 0.0}
+_LISTENING = False
+
+
+def _base_name(fun_name: str) -> str:
+    """``jit(f)`` (the lowering's and the build's name) -> ``f`` (the
+    trace's), so one program's three stages share a row."""
+    head, sep, rest = fun_name.partition('(')
+    if sep and rest.endswith(')') and head.isidentifier():
+        return rest[:-1]
+    return fun_name
+
+
+def _on_span(event: str, start_time: float, end_time: float,
+             **kwargs: Any) -> None:
+    """The time-span listener: one trace, lowering or build, on the
+    wall clock, to the stage's union, the name's row and the program
+    dispatching on this thread."""
+    i = _STAGE_OF.get(event)
+    if i is None:
+        return
+    t = time.perf_counter()
+    name = _base_name(str(kwargs.get('fun_name', '?')))
+    dur = end_time - start_time
+    with _SETUP_LOCK:
+        _UNIONS[i].add(start_time, end_time)
+        if i == _BUILD:
+            _COUNTS['builds'].add(end_time)
+        row = _NAMES.get(name)
+        if row is None:
+            if len(_NAMES) >= _NAMES_KEPT:
+                name = _OTHER_NAMES
+            row = _NAMES.setdefault(name, [0.0, 0.0, 0.0, 0, 0, 0])
+        row[i] += dur
+        row[len(SETUP_STAGES) + i] += 1
+        _SELF['events'] += 1
+        _SELF['listener_s'] += time.perf_counter() - t
+    if getattr(_TLS, 'program', None) is not None:
+        acc = getattr(_TLS, 'stage_ms', None)
+        if acc is None:
+            acc = _TLS.stage_ms = [0.0] * len(SETUP_STAGES)
+        acc[i] += dur * 1000.0
+
+
+def _on_event(event: str, **_kw: Any) -> None:
+    """The event listener: the persistent cache's hits and misses."""
+    key = _CACHE_EVENTS.get(event)
+    if key is None:
+        return
+    t = time.perf_counter()
+    with _SETUP_LOCK:
+        _COUNTS[key].add(time.time())
+        _SELF['events'] += 1
+        _SELF['listener_s'] += time.perf_counter() - t
+
+
+def listen() -> None:
+    """Register the set-up ledger's listener, once per process. Entry
+    points reach it through ``jax_env.enable_compile_cache()`` before
+    their first lowering; what JAX built before is not counted."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    from jax import monitoring as jax_monitoring
+    with _SETUP_LOCK:
+        if _LISTENING:
+            return
+        jax_monitoring.register_event_time_span_listener(_on_span)
+        jax_monitoring.register_event_listener(_on_event)
+        _LISTENING = True
+
+
+def setup_totals(until: Optional[float] = None,
+                 top: Optional[int] = 10) -> Dict[str, Any]:
+    """What this process traced, lowered and built since :func:`listen`:
+    each stage's seconds (the union of its intervals), the builds, the
+    persistent cache's hits and misses, cut at ``until`` (a
+    ``time.time()`` instant) when given. ``by_name``: the ``top`` names
+    (all with ``None``) by trace + lower + build seconds, each stage's
+    seconds and count, since the start whatever ``until`` is.
+    ``events`` and ``listener_s`` are the listener's own cost."""
+    stages = len(SETUP_STAGES)
+    with _SETUP_LOCK:
+        out: Dict[str, Any] = {
+            f'{stage}_s': u.seconds(until)
+            for (stage, _), u in zip(SETUP_STAGES, _UNIONS)}
+        out.update({k: s.count(until) for k, s in _COUNTS.items()})
+        out.update(_SELF)
+        rows = sorted(_NAMES.items(), key=lambda kv: -sum(kv[1][:stages]))
+    out['by_name'] = {name: dict(zip(_ROW_KEYS, row))
+                      for name, row in rows[:top]}
+    return out
 
 
 def _shape_sig(args: tuple, kwargs: dict) -> str:
@@ -344,19 +511,23 @@ def _shape_sig(args: tuple, kwargs: dict) -> str:
     return ','.join(parts)[:240]
 
 
-def _note_compile(name: str, ms: float, args: tuple,
+def _note_compile(name: str, stage_ms: list, args: tuple,
                   kwargs: dict) -> None:
     """Record one compile on the ledger (slow path — a compile just
     happened, so the device is paying seconds; the host paying a
-    signature walk and a locked update is free by comparison). Storm =
+    signature walk and a locked update is free by comparison).
+    ``compile_ms`` is the sum of the stages' milliseconds. Storm =
     distinct-compile count past the program's declared budget."""
     sig = _shape_sig(args, kwargs)
     budget = budget_for(name)
     storm = False
+    ms = sum(stage_ms)
     with _LOCK:
         st = _entry(name)
         st['compiles'] += 1
         st['compile_ms'] += ms
+        for key, val in zip(_STAGE_MS, stage_ms):
+            st[key] += val
         st['last_compile_ts'] = round(time.time(), 3)
         st['shapes'].appendleft(sig)
         if st['compiles'] > budget:
@@ -381,9 +552,9 @@ def profiled_jit(name: str, fn, **jit_kwargs):
     must be declared in :data:`PROGRAMS`. With SKYTPU_PROFILE off the
     wrapper is a passthrough to the jitted callable (one env read per
     dispatch — the same live-read cost blackbox.record already pays);
-    with it on, the added steady-state cost is two thread-local
-    attribute writes. Shape signatures and ledger updates happen only
-    when a compile actually fired."""
+    with it on, the added steady-state cost is a few thread-local
+    attribute reads and writes. Shape signatures and ledger updates
+    happen only when a compile actually fired."""
     if name not in PROGRAM_NAMES:
         hint = _closest(name)
         raise ValueError(
@@ -397,29 +568,19 @@ def profiled_jit(name: str, fn, **jit_kwargs):
     def wrapper(*args, **kwargs):
         if not enabled():
             return jitted(*args, **kwargs)
-        use_events = _ensure_listener()
-        if use_events:
-            prev = getattr(_TLS, 'program', None)
-            _TLS.program = name
-            _TLS.compile_ms = 0.0
-            try:
-                out = jitted(*args, **kwargs)
-            finally:
-                ms = getattr(_TLS, 'compile_ms', 0.0)
-                _TLS.program = prev
-            if ms:
-                _note_compile(name, ms, args, kwargs)
-            return out
-        # Fallback (no jax.monitoring): detect compiles from the jit
-        # cache size; the wall-clock of a compiling dispatch stands in
-        # for compile time (tracing+lowering+compile run synchronously
-        # inside the call; execution is async and excluded... mostly).
-        pre = _safe_cache_size(jitted)
-        t0 = time.perf_counter()
-        out = jitted(*args, **kwargs)
-        if pre is not None and _safe_cache_size(jitted) != pre:
-            _note_compile(name, (time.perf_counter() - t0) * 1e3,
-                          args, kwargs)
+        listen()
+        prev = getattr(_TLS, 'program', None)
+        prev_ms = getattr(_TLS, 'stage_ms', None)
+        _TLS.program = name
+        _TLS.stage_ms = None
+        try:
+            out = jitted(*args, **kwargs)
+        finally:
+            stage_ms = _TLS.stage_ms
+            _TLS.program = prev
+            _TLS.stage_ms = prev_ms
+        if stage_ms is not None:
+            _note_compile(name, stage_ms, args, kwargs)
         return out
 
     wrapper.program_name = name
@@ -482,7 +643,7 @@ def jit_cache_sizes() -> Dict[str, int]:
 
 def _safe_cache_size(jitted) -> Optional[int]:
     try:
-        return jitted._cache_size()  # noqa: SLF001 — fallback only
+        return jitted._cache_size()  # noqa: SLF001 — no public API
     except Exception:  # noqa: BLE001 — no cache API: give up counting
         return None
 
@@ -682,6 +843,7 @@ def snapshot() -> Dict[str, Any]:
             programs[name] = {
                 'compiles': st['compiles'],
                 'compile_ms': round(st['compile_ms'], 3),
+                **{k: round(st[k], 3) for k in _STAGE_MS},
                 'budget': budget_for(name),
                 'storms': st['storms'],
                 'last_compile_ts': st['last_compile_ts'],
@@ -696,6 +858,7 @@ def snapshot() -> Dict[str, Any]:
         'storms_total': storms,
         'cold_start': cold_start_ledger(),
         'device_memory': mem,
+        'setup': setup_totals(),
     })
     return out
 
@@ -762,11 +925,14 @@ def debug_payload(query: Any) -> Dict[str, Any]:
 def reset() -> None:
     """Drop ledger state (tests / probes). Wrapped-program entries are
     re-created empty so the ledger keeps listing every wrapped
-    program; phase crossings and memory samples clear."""
+    program; phase crossings and memory samples clear. The set-up
+    ledger is not reset: it is this process's record since
+    :func:`listen`."""
     with _LOCK:
         for st in _LEDGER.values():
             st['compiles'] = 0
             st['compile_ms'] = 0.0
+            st.update(dict.fromkeys(_STAGE_MS, 0.0))
             st['storms'] = 0
             st['last_compile_ts'] = None
             st['shapes'].clear()

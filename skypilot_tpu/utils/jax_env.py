@@ -50,7 +50,14 @@ def enable_compile_cache() -> Dict[str, Any]:
     ``{'enabled', 'dir', 'source', 'entries_at_start', 'warm'}`` —
     ``warm`` (the directory already held entries) is how boots are
     classified for the autoscaler's lead-time model. Cache trouble
-    never fails a boot: running without the cache is only slower."""
+    never fails a boot: running without the cache is only slower.
+
+    First of all it registers the profiler's set-up listener
+    (``profiler.listen``, once per process), so what every entry point
+    traces, lowers and builds from here on is counted, whatever the
+    cache's state."""
+    from skypilot_tpu.observability import profiler
+    profiler.listen()
     global _cache_state
     if _cache_state is not None:
         return _cache_state
