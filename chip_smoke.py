@@ -23,9 +23,9 @@ line; any phase not ``ok`` makes the exit code non-zero):
                 through paged_decode (which writes the step's K/V row
                 too); SIGTERM -> drain -> exit 0.
   kernels       each Pallas kernel compiled (not interpreted) against its
-                jnp reference (paged_decode, mla_decode and kda_step at the
-                benchmark cells' geometries) and the train step's HLO searched
-                for the Mosaic call.
+                jnp reference (paged_decode, mla_decode, kda_step and
+                moe_gmm at the benchmark cells' geometries) and the train
+                step's HLO searched for the Mosaic call.
   launch-local  execution.launch(Task(run='python -m ...train.run'),
                 cloud='local'): the orchestrator's own path.
   train-4chip, serve-tp4   with four or more devices; else a stated skip.
@@ -74,7 +74,10 @@ REAL = dict(model='bench-1b', tp_model='bench-1b', vocab=32768,
             mla=dict(slots=48, blocks=8193, block=16, max_blocks=256),
             # kimi-linear-docs-steady's state: four KDA layers x 48
             # slots x 32 heads of [128, 128] float32.
-            kda=dict(layers=4, slots=48, heads=32, d=128))
+            kda=dict(layers=4, slots=48, heads=32, d=128),
+            # kimi-linear-docs-steady's prefill piece: 512 x 8 rows over
+            # the 128 held experts of a stack of four layers, gate/up.
+            gmm=dict(m=4096, k=2304, n=1024, groups=128, layers=4))
 # tiny-mh: 8 kv heads, so --tp 4 divides them. The interpreter cannot
 # afford the kernels' real VMEM caps, so the rehearsal names small ones.
 REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
@@ -85,7 +88,8 @@ REHEARSAL = dict(model='tiny', tp_model='tiny-mh', vocab=256,
                             hq=4, hkv=2, d=128),
                  mla=dict(slots=4, blocks=33, block=16, max_blocks=8,
                           heads=4, rank=96, rope=16),
-                 kda=dict(layers=2, slots=4, heads=2, d=128))
+                 kda=dict(layers=2, slots=4, heads=2, d=128),
+                 gmm=dict(m=256, k=256, n=256, groups=8, layers=2))
 
 
 def remaining() -> float:
@@ -821,10 +825,39 @@ def child_kernels(rehearse: bool, meshes) -> int:
              and not np.asarray(got_o)[~live].any(),
              err=float(f'{err:.3g}'), tol=1e-5, untouched=untouched)
 
+    def gmm_case(m, k, n, groups, layers):
+        """``moe_gmm`` on the last layer of a stack, half the rows routed
+        to held experts as a skewed router routes them (a few hot, many
+        empty), against ``ragged_dot`` in float32; the rows past the
+        groups zero."""
+        from skypilot_tpu.models import moe
+        from skypilot_tpu.ops import grouped_matmul
+        rng = np.random.default_rng(m)
+        share = rng.dirichlet(np.full(groups, 0.3))
+        sizes = np.floor(share * m / 2).astype(np.int32)
+        ks = jax.random.split(jax.random.PRNGKey(m), 2)
+        lhs = jax.random.normal(ks[0], (m, k), jnp.bfloat16)
+        rhs = jax.random.normal(ks[1], (layers * groups, k, n), jnp.bfloat16)
+        tm = moe.row_tile(m, groups)
+        assert interpret or grouped_matmul.gmm_fits(tm, k, n, rhs.dtype)
+        # skylint: allow-jit(one-shot numerics check, not a program)
+        got = jax.jit(lambda *a: grouped_matmul.moe_gmm(
+            *a, jnp.int32(layers - 1), tm=tm, interpret=interpret))(
+            lhs, rhs, jnp.asarray(sizes))
+        want = jax.lax.ragged_dot(lhs, rhs[-groups:], jnp.asarray(sizes),
+                                  preferred_element_type=jnp.float32)
+        total = int(sizes.sum())
+        err = rel_err(got[:total], want[:total])
+        emit(f'moe_gmm M{m} K{k} N{n} E{groups} L{layers} tm{tm} bf16',
+             np.isfinite(err) and err <= 1e-2
+             and not np.asarray(got[total:], np.float32).any(),
+             err=float(f'{err:.3g}'), tol=1e-2)
+
     hq, hkv, d = c['hq'], c['hkv'], c['d']
     guarded('paged_decode', lambda: paged_case(**c['paged']))
     guarded('mla_decode', lambda: mla_case(**c['mla']))
     guarded('kda_step', lambda: kda_case(**c['kda']))
+    guarded('moe_gmm', lambda: gmm_case(**c['gmm']))
     for s in c['flash_seqs']:
         guarded(f'flash S{s}', lambda s=s: flash_case(2, hq, hkv, s, d))
     # The VMEM cap itself, as the code has it: one group.

@@ -541,6 +541,25 @@ def decode_path(tables_shape, pool_shape, dtype) -> str:
     return 'gather'
 
 
+def step_paths(cfg: MlaMoeConfig, pool, prefill_tokens: int
+               ) -> Dict[str, Any]:
+    """``ModelOps.step_paths`` of this family: how the routed experts'
+    grouped matmuls run (``moe.grouped_path``) in a decode step of the
+    pool's slots and in a prefill of ``prefill_tokens`` (one piece, or
+    one whole prompt), under ``moe_grouped``; {} without routed
+    experts."""
+    if not cfg.num_experts:
+        return {}
+    lo, hi = cfg.held
+
+    def path(tokens):
+        return moe.grouped_path(tokens * cfg.expert_top_k, hi - lo,
+                                cfg.d_model, cfg.d_ff_expert, cfg.dtype)
+
+    return {'moe_grouped': {'decode': path(pool.tables.shape[0]),
+                            'prefill': path(prefill_tokens)}}
+
+
 def _ffn(cfg: MlaMoeConfig, h: jax.Array, layer: Params, token_mask):
     """Dense SwiGLU or the drop-free expert layer on h [B, S, d];
     -> (y, per-expert counts or None). ``layer['stack_layer']``: the

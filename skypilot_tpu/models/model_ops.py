@@ -39,9 +39,11 @@ class ModelOps:
 
     ``decode_attention(pool, quantized) -> str``: how the S = 1 step
     reads the pool (``stats()['decode_attention']``).
-    ``step_paths(pool) -> {stats key: answer}``: the family's further
-    rules for its decode program, each reported under its own key (a
-    recurrent state: how its one-token step runs).
+    ``step_paths(cfg, pool, prefill_tokens) -> {stats key: answer}``:
+    the family's further rules for its programs, each reported under
+    its own key (a recurrent state: how its one-token step runs; routed
+    experts: how their grouped matmuls run in a decode step of the
+    pool's slots and in a prefill of ``prefill_tokens``).
     ``state_bytes_per_slot(cfg)``: what a sequence keeps beside its
     paged rows, whatever its length (a recurrent state a slot).
     ``prefill_chunk(cfg)``: the family's default piece of a chunked
@@ -67,7 +69,8 @@ class ModelOps:
     # state a slot, beside what a token costs): 0 where a sequence is
     # its keys and values alone.
     state_bytes_per_slot: Callable[[Any], int] = lambda cfg: 0
-    step_paths: Callable[[Any], Dict[str, str]] = lambda pool: {}
+    step_paths: Callable[[Any, Any, int], Dict[str, Any]] = (
+        lambda cfg, pool, prefill_tokens: {})
     # The piece (tokens) a long prompt's prefill advances by between
     # decode chunks where neither the caller nor the environment says
     # (``ContinuousEngine(prefill_chunk=)``): 0, the whole prompt in
@@ -126,6 +129,7 @@ def _mla_moe() -> ModelOps:
             pool.tables.shape, pool.k.shape, pool.k.dtype),
         kv_bytes_per_token=lambda cfg: cfg.kv_bytes_per_token,
         rows_couple=lambda cfg: False,     # drop-free routing
+        step_paths=mla_moe.step_paths,
         refuses={
             'kv_quantize': 'the latent pool has no int8 mode',
             'kv_tiers': one_plane,
@@ -166,8 +170,9 @@ def _kda_mla_moe() -> ModelOps:
     return dataclasses.replace(
         base, name='kda_mla_moe', insert_paged=mla_moe.jit_insert,
         state_bytes_per_slot=lambda cfg: cfg.state_bytes_per_slot,
-        step_paths=lambda pool: {'kda_step': kda.step_path(
-            pool.state.shape, pool.state.dtype)},
+        step_paths=lambda cfg, pool, prefill_tokens: dict(
+            base.step_paths(cfg, pool, prefill_tokens),
+            kda_step=kda.step_path(pool.state.shape, pool.state.dtype)),
         prefill_chunk=lambda cfg: KDA_PREFILL_CHUNK,
         paged_chunk_n=mla_moe.jit_paged_chunk_n,
         # the chunked long prefill seeds its scratch row from shared

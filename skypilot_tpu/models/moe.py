@@ -26,6 +26,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from skypilot_tpu.ops import attention as attention_ops
+from skypilot_tpu.ops import decode_attention, grouped_matmul
+
 Params = Dict[str, Any]
 
 
@@ -159,6 +162,49 @@ def route_sigmoid(x: jax.Array, router: jax.Array, bias: jax.Array,
         return idx.astype(jnp.int32), w * scale
 
 
+# Rows a held expert gets in a call, on average (M over the held groups),
+# from which the experts' grouped matmuls take ``grouped_matmul.moe_gmm``.
+# Alone on a v5e (PERF.md §6, PR 39) XLA's ``ragged_dot`` reads 423 GB/s
+# of weights at 3 rows an expert and 259 from 8 on; the kernel 665-694
+# at either. Below 8 are the decode steps, whose calls are mostly junk
+# rows of empty slots: not timed, so left to ``ragged_dot``.
+GROUPED_KERNEL_ROWS = 8
+# The kernel's row tile: the rows an expert gets, in whole bf16 sublane
+# tiles, and no more than this.
+GROUPED_MAX_TILE = 128
+
+
+def row_tile(m: int, groups: int) -> int:
+    """``moe_gmm``'s row tile for ``m`` sorted rows over ``groups``
+    experts: the mean rows an expert gets, rounded down to a power of
+    two, between bf16's 16-row sublane tile and ``GROUPED_MAX_TILE``."""
+    mean = max(m // max(groups, 1), 1)
+    return min(GROUPED_MAX_TILE, max(16, 1 << (mean.bit_length() - 1)))
+
+
+def grouped_path(m: int, groups: int, k: int, n: int, dtype) -> str:
+    """How a drop-free expert layer's grouped matmuls run over ``m``
+    sorted rows, ``groups`` held experts, [m, k] x [k, n] in ``dtype``:
+    ``'kernel'`` (``grouped_matmul.moe_gmm``: each expert with rows read
+    once, at the HBM rate whatever its rows) or ``'ragged_dot'``
+    (XLA's). ``paged.decode_path``'s rule: a TPU, or the interpreter
+    where a test asks for it by name, and shapes the kernel takes; and
+    ``GROUPED_KERNEL_ROWS`` rows an expert or more, where XLA's falls
+    behind. The ONE definition: ``dropfree_mlp`` branches on it and the
+    engine reports it (``stats()['moe_grouped']``)."""
+    if not (attention_ops._use_pallas()
+            or decode_attention.PAGED_INTERPRET):
+        return 'ragged_dot'
+    if m < GROUPED_KERNEL_ROWS * groups:
+        return 'ragged_dot'
+    if grouped_matmul.gmm_fits(row_tile(m, groups), k, n, dtype):
+        return 'kernel'
+    attention_ops.log_fallback_once(
+        'moe_gmm', (m, groups, k, n),
+        f'{jnp.dtype(dtype).name} [{k}, {n}] outside gmm_fits()')
+    return 'ragged_dot'
+
+
 def dropfree_mlp(x: jax.Array, params: Params, top_k: int, scale: float,
                  norm: bool = True, held: Optional[Tuple[int, int]] = None,
                  token_mask: Optional[jax.Array] = None,
@@ -170,9 +216,10 @@ def dropfree_mlp(x: jax.Array, params: Params, top_k: int, scale: float,
     (token, choice) pairs by expert, run the experts HELD here
     (``held = (lo, hi)``, a contiguous range; None = all; ``we_*`` hold
     exactly those, ``[hi - lo, ...]``) as grouped matmuls over the
-    sorted rows (``jax.lax.ragged_dot``: on a TPU one Mosaic grouped
-    matmul that reads each expert's weights once if any row is routed to
-    it), and un-sort with the routing weights. Pairs routed to experts
+    sorted rows (``grouped_path`` says how: ``grouped_matmul.moe_gmm``
+    where experts get many rows, else ``jax.lax.ragged_dot``; either
+    reads an expert's weights only if a row is routed to it), and
+    un-sort with the routing weights. Pairs routed to experts
     held elsewhere sort last, belong to no group and add nothing: their
     part is another chip's. A shared expert (``ws_*``, if present) takes
     every token. ``token_mask [N]`` keeps junk rows (a freed slot still
@@ -207,19 +254,33 @@ def dropfree_mlp(x: jax.Array, params: Params, top_k: int, scale: float,
                             length=e + 1)[:-1].astype(jnp.int32)
     with jax.named_scope('moe.experts'):
         we = [params[k] for k in ('we_gate', 'we_up', 'we_down')]
-        groups = sizes
         if stack_layer is not None:
             n_l = we[0].shape[0]
             we = [w.reshape((n_l * (hi - lo),) + w.shape[2:]) for w in we]
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros((n_l * (hi - lo),), jnp.int32), sizes,
-                (stack_layer * (hi - lo),))
-        gate = jax.lax.ragged_dot(xs, we[0], groups)
-        up = jax.lax.ragged_dot(xs, we[1], groups)
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, we[2], groups)
+        m = n * top_k
+        if grouped_path(m, hi - lo, d, we[0].shape[-1], x.dtype) == 'kernel':
+            tm = row_tile(m, hi - lo)
+            xs = jnp.pad(xs, ((0, -m % tm), (0, 0)))
+
+            def mm(a, w):
+                return grouped_matmul.moe_gmm(
+                    a, w, sizes, stack_layer, tm=tm,
+                    interpret=not attention_ops._use_pallas())
+        else:
+            groups = sizes
+            if stack_layer is not None:
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((we[0].shape[0],), jnp.int32), sizes,
+                    (stack_layer * (hi - lo),))
+
+            def mm(a, w):
+                return jax.lax.ragged_dot(a, w, groups)
+        gate = mm(xs, we[0])
+        up = mm(xs, we[1])
+        out = mm(jax.nn.silu(gate) * up, we[2])[:m]
     with jax.named_scope('moe.dispatch'):
-        # rows past the last group are not written by the grouped
-        # matmul: take them as zero, whatever the buffer holds
+        # rows past the last group are not written by ragged_dot (the
+        # kernel writes zeros): take them as zero, whatever it holds
         in_group = jnp.arange(n * top_k) < jnp.sum(sizes)
         out = jnp.where(in_group[:, None], out, 0)
         back = out[jnp.argsort(order)].reshape(n, top_k, d)

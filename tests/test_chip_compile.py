@@ -19,7 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from skypilot_tpu.models import engine as engine_lib
 from skypilot_tpu.models import llama, paged
-from skypilot_tpu.ops import attention, decode_attention
+from skypilot_tpu.ops import attention, decode_attention, grouped_matmul
 
 # The benchmark's serving cells: 48 slots, max_len 2048 in blocks of 16
 # out of a pool of 2,049, 16/8 heads x 128, bf16.
@@ -499,6 +499,44 @@ def test_kda_step_compiles_at_the_cells_geometry(one_chip):
     assert stats.temp_size_in_bytes < 16e6
 
 
+@pytest.mark.parametrize('m,k,n,groups,layers', [
+    (512 * 8, 2304, 1024, 128, 4), (512 * 8, 1024, 2304, 128, 4),
+    (4 * 4096 * 8, 2304, 1024, 128, 4), (512 * 4, 3584, 1024, 64, 5),
+    (512 * 4, 1024, 3584, 64, 5)], ids=['kimi_piece_gate', 'kimi_piece_down',
+                                        'kimi_group_prefill', 'xing_suffix',
+                                        'xing_suffix_down'])
+def test_moe_gmm_compiles_at_the_cells_widths(one_chip, m, k, n, groups,
+                                              layers):
+    """The grouped-matmul kernel at the experts' widths and the row tile
+    the rule gives (``moe.row_tile``): two experts' [K, N] in VMEM, the
+    whole stack [L * E, K, N] handed to the call as it lies (a
+    parameter, no copy or slice in front), the result bf16 [M, N]: the
+    signature ``step.moe_share.ttft.kimi-linear-docs-steady`` matches."""
+    from skypilot_tpu.models import moe
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm = moe.row_tile(m, groups)
+    assert grouped_matmul.gmm_fits(tm, k, n, jnp.bfloat16)
+    # skylint: allow-jit(test-only compile check)
+    compiled = jax.jit(lambda x, w, s, l: grouped_matmul.moe_gmm(
+        x, w, s, l, tm=tm)).lower(
+        sds((m, k)), sds((layers * groups, k, n)),
+        sds((groups,), jnp.int32), sds((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    call = re.search(r'%moe_gmm[.\d]* = (bf16\[\d+,\d+\])[^\n]*'
+                     r'custom-call\(([^)]*)\)', hlo)
+    assert call, 'no moe_gmm call'
+    assert call.group(1) == f'bf16[{m},{n}]'
+    defs = {d.group(1): (d.group(2), d.group(3)) for d in re.finditer(
+        r'(%[\w.-]+) = (\S+) ([\w-]+)\(', hlo)}
+    shape, op = defs[call.group(2).split(',')[-1].strip()]
+    assert shape.startswith(f'bf16[{layers * groups},{k},{n}]'), shape
+    assert op == 'parameter', op
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
 def test_kda_group_prefill_fits_beside_the_weights(one_chip, monkeypatch):
     """A group of 4 rows padded to 4,096: the MLA layer takes the flash
     kernel, and the KDA layers go a row at a time, so the chunked form's
@@ -550,8 +588,22 @@ def test_kda_prefill_piece_continues_a_scratch_row(one_chip, monkeypatch):
         cache, cfg, jax.ShapeDtypeStruct((1,), jnp.int32,
                                          sharding=one_chip)).compile()
     hlo = compiled.as_text()
-    assert 'flash_fwd' not in hlo
+    # (by instruction: the module's table of stack frames may name
+    # functions other programs of this process traced)
+    assert not re.search(r'%flash_fwd[.\d]* = ', hlo)
     # layers 0 | 1 | 2 | 3 are runs of their own here (KDA + dense, KDA +
     # experts, MLA, KDA + experts): three chunk scans, no scan of rows
     assert len(re.findall(r' while\(', hlo)) == 3
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    # 512 x 8 rows over 128 held experts: the three expert layers' nine
+    # grouped matmuls are the kernel's, each handed its layer's whole
+    # stack [128, K, N] as it lies (PR 28: a slice in front is a copy)
+    calls = re.findall(r'%moe_gmm[.\d]* = bf16\[4096,\d+\][^\n]*'
+                       r'custom-call\(([^)]*)\)', hlo)
+    assert len(calls) == 9 and not re.search(r'%ragged-dot', hlo)
+    defs = {d.group(1): (d.group(2), d.group(3)) for d in re.finditer(
+        r'(%[\w.-]+) = (\S+) ([\w-]+)\(', hlo)}
+    for operands in calls:
+        shape, op = defs[operands.split(',')[-1].strip()]
+        assert re.match(r'bf16\[128,(2304,1024|1024,2304)\]', shape), shape
+        assert op in ('bitcast', 'get-tuple-element', 'parameter'), op

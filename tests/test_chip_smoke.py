@@ -44,9 +44,10 @@ def test_rehearsal_passes_end_to_end():
     assert phases['launch-local']['framework_processes_left'] == []
     assert phases['launch-local']['gang_runner'] in ('native gangd',
                                                      'python')
-    assert len(phases['kernels']['cases']) == 9
-    assert sum('kda_step' in c['case']
-               for c in phases['kernels']['cases']) == 1
+    assert len(phases['kernels']['cases']) == 10
+    for kernel in ('kda_step', 'moe_gmm'):
+        assert sum(kernel in c['case']
+                   for c in phases['kernels']['cases']) == 1
     assert not any('flash_decode' in c['case']
                    for c in phases['kernels']['cases'])
     assert lines[-1] == {'ok': True, 'rehearsal': True, 'device': {

@@ -585,12 +585,20 @@ def test_the_table_has_the_familys_row_and_its_reasons():
     assert ops.prefill_chunk(pcfg) == 512 == 8 * kda.CHUNK
     assert model_ops.ops_for(mla_moe.TINY).prefill_chunk(mla_moe.TINY) == 0
     assert model_ops.ops_for(llama.TINY).prefill_chunk(llama.TINY) == 0
-    # the family's own rule, under the family's own stats() key; the
-    # other families report none
+    # the families' own rules, each under its own stats() key: the KDA
+    # layers' and the experts' (this family's and the latent one's);
+    # the dense family reports none
     shapes = jax.eval_shape(lambda: mla_moe.init_pool(pcfg, 2, 64, 9, 16))
-    assert ops.step_paths(shapes) == {'kda_step': 'xla'}
-    assert model_ops.ops_for(mla_moe.TINY).step_paths(None) == {}
-    assert model_ops.ops_for(llama.TINY).step_paths(None) == {}
+    experts = {'moe_grouped': {'decode': 'ragged_dot',
+                               'prefill': 'ragged_dot'}}
+    assert ops.step_paths(pcfg, shapes, 512) == dict(experts,
+                                                     kda_step='xla')
+    latent = jax.eval_shape(lambda: mla_moe.init_pool(mla_moe.TINY, 2, 64,
+                                                      9, 16))
+    assert model_ops.ops_for(mla_moe.TINY).step_paths(
+        mla_moe.TINY, latent, 64) == experts
+    assert model_ops.ops_for(llama.TINY).step_paths(llama.TINY, None,
+                                                    64) == {}
     with open(engine_lib.__file__) as f:
         src = f.read()
     assert 'kda' not in src.lower() and 'Kimi' not in src
