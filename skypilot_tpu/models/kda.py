@@ -28,7 +28,11 @@ sum inside a chunk, ``A_ij = sum_c k_ic k_jc exp(G_ic - G_jc)`` (j < i)
 makes the chunk's updates the solution of a unit lower-triangular
 system, ``(I + Diag(beta) A) U = Diag(beta) (V - (K exp G) S_0)``, which
 is solved for ALL chunks at once as ``U = U_0 - W_k S_0`` (the WY
-form); only ``S_0 -> U -> O, S_C`` is sequential over the chunks.
+form); only ``S_0 -> U -> O, S_C`` is sequential over the chunks. On
+a TPU the same algebra runs as ``ops/kda_prefill.kda_chunk``, a row's
+chunks in VMEM and its state in a scratch across them (``chunk_path``
+is the rule); elsewhere ``chunked``, plain XLA, all chunks' pairs at
+once.
 Every exponent taken is of a difference ``G_i - G_j`` with j <= i, so
 nothing overflows however fast a channel decays: inside blocks of
 ``SUB`` positions the pairs are taken exactly, and across blocks both
@@ -49,7 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from skypilot_tpu.ops import attention as attention_ops
-from skypilot_tpu.ops import decode_attention
+from skypilot_tpu.ops import decode_attention, kda_prefill
 
 Params = Dict[str, Any]
 CHUNK = 64      # positions a chunk of the prefill form
@@ -148,25 +152,31 @@ def _out(cfg, o: jax.Array, h: jax.Array, layer: Params) -> jax.Array:
 # -- decode: one token a row --------------------------------------------------
 
 
-def step_path(state_shape, dtype) -> str:
-    """How the one-token recurrence runs over a state [..., H, dk, dv]:
-    ``'kernel'`` (``ops/decode_attention.kda_step``: the live rows'
-    state once, in place) or ``'xla'`` (``recur`` over the layer's slice
-    of every row). ``paged.decode_path``'s rule: a TPU, or the
-    interpreter where a test asks for it by name, and a state the
-    kernel takes (float32, whole tiles). The ONE definition:
-    ``step_layer`` branches on it and the engine reports it
-    (``stats()['kda_step']``)."""
+def _path(kernel: str, state_shape, dtype) -> str:
+    """``step_path`` and ``chunk_path``: ``paged.decode_path``'s rule,
+    a TPU, or the interpreter where a test asks for it by name, and a
+    state both kernels take (``decode_attention.kda_fits``: float32,
+    whole tiles)."""
     if not (attention_ops._use_pallas()
             or decode_attention.PAGED_INTERPRET):
         return 'xla'
     if decode_attention.kda_fits(state_shape, dtype):
         return 'kernel'
     attention_ops.log_fallback_once(
-        'kda_step', state_shape[-3:],
+        kernel, state_shape[-3:],
         f'a {jnp.dtype(dtype).name} state of heads {state_shape[-2:]} '
         f'outside kda_fits()')
     return 'xla'
+
+
+def step_path(state_shape, dtype) -> str:
+    """How the one-token recurrence runs over a state [..., H, dk, dv]:
+    ``'kernel'`` (``ops/decode_attention.kda_step``: the live rows'
+    state once, in place) or ``'xla'`` (``recur`` over the layer's slice
+    of every row), by ``_path``'s rule. The ONE definition:
+    ``step_layer`` branches on it and the engine reports it
+    (``stats()['kda_step']``)."""
+    return _path('kda_step', state_shape, dtype)
 
 
 def step_inputs(cfg, h: jax.Array, layer: Params, tail: jax.Array,
@@ -302,18 +312,25 @@ def _decayed_pairs(x: jax.Array, k: jax.Array, gc: jax.Array) -> jax.Array:
                      outer).reshape(lead + (t, t))
 
 
+def _chunks(q, k, v, g, beta):
+    """A row's chunk (``CHUNK``, or the row in whole blocks when it is
+    shorter) and q, k, v, g, beta padded to a whole number of chunks:
+    masked positions, g = beta = 0, change nothing."""
+    s = q.shape[1]
+    chunk = min(CHUNK, -(-s // SUB) * SUB)
+    pad = -s % chunk
+    return chunk, tuple(jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
+                                * (a.ndim - 2))
+                        for a in (q, k, v, g, beta))
+
+
 def chunked(q, k, v, g, beta, state):
     """The recurrence over a block of positions. q, k, v, g [B, S, H,
     dk] and beta [B, S, H] float32 (g, beta 0 at masked positions),
     state [B, H, dk, dv] -> (o [B, S, H, dv], state)."""
     b, s, h, dk = q.shape
-    chunk = min(CHUNK, -(-s // SUB) * SUB)
-    pad = -s % chunk
-    if pad:     # masked positions: g = beta = 0 change nothing
-        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),)
-                                    * (a.ndim - 2))
-                            for a in (q, k, v, g, beta))
-    n = (s + pad) // chunk
+    chunk, (q, k, v, g, beta) = _chunks(q, k, v, g, beta)
+    n = q.shape[1] // chunk
     # [B, S, H, x] -> [B, H, N, T, x]
     cut = lambda a: a.reshape(b, n, chunk, h, -1).transpose(0, 3, 1, 2, 4)  # noqa: E731,E501
     q, k, v, g = cut(q), cut(k), cut(v), cut(g)
@@ -346,6 +363,27 @@ def chunked(q, k, v, g, beta, state):
     return o.reshape(b, n * chunk, h, -1)[:, :s], state
 
 
+def chunk_path(state_shape, dtype) -> str:
+    """How the chunked recurrence of a prefill runs over a state [...,
+    H, dk, dv]: ``'kernel'`` (``ops/kda_prefill.kda_chunk``: a head's
+    chunks in VMEM, its state in a scratch across them) or ``'xla'``
+    (``chunked``), by ``step_path``'s rule. The ONE definition:
+    ``forward`` branches on it and the engine reports it
+    (``stats()['kda_chunk']``)."""
+    return _path('kda_chunk', state_shape, dtype)
+
+
+def chunked_kernel(q, k, v, g, beta, state):
+    """``chunked``'s arguments and result through ``kda_chunk``: the
+    same chunks (and the same padding of a row to a whole number of
+    them), the state continued in VMEM."""
+    chunk, padded = _chunks(q, k, v, g, beta)
+    state, o = kda_prefill.kda_chunk(
+        *padded, state, chunk=chunk, sub=SUB,
+        interpret=not attention_ops._use_pallas())
+    return o[:, :q.shape[1]], state
+
+
 def forward(cfg, h: jax.Array, layer: Params, state: jax.Array,
             tail: jax.Array, row_lens: jax.Array,
             live: Optional[jax.Array] = None):
@@ -356,7 +394,8 @@ def forward(cfg, h: jax.Array, layer: Params, state: jax.Array,
     chunks to keep the chip busy, and the float32 temporaries of the
     chunked form (a dozen of [S, H, dk]) are then one row's, not the
     group's (a 4 x 4096 prefill compiles to 4.5 GB of temporaries
-    that way, 1.9 this way)."""
+    that way, 1.9 this way). The recurrence of a row runs where
+    ``chunk_path`` says: ``kda_chunk``, or ``chunked``."""
     if live is not None:
         row_lens = jnp.where(live, row_lens, 0)
 
@@ -368,7 +407,10 @@ def forward(cfg, h: jax.Array, layer: Params, state: jax.Array,
         q, k, v = _qkv(cfg, y)
         g, beta = _gates(cfg, h, layer, real)
         with jax.named_scope('kda.chunk'):
-            o, state = chunked(q, k, v, g, beta, state)
+            if chunk_path(state.shape, state.dtype) == 'kernel':
+                o, state = chunked_kernel(q, k, v, g, beta, state)
+            else:
+                o, state = chunked(q, k, v, g, beta, state)
         return _out(cfg, o, h, layer), state, tail
 
     return jax.lax.map(

@@ -172,7 +172,8 @@ def _kda_mla_moe() -> ModelOps:
         state_bytes_per_slot=lambda cfg: cfg.state_bytes_per_slot,
         step_paths=lambda cfg, pool, prefill_tokens: dict(
             base.step_paths(cfg, pool, prefill_tokens),
-            kda_step=kda.step_path(pool.state.shape, pool.state.dtype)),
+            kda_step=kda.step_path(pool.state.shape, pool.state.dtype),
+            kda_chunk=kda.chunk_path(pool.state.shape, pool.state.dtype)),
         prefill_chunk=lambda cfg: KDA_PREFILL_CHUNK,
         paged_chunk_n=mla_moe.jit_paged_chunk_n,
         # the chunked long prefill seeds its scratch row from shared

@@ -419,7 +419,9 @@ def kda_fits(state_shape, dtype) -> bool:
     (the recurrence's precision IS the state's), and a head's [dk, dv]
     a whole number of (8, 128) tiles with ``dk`` a whole number of lanes
     too (the step's k, q and decay arrive with dk on the lanes and are
-    turned onto the sublanes a head at a time)."""
+    turned onto the sublanes a head at a time). The prefill's kernel,
+    ``ops/kda_prefill.kda_chunk``, takes the same states (q, k, g and
+    the state's rows along the lanes)."""
     dk, dv = state_shape[-2:]
     return (jnp.dtype(dtype) == jnp.float32 and dk % 128 == 0
             and dv % 128 == 0)

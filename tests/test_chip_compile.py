@@ -9,6 +9,7 @@ and the chip's own (``chip_smoke.py``, ``kernels`` phase).
 The topology is described inside a fixture, and only this file does it:
 one process at a time may load the TPU's library (see
 /opt/skills/guides/on-chip-measurement/SKILL.md §2)."""
+import json
 import os
 import re
 
@@ -537,6 +538,33 @@ def test_moe_gmm_compiles_at_the_cells_widths(one_chip, m, k, n, groups,
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
 
 
+@pytest.mark.parametrize('s', [512, 4096], ids=['piece', 'long_row'])
+def test_kda_chunk_compiles_at_the_cells_geometry(one_chip, s):
+    """One row of a prefill piece (512 positions) and of the longest
+    group prefill (4,096, whose operands stay in HBM: the blocks' double
+    buffers and the kernel's spills pass the default scoped VMEM), 32
+    heads of [128, 128] float32: Mosaic takes the kernel, and its result
+    is (state, o), the state first."""
+    from skypilot_tpu.models import kda
+    from skypilot_tpu.ops import kda_prefill
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    h, d = 32, 128
+    assert decode_attention.kda_fits((1, h, d, d), jnp.float32)
+    row = sds((1, s, h, d))
+    # skylint: allow-jit(test-only compile check)
+    compiled = jax.jit(lambda *a: kda_prefill.kda_chunk(
+        *a, chunk=kda.CHUNK, sub=kda.SUB)).lower(
+        row, row, row, row, sds((1, s, h)), sds((1, h, d, d))).compile()
+    call = re.search(r'%kda_chunk[.\d]* = (\([^\n]*?\)) custom-call\(',
+                     compiled.as_text())
+    assert call, 'no kda_chunk call'
+    assert re.match(rf'\(f32\[1,32,128,128\]\S*, f32\[1,32,{s // 64},64,'
+                    r'128\]', call.group(1)), call.group(1)
+
+
 def test_kda_group_prefill_fits_beside_the_weights(one_chip, monkeypatch):
     """A group of 4 rows padded to 4,096: the MLA layer takes the flash
     kernel, and the KDA layers go a row at a time, so the chunked form's
@@ -566,9 +594,9 @@ def test_kda_prefill_piece_continues_a_scratch_row(one_chip, monkeypatch):
     tokens of ONE row continuing a scratch row 4,096 wide (state, tails
     and the latent rows so far). The MLA layer attends over the row's
     view (no flash kernel: that is the fresh prefill's), the map over
-    rows is gone for the one row (the only loops left are the layer scan
-    and the KDA layers' chunk scans), and the temporaries are a fraction
-    of the group prefill's."""
+    rows is gone for the one row, the KDA layers' chunks are walked
+    inside ``kda_chunk`` (no loop is left in the program), and the
+    temporaries are a fraction of the group prefill's."""
     from skypilot_tpu.models import mla_moe, model_ops
     monkeypatch.setattr(attention, '_use_pallas', lambda: True)
     cfg = _kda_cfg()
@@ -592,8 +620,20 @@ def test_kda_prefill_piece_continues_a_scratch_row(one_chip, monkeypatch):
     # functions other programs of this process traced)
     assert not re.search(r'%flash_fwd[.\d]* = ', hlo)
     # layers 0 | 1 | 2 | 3 are runs of their own here (KDA + dense, KDA +
-    # experts, MLA, KDA + experts): three chunk scans, no scan of rows
-    assert len(re.findall(r' while\(', hlo)) == 3
+    # experts, MLA, KDA + experts): three ``kda_chunk`` calls, no scan of
+    # chunks or of rows
+    assert len(re.findall(r' while\(', hlo)) == 0
+    chunks = re.findall(r'(%kda_chunk[.\d]* = \([^\n]*?\)) custom-call\(',
+                        hlo)
+    assert len(chunks) == 3, len(chunks)
+    # each hands back its layer's state FIRST, in the form the prefill's
+    # map over rows carries, so ``step.kda_share.ttft`` counts the call
+    with open(os.path.join(os.path.dirname(__file__), '..', 'benchmarks',
+                           'metrics', 'step.kda_share.ttft.json')) as f:
+        share = re.compile(json.load(f)['args']['match'])
+    for call in chunks:
+        assert re.search(r'= \(f32\[1,32,128,128\]', call), call
+        assert share.search(call), call
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
     # 512 x 8 rows over 128 held experts: the three expert layers' nine
     # grouped matmuls are the kernel's, each handed its layer's whole

@@ -53,9 +53,18 @@ def _recurrence(q, k, v, g, beta, state):
 
 # 100 and 40 are no multiples of the chunk (64) or of its blocks (16);
 # 7 is shorter than a block; 192 is three chunks
+@pytest.mark.parametrize('kernel', [False, True], ids=['xla', 'kernel'])
 @pytest.mark.parametrize('s', [7, 16, 40, 64, 100, 192])
-def test_the_chunked_form_is_the_token_recurrence(s):
-    b, h, dk = 2, 3, 8
+def test_the_chunked_form_is_the_token_recurrence(s, kernel, monkeypatch):
+    """``kda.chunked``, and ``kda_chunk`` (the interpreter, asked for by
+    name; heads of 128, a whole lane tile) where ``kda.chunk_path``
+    takes it."""
+    b, h, dk = (2, 3, 128) if kernel else (2, 3, 8)
+    if kernel:
+        monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+    assert kda.chunk_path((b, h, dk, dk), jnp.float32) == (
+        'kernel' if kernel else 'xla')
+    form = kda.chunked_kernel if kernel else kda.chunked
     ks = jax.random.split(jax.random.PRNGKey(s), 6)
     q = jax.random.normal(ks[0], (b, s, h, dk))
     k = kda._l2(jax.random.normal(ks[1], (b, s, h, dk)))
@@ -70,7 +79,7 @@ def test_the_chunked_form_is_the_token_recurrence(s):
     beta = jnp.where(real[..., None], beta, 0.0)
     with jax.default_matmul_precision('highest'):
         want_o, want_s = _recurrence(q, k, v, g, beta, state)
-        got_o, got_s = kda.chunked(q, k, v, g, beta, state)
+        got_o, got_s = form(q, k, v, g, beta, state)
     assert float(jnp.max(jnp.abs(got_o - want_o))) < 5e-4
     assert float(jnp.max(jnp.abs(got_s - want_s))) < 1e-4
 
@@ -369,8 +378,9 @@ def test_a_slot_freed_and_readmitted_sees_none_of_its_predecessors_state():
         assert st['state_bytes_per_slot'] == 4 * (2 * 16 * 16 * 4
                                                   + 3 * 96 * 4)
         assert st['decode_attention'] == 'gather'
-        # heads of 16 on a CPU: the plain recurrence
+        # heads of 16 on a CPU: the plain recurrence, both forms
         assert st['kda_step'] == 'xla'
+        assert st['kda_chunk'] == 'xla'
         assert st['moe_tokens_routed'] % 8 == 0 and st['moe_tokens_routed']
     finally:
         eng.stop()
@@ -382,12 +392,14 @@ def test_the_engine_serves_through_the_kernel_and_says_so(piece,
                                                           monkeypatch):
     """Both decode programs (the whole chunk, and with pieces on the
     chunk that stops at its first row to finish) with ``kda_step`` in
-    them (the interpreter, asked for by name; heads of 128): slots are
-    freed and re-used, one stays empty at times, and every served token
-    is the reference's pick; ``stats()`` names the path."""
+    them (the interpreter, asked for by name; heads of 128), and the
+    prompts' prefills with ``kda_chunk``: slots are freed and re-used,
+    one stays empty at times, and every served token is the reference's
+    pick; ``stats()`` names the paths."""
     cfg, fam, params, pcfg = _wide_heads()
     monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
-    programs = (mla_moe.jit_paged_chunk, mla_moe.jit_paged_chunk_n)
+    programs = (mla_moe.jit_prefill, mla_moe.jit_paged_chunk,
+                mla_moe.jit_paged_chunk_n)
     # the path is chosen when a program is traced
     for p in programs:
         p.clear_cache()
@@ -402,6 +414,8 @@ def test_the_engine_serves_through_the_kernel_and_says_so(piece,
             _picks_are_the_references(fam, params, cfg, row, out)
         st = eng.stats()
         assert st['kda_step'] == 'kernel'
+        # and each prompt's prefill through ``kda_chunk``
+        assert st['kda_chunk'] == 'kernel'
         assert st['decode_attention'] == 'mla_kernel'
     finally:
         eng.stop()
@@ -409,17 +423,29 @@ def test_the_engine_serves_through_the_kernel_and_says_so(piece,
             p.clear_cache()
 
 
+@pytest.mark.parametrize('kernel', [False, True], ids=['xla', 'kernel'])
 @pytest.mark.parametrize('piece', [16, 24, 64])
-def test_a_long_prompt_prefilled_in_pieces_is_the_references(piece):
+def test_a_long_prompt_prefilled_in_pieces_is_the_references(piece, kernel,
+                                                             monkeypatch):
     """The chunked long prefill (``prefill_chunk``): a prompt longer
     than the piece advances a piece an engine iteration over a scratch
     row whose state, tails and latent rows each piece continues; shorter
     prompts keep the group prefill; a request that ends at its first
     token leaves junk in a free slot that the next admission replaces.
-    24 is no multiple of the mixer's blocks of 16."""
-    cfg, fam, params, pcfg = setup()
+    24 is no multiple of the mixer's blocks of 16. ``kernel``: heads of
+    128 and the kernels in the interpreter, asked for by name, so each
+    piece and each group prefill runs ``kda_chunk``."""
+    cfg, fam, params, pcfg = _wide_heads() if kernel else setup()
+    programs = (mla_moe.jit_prefill, mla_moe.jit_paged_chunk,
+                mla_moe.jit_paged_chunk_n)
+    if kernel:
+        monkeypatch.setattr(decode_attention, 'PAGED_INTERPRET', True)
+        # the paths are chosen when a program is traced
+        for p in programs:
+            p.clear_cache()
     eng = _engine(params, pcfg, prefill_chunk=piece)
     try:
+        assert eng.stats()['kda_chunk'] == ('kernel' if kernel else 'xla')
         assert eng.prefill_chunk == piece
         lens = [70, 13, 49, 80, 7, 33]
         news = [6, 6, 1, 5, 6, 6]
@@ -436,6 +462,9 @@ def test_a_long_prompt_prefilled_in_pieces_is_the_references(piece):
         assert st['prefill_tokens'] == sum(lens)
     finally:
         eng.stop()
+        if kernel:
+            for p in programs:
+                p.clear_cache()
 
 
 @pytest.mark.parametrize('max_new, steps', [(6, [4, 1, 1]), (9, [4, 4, 1]),
@@ -591,8 +620,8 @@ def test_the_table_has_the_familys_row_and_its_reasons():
     shapes = jax.eval_shape(lambda: mla_moe.init_pool(pcfg, 2, 64, 9, 16))
     experts = {'moe_grouped': {'decode': 'ragged_dot',
                                'prefill': 'ragged_dot'}}
-    assert ops.step_paths(pcfg, shapes, 512) == dict(experts,
-                                                     kda_step='xla')
+    assert ops.step_paths(pcfg, shapes, 512) == dict(
+        experts, kda_step='xla', kda_chunk='xla')
     latent = jax.eval_shape(lambda: mla_moe.init_pool(mla_moe.TINY, 2, 64,
                                                       9, 16))
     assert model_ops.ops_for(mla_moe.TINY).step_paths(
