@@ -785,6 +785,9 @@ class ContinuousEngine:
         self.share_commits = 0
         self.share_evictions = 0
         self.cow_forks = 0
+        # Shared-prefix prefills by how their tail went into the pool
+        # (paged.write_path: 'blocks' or 'rows').
+        self.pool_writes = {'blocks': 0, 'rows': 0}
         # Prefill cost counters: real prompt tokens the prefill
         # actually computed vs tokens skipped via shared prefix KV —
         # the probe's >= 40% savings gate reads these.
@@ -1221,6 +1224,10 @@ class ContinuousEngine:
                     'commits': self.share_commits,
                     'evictions': self.share_evictions,
                     'cow_forks': self.cow_forks},
+                # Shared-prefix prefills since start by how their tail
+                # was written into the pool (paged.write_path): whole
+                # blocks, or one [D] row a position and head.
+                'pool_write': dict(self.pool_writes),
                 'prefill_tokens': self.prefill_tokens,
                 'prefill_tokens_saved': self.prefill_tokens_saved,
                 'prefill_ms': round(self.prefill_ms, 3),
@@ -1933,13 +1940,17 @@ class ContinuousEngine:
             self._cache = self._ops.fork_block(
                 self._cache, jnp.int32(partial.block), jnp.int32(owned[0]))
         suffix = row[covered:]
-        # The padded width must not overhang max_len: positions past
-        # the table are CLIPPED to its last entry, and with a full
-        # reservation that entry is the request's own live block — the
-        # padded junk would scribble over real prompt KV. Room always
+        # The padded width must not overhang max_len: written by rows
+        # (paged.write_path), positions past the table are CLIPPED to
+        # its last entry, and with a full reservation that entry is the
+        # request's own live block — the padded junk would scribble
+        # over real prompt KV. Room always
         # suffices: submit validates row + max_new <= max_len, so
         # max_len - covered >= len(suffix) + max_new.
         w = min(prompt_bucket(len(suffix)), self.max_len - covered)
+        plane = self._cache.k  # its heads as the program holds them
+        write = paged_lib.write_path(
+            w, p, plane.sharding.shard_shape(plane.shape)[2])
         padded = np.zeros((1, w), np.int32)
         padded[0, :len(suffix)] = suffix
         logits, self._cache = self._ops.prefill_shared(
@@ -1974,6 +1985,7 @@ class ContinuousEngine:
             self.prefills += 1
             self.share_hits += 1
             self.share_hit_tokens += covered
+            self.pool_writes[write] += 1
             self.prefill_tokens += len(suffix)
             self.prefill_tokens_saved += covered
         self._note_prefill_time(t0, had_active)

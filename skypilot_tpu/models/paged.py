@@ -33,8 +33,15 @@ TPU-first shape discipline (vs the GPU original's per-block kernels):
   with 20 at ~1,400, against 2.7 ms a layer whatever the slots hold for
   the dense view below, and 63 us a layer for the row scatter alone
   (PR 26 to PR 30 scattered the row first: 18% of the step);
-* every other write is a row scatter at ``table[b, len // P]``, offset
-  ``len % P``, into the pool seen as ``L * NB`` blocks (``pool_write``);
+* every other write goes into the pool seen as ``L * NB`` blocks
+  (``pool_write``) at ``table[b, len // P]``, offset ``len % P``, as
+  ``write_path`` says: a wide window (the shared-prefix prefill's tail,
+  S = W) as the WHOLE blocks it touches, gathered, overlaid and
+  scattered back; a narrow one (the latent decode step, S = 1; the
+  speculative verify, S = k + 1) as ``[D]`` rows, which XLA updates
+  one at a time (~70 ns a row of 8 heads x 128: a Llama tail of 256
+  was 24 x 2 x 142 us of row updates a prefill, 11% of a saturated
+  device, ledger PR 42; 17 us a layer-plane by blocks, PR 45);
 * everything else — the speculative verify (S = k + 1), the
   shared-prefix prefill (S = W, one row), int8 pools, backends without
   the kernel — GATHERS each row's whole table out of the same flat view
@@ -238,7 +245,10 @@ def _scatter_rows(pool: jax.Array, tables: jax.Array,
     the one a Mosaic call's operands must have. Written as a scatter of
     [H, D] slabs (``pool.at[blk, :, off]``) the TPU compiler re-lays the
     whole pool as [NB, P, H, D] inside the decode loop and converts it
-    back in front of the kernel (PR 26: 9 ms of a 38 ms step)."""
+    back in front of the kernel (PR 26: 9 ms of a 38 ms step). XLA
+    updates one [D] row at a time, ~70-120 ns a row on a v5e: the
+    narrow windows' write (``write_path``); a wide one goes by
+    blocks."""
     b, h, s, d = new.shape
     nb, _, p, _ = pool.shape
     blk, off = _block_offsets(tables, lengths, s, p, active_rows)
@@ -247,6 +257,38 @@ def _scatter_rows(pool: jax.Array, tables: jax.Array,
     vals = new.transpose(0, 2, 1, 3).reshape(b * s * h, d)
     return pool.reshape(nb * h * p, d).at[rows].set(vals).reshape(
         pool.shape)
+
+
+def _write_blocks(pool: jax.Array, tables: jax.Array,
+                  lengths: jax.Array, new: jax.Array,
+                  active_rows) -> jax.Array:
+    """``_scatter_rows``' write by whole blocks: the ``nbt = ceil(S/P)
+    + 1`` blocks that positions [lengths, lengths + S) can touch are
+    gathered out of ``pool`` [NB, H, P, D] as slabs on its leading dim
+    (a static count), the window goes in at in-block offset
+    ``lengths % P`` of their [H, nbt·P, D] view, and they are scattered
+    back whole, so each touched block keeps the positions outside the
+    window as it read them (a shared or forked prefix, byte for byte).
+    A block the window does not reach, one past the table (never
+    clipped onto the table's last entry: two updates of one live block,
+    one stale, would race) and every block of an inactive row go to the
+    junk sink (block 0) instead, like the writes past a reservation."""
+    b, h, s, d = new.shape
+    p = pool.shape[2]
+    mb = tables.shape[1]
+    nbt = -(-s // p) + 1
+    j = lengths[:, None] // p + jnp.arange(nbt, dtype=jnp.int32)[None]
+    live = (j < mb) & (j * p < (lengths + s)[:, None])
+    if active_rows is not None:
+        live = live & active_rows[:, None]
+    ids = jnp.where(live, jnp.take_along_axis(
+        tables, jnp.minimum(j, mb - 1), axis=1), 0).reshape(-1)
+    view = pool[ids].reshape(b, nbt, h, p, d).transpose(0, 2, 1, 3, 4)
+    view = jax.vmap(lambda v, n, o: jax.lax.dynamic_update_slice(
+        v, n, (0, o, 0)))(view.reshape(b, h, nbt * p, d), new,
+                          lengths % p)
+    blocks = view.reshape(b, h, nbt, p, d).transpose(0, 2, 1, 3, 4)
+    return pool.at[ids].set(blocks.reshape((b * nbt,) + pool.shape[1:]))
 
 
 def _scatter_multi_s(pool_s: jax.Array, tables: jax.Array,
@@ -271,15 +313,40 @@ def _layer_blocks(pool: jax.Array, l, tables: jax.Array):
     return pool.reshape((-1,) + pool.shape[2:]), tables + l * pool.shape[1]
 
 
+def write_path(s: int, p: int, h: int) -> str:
+    """How ``pool_write`` puts a window of ``s`` positions a row into a
+    plane of ``h`` heads (as the writing program holds it: a TP shard's
+    heads) in blocks of ``p`` positions: ``'blocks'`` (``_write_blocks``:
+    the touched blocks gathered whole, the window laid in, the blocks
+    scattered back) or ``'rows'`` (``_scatter_rows``: one [D] row a
+    position and head). XLA moves either one index at a time, ~70-120
+    ns a [D] row, ~0.4-1.7 us a whole block beside ~3.5 us for the
+    three operations (a v5e, alone, PERF.md section 6, PR 45), so
+    blocks win once the window's ``h * s`` rows number four blocks'
+    positions: every shared-prefix prefill's tail at 8 heads (the
+    Llama cells, from S = 16), a latent tail of 64 or more; the decode
+    step (S = 1) and a verify (S = k + 1) keep rows. Decided on static
+    shapes, for any number of rows a batch. The ONE definition:
+    ``pool_write`` branches on it and the engine counts it
+    (``stats()['pool_write']``)."""
+    return 'blocks' if h * s >= 4 * p else 'rows'
+
+
 def pool_write(pool: jax.Array, l, tables: jax.Array, lengths: jax.Array,
                new: jax.Array, active_rows) -> jax.Array:
     """Write ``new`` [B, H, S, D] (a scale plane's: [B, H, S]) of layer
     ``l`` (a traced scalar) at positions [lengths, lengths + S) through
-    the tables."""
+    the tables, by blocks or by rows as ``write_path`` says (a scale
+    plane by rows)."""
     flat, blocks = _layer_blocks(pool, l, tables)
-    scatter = _scatter_rows if new.ndim == 4 else _scatter_multi_s
-    return scatter(flat, blocks, lengths, new.astype(pool.dtype),
-                   active_rows).reshape(pool.shape)
+    if new.ndim == 3:
+        write = _scatter_multi_s
+    elif write_path(new.shape[2], pool.shape[3], new.shape[1]) == 'blocks':
+        write = _write_blocks
+    else:
+        write = _scatter_rows
+    return write(flat, blocks, lengths, new.astype(pool.dtype),
+                 active_rows).reshape(pool.shape)
 
 
 def pool_view(pool: jax.Array, l, tables: jax.Array) -> jax.Array:

@@ -141,14 +141,21 @@ _MOVES = ('copy', 'copy-start', 'copy-done', 'dynamic-slice',
           'dynamic-update-slice', 'slice', 'transpose', 'concatenate')
 
 
-def _no_pool_is_taken_apart(hlo, writer):
-    """No instruction PRODUCES a pool or a plane by moving it, under any
-    shape of the same size (the flat views included): the pool enters as
-    a parameter, rides the loops' tuples, is seen through bitcasts, and
-    is written in place by ``writer`` and by nothing else:
-    ``'fusion:scatter'`` (the row scatter's fusion: S > 1) or
-    ``'paged_decode'`` (the decode kernel, whose pool results alias its
-    operands: ``_kernel_call``)."""
+def _no_pool_is_taken_apart(hlo, writer, pool=(2, 2049, 8, 16, 128)):
+    """No instruction PRODUCES a pool or a plane of shape ``pool`` by
+    moving it, under any shape of the same size (the flat views
+    included): the pool enters as a parameter, rides the loops' tuples,
+    is seen through bitcasts, and is written in place by ``writer`` and
+    by nothing else: ``'fusion:scatter'`` (the scatter of whole blocks:
+    S > 1) or ``'paged_decode'`` (the decode kernel, whose pool results
+    alias its operands: ``_kernel_call``). Nor is it ever seen as
+    ``[L·NB·H·P, D]`` rows: a row scatter over the whole pool updates
+    one ``[D]`` row at a time (0.66 s of a 6 s window, ledger, PR 42)."""
+    plane = 1
+    for n in pool:
+        plane *= n
+    l, nb, h, p, d = pool
+    assert f'bf16[{l * nb * h * p},{d}]' not in hlo
     seen = set()
     for m in re.finditer(r'(%[\w.-]+) = bf16\[([\d,]+)\]\S* ([\w-]+)\('
                          r'([^\n]*)', hlo):
@@ -156,7 +163,7 @@ def _no_pool_is_taken_apart(hlo, writer):
         elems = 1
         for n in dims.split(','):
             elems *= int(n)
-        if elems not in (_PLANE_ELEMS, 2 * _PLANE_ELEMS):
+        if elems not in (plane, 2 * plane):
             continue
         if op == 'fusion':  # judged by what it is rooted in
             body = hlo[hlo.index(re.search(r'calls=(%[\w.-]+)',
@@ -169,8 +176,14 @@ def _no_pool_is_taken_apart(hlo, writer):
     assert ('fusion:scatter' in seen) == (writer == 'fusion:scatter'), seen
     if writer == 'paged_decode':
         _kernel_call(hlo)
-    # the pool keeps its row-major layout from the arguments on
-    assert set(re.findall(_POOL + r'\{([\d,]+)', hlo)) == {'4,3,2,1,0'}
+    # the pool keeps its row-major layout from the arguments on (a dim
+    # of size 1 may sit anywhere in it: the same bytes)
+    shape = r'bf16\[' + ','.join(map(str, pool)) + r'\]'
+    layouts = set(re.findall(shape + r'\{([\d,]+)', hlo))
+    assert layouts
+    for layout in layouts:
+        order = [int(i) for i in layout.split(',') if pool[int(i)] > 1]
+        assert order == sorted(order, reverse=True), layout
 
 
 def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
@@ -212,25 +225,45 @@ def test_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
     assert 'kernel-fallback' not in hlo
 
 
-@pytest.mark.parametrize('width', [32, 256])
+@pytest.mark.parametrize('model,width', [
+    pytest.param('llama', 32, id='32'), pytest.param('llama', 256, id='256'),
+    pytest.param('latent', 64, id='latent-64'),
+    pytest.param('latent', 256, id='latent-256')])
 def test_shared_prefix_prefill_writes_the_pool_in_place(one_chip,
-                                                        monkeypatch, width):
+                                                        monkeypatch, model,
+                                                        width):
     """The suffix prefill over the pool (S = W, one row, the gather
-    path), at two of the widths the engine warms: the same carry, so the
-    tail's rows are scattered into the donated pool and the prefix is
-    gathered out of it, block by block, with no plane in between."""
+    path), at two of the widths the engine warms, for the Llama cells
+    and the latent one (``MLA_CELL``: one 640-wide plane; its tails
+    from 64 go by blocks): the same carry, so the tail goes into the
+    donated pool as whole blocks (``paged.write_path``) and the prefix
+    is gathered out of it, block by block, with no plane in between
+    and no row scatter."""
     monkeypatch.setattr(attention, '_use_pallas', lambda: True)
-    cfg, params, pool = _llama_cell(one_chip)
+    if model == 'llama':
+        cfg, params, pool = _llama_cell(one_chip)
+        prefill, geometry, mb = paged.jit_prefill_shared, None, CELL[
+            'max_blocks']
+    else:
+        from skypilot_tpu.models import mla_moe
+        cfg, params, pool = _mla_cell(one_chip)
+        prefill, geometry, mb = (mla_moe.jit_prefill_shared,
+                                 pool.k.shape, MLA_CELL['max_blocks'])
+    assert paged.write_path(width, pool.k.shape[3],
+                            pool.k.shape[2]) == 'blocks'
 
     def vec(dtype, *shape):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    hlo = paged.jit_prefill_shared.lower(
-        cfg, params, pool, vec(jnp.int32, 1, width),
-        vec(jnp.int32, 1, CELL['max_blocks']), vec(jnp.int32),
-        vec(jnp.int32, 1), vec(jnp.int32, 1), None).compile().as_text()
-    assert 'paged_decode' not in hlo
-    _no_pool_is_taken_apart(hlo, 'fusion:scatter')
+    hlo = prefill.lower(
+        cfg, params, pool, vec(jnp.int32, 1, width), vec(jnp.int32, 1, mb),
+        vec(jnp.int32), vec(jnp.int32, 1), vec(jnp.int32, 1),
+        None).compile().as_text()
+    assert 'paged_decode' not in hlo and 'mla_decode' not in hlo
+    if geometry is None:
+        _no_pool_is_taken_apart(hlo, 'fusion:scatter')
+    else:
+        _no_pool_is_taken_apart(hlo, 'fusion:scatter', geometry)
 
 
 # -- the latent (MLA) cell: xing-docs-sessions -------------------------------
@@ -244,6 +277,25 @@ def _mla_cfg(layers=3):
     from skypilot_tpu.models import mla_moe
     return mla_moe.MlaMoeConfig(vocab_size=1024, n_layers=layers,
                                 n_dense_layers=1, max_seq_len=32768)
+
+
+def _mla_cell(one_chip):
+    """One dense and two expert layers at the published widths,
+    parameters and pool described on the chip: (cfg, params, pool)."""
+    from skypilot_tpu.models import mla_moe
+    cfg = _mla_cfg()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    c = MLA_CELL
+    params = on_chip(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = on_chip(jax.eval_shape(lambda: mla_moe.init_pool(
+        cfg, c['slots'], c['max_blocks'] * c['block'], c['blocks'],
+        c['block'])))
+    return cfg, params, pool
 
 
 def test_mla_decode_compiles_at_the_cells_geometry(one_chip):
@@ -284,18 +336,8 @@ def test_mla_decode_step_hands_the_kernel_the_pool_as_it_lies(one_chip,
     call (a sliced stack did: 64% of the step on the chip, PR 28)."""
     from skypilot_tpu.models import mla_moe
     monkeypatch.setattr(attention, '_use_pallas', lambda: True)
-    cfg = _mla_cfg()
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    c = MLA_CELL
-    slots = c['slots']
-    params = on_chip(jax.eval_shape(
-        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
-    pool = on_chip(jax.eval_shape(lambda: mla_moe.init_pool(
-        cfg, slots, c['max_blocks'] * c['block'], c['blocks'], c['block'])))
+    cfg, params, pool = _mla_cell(one_chip)
+    slots = MLA_CELL['slots']
     assert pool.v is None and pool.k.shape == (3, 8193, 1, 16, 640)
     assert mla_moe.decode_path(pool.tables.shape, pool.k.shape,
                                pool.k.dtype) == 'mla_kernel'

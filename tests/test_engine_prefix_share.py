@@ -100,6 +100,59 @@ def test_share_greedy_byte_parity_on_vs_off(tiny):
     assert st['cow_forks'] >= 1, st  # 24-token head: full block + 8
     assert stats[True]['prefill_tokens'] < stats[False]['prefill_tokens']
     assert not stats[False]['prefix_share']['enabled']
+    # every hit's tail (8 tokens padded to 16) by the one rule
+    path = paged_lib.write_path(16, 16, cfg.n_kv_heads)
+    assert stats[True]['pool_write'][path] == st['hits'], stats[True]
+    assert sum(stats[True]['pool_write'].values()) == st['hits']
+    assert stats[False]['pool_write'] == {'blocks': 0, 'rows': 0}
+
+
+@pytest.mark.parametrize('tail,path', [(8, 'rows'), (20, 'blocks')])
+def test_pool_write_counts_each_shared_prefill_by_its_path(tiny, tail,
+                                                           path):
+    """A tail of 8 tokens pads to 16 positions, 32 rows of the tiny
+    model's 2 heads: by rows (paged.write_path); one of 20 pads to 32,
+    64 rows, four blocks' positions: by blocks. Either way the stream
+    is the solo oracle's."""
+    cfg, params = tiny
+    eng = _mk(params, cfg, max_len=128)
+    try:
+        head = [((17 * j) % 250) + 1 for j in range(32)]
+        a = head + [3, 4, 5]
+        b = head + [((5 * j) % 250) + 1 for j in range(tail)]
+        for row in (a, b):
+            assert eng.submit(row, 4).result(timeout=300) == \
+                _solo(params, cfg, row, 4, max_len=128)
+        st = eng.stats()
+        assert st['prefix_share']['hits'] == 1, st['prefix_share']
+        width = engine_lib.prompt_bucket(tail)
+        assert paged_lib.write_path(width, 16, cfg.n_kv_heads) == path
+        assert st['pool_write'] == {'blocks': int(path == 'blocks'),
+                                    'rows': int(path == 'rows')}
+    finally:
+        eng.stop()
+
+
+def test_pool_write_under_tensor_parallel_counts_a_shards_heads(tiny):
+    """Under TP each shard writes its own kv heads (``_cache_step``
+    under ``shard_map``): the tiny model's 2 heads over a tensor axis of
+    2 leave one a shard, so a tail padded to 32 is 32 rows there, under
+    four blocks' positions: by rows, and the count says so."""
+    from skypilot_tpu.parallel import mesh as mesh_lib
+    cfg, params = tiny
+    mesh = mesh_lib.build_mesh(mesh_lib.MeshSpec(fsdp=1, tensor=2),
+                               devices=jax.devices()[:2])
+    eng = _mk(params, cfg, max_len=128, mesh=mesh)
+    try:
+        head = [((17 * j) % 250) + 1 for j in range(32)]
+        for row in (head + [3, 4, 5],
+                    head + [((3 * j) % 250) + 1 for j in range(20)]):
+            assert eng.submit(row, 4).result(timeout=300) == \
+                _solo(params, cfg, row, 4, max_len=128)
+        assert paged_lib.write_path(32, 16, cfg.n_kv_heads // 2) == 'rows'
+        assert eng.stats()['pool_write'] == {'blocks': 0, 'rows': 1}
+    finally:
+        eng.stop()
 
 
 def test_share_cow_fork_on_divergent_append(tiny):
@@ -316,6 +369,7 @@ def test_stats_surface_share_counters(tiny):
         for key in ('enabled', 'hits', 'misses', 'hit_rate',
                     'hit_tokens', 'commits', 'evictions', 'cow_forks'):
             assert key in ps, ps
+        assert st['pool_write'] == {'blocks': 0, 'rows': 0}
         assert 'prefill_tokens' in st and 'prefill_tokens_saved' in st
         assert 'prefill_bubble_ms' in st
     finally:

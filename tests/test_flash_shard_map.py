@@ -108,15 +108,17 @@ def test_the_three_kernels_carry_their_names_into_the_program():
 # -- paged decode per kv-head shard (ops/decode_attention.paged_decode) -----
 
 
-@pytest.mark.parametrize('s', [1, 3], ids=['s1-kernel', 's3-gather'])
+@pytest.mark.parametrize('s', [1, 3, 64],
+                         ids=['s1-kernel', 's3-gather', 's64-blocks'])
 @pytest.mark.parametrize('axes', [dict(tensor=4), dict(data=2, tensor=2)],
                          ids=['tensor4', 'data2-tensor2'])
 def test_sharded_paged_step_equals_unsharded(axes, s, monkeypatch):
     """Under a TP mesh the cache write and read of a layer run per
     kv-head shard of the WHOLE pools (heads are independent): the
     kernel, which writes the step's row itself (S = 1), or the row
-    scatter and the gathered view (S = 3: the verify, the shared-prefix
-    prefill). The layer, tables
+    scatter (S = 3: the verify) or the write by blocks (S = 64: the
+    shared-prefix prefill; ``paged.write_path`` of a shard's one or two
+    heads) and the gathered view. The layer, tables
     and lengths go to every shard whole, whatever the mesh does with
     the batch. Four virtual devices, interpret mode: the sharded step
     equals the unsharded one, keeps heads and pools sharded, and
